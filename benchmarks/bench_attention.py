@@ -152,7 +152,8 @@ def measure(backend, B, Sq, Sk, H, KV, hd, causal, iters=3):
     qd = jax.random.normal(ks[3], (B, 1, H, hd), jnp.bfloat16)
     lens = jnp.full((B,), Sk // 2, jnp.int32)
     dec = jax.jit(lambda q, k, v, n: FA.decode_attention(
-        q, k, v, n, backend=backend))
+        q, jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2), n,
+        backend=backend))
     return {
         "fwd_s": _wallclock(fwd, q, k, v, iters=iters),
         "bwd_s": _wallclock(bwd, q, k, v, iters=iters),
@@ -165,7 +166,7 @@ def measure_paged(backend, B, Sq, nb, bs, H, KV, hd, iters=3):
     the dispatch layer at fill = half the window."""
     from repro.kernels.paged_attention import ops as PA
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
-    kp = jax.random.normal(ks[0], (B * nb + 1, bs, KV, hd), jnp.bfloat16)
+    kp = jax.random.normal(ks[0], (B * nb + 1, KV, bs, hd), jnp.bfloat16)
     vp = jax.random.normal(ks[1], kp.shape, jnp.bfloat16)
     tables = jnp.arange(B * nb, dtype=jnp.int32).reshape(B, nb)
     off = jnp.full((B,), nb * bs // 2, jnp.int32)

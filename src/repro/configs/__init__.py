@@ -1,7 +1,8 @@
 """Architecture registry: the 10 assigned architectures + the paper's own
-CLIP ViT-Huge. `get_config(name)` returns the exact full-size config;
-`get_reduced_config(name)` returns the same-family shrunken config used by
-the CPU smoke tests (full configs are exercised only via the dry-run).
+CLIP ViT-Huge. `get_config(name)` returns the exact published config;
+each arch's same-family shrunken config for CPU runs is registered under
+its own name, ``<arch>-reduced`` (``get_config("smollm-360m-reduced")``,
+or ``get_reduced_config("smollm-360m")``).
 """
 from __future__ import annotations
 
@@ -19,6 +20,8 @@ ARCH_IDS = (
 )
 PAPER_ARCH = "clip-vit-huge"
 ALL_ARCHS = ARCH_IDS + (PAPER_ARCH,)
+REDUCED_SUFFIX = "-reduced"
+REDUCED_ARCHS = tuple(a + REDUCED_SUFFIX for a in ALL_ARCHS)
 
 _MODULES = {
     "qwen3-moe-30b-a3b": "qwen3_moe_30b",
@@ -36,8 +39,13 @@ _MODULES = {
 
 
 def get_config(name: str):
+    """The published config of ``name``, or the reduced config of
+    ``<arch>-reduced``."""
+    if name in REDUCED_ARCHS:
+        return get_reduced_config(name[:-len(REDUCED_SUFFIX)])
     if name not in _MODULES:
-        raise KeyError(f"unknown arch {name!r}; known: {sorted(_MODULES)}")
+        raise KeyError(f"unknown arch {name!r}; known: "
+                       f"{sorted(ALL_ARCHS + REDUCED_ARCHS)}")
     mod = importlib.import_module(f"repro.configs.{_MODULES[name]}")
     return mod.CONFIG
 

@@ -8,12 +8,14 @@ the paper's methods and baselines (see core/switchback.py).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.core import switchback as SB
+from repro.core.sharding import kernel_call
 
 Array = jax.Array
 
@@ -118,15 +120,18 @@ def quant_linear(x: Array, w: Array, b: Optional[Array] = None, *,
     plain dot in the compute dtype with f32 accumulation.
     """
     if policy.is_quantized:
-        xq = x.astype(policy.compute_dtype)
-        return SB.switchback_linear(
-            xq, w.astype(jnp.float32), b,
+        args = (x.astype(policy.compute_dtype), w.astype(jnp.float32))
+        args += () if b is None else (b,)
+        sb = functools.partial(
+            SB.switchback_linear,
             variant=_SB_VARIANT[policy.mode],
             fwd_fmt=policy.fwd_fmt, bwd_fmt=policy.bwd_fmt,
             backend=policy.backend,
             block_rows=policy.fp8_block_rows,
             block_cols=policy.fp8_block_cols,
             fallback_ratio=policy.fp8_fallback_ratio)
+        return kernel_call(sb, *args, batch_dims=(0, None, None)[:len(args)],
+                           backend=policy.backend)
     cd = (jnp.float32 if policy.mode == "fp32" else policy.compute_dtype)
     y = jax.lax.dot_general(
         x.astype(cd), w.astype(cd),

@@ -14,12 +14,24 @@ from __future__ import annotations
 import numpy as np
 
 
+# Above this vocabulary the dense V x V transition matrix no longer fits a
+# host (49152² float64 is 19 GB): each token then moves to one of
+# SPARSE_FANOUT fixed random successors instead.
+DENSE_VOCAB_MAX = 4096
+SPARSE_FANOUT = 64
+
+
 class BigramLM:
     """Deterministic synthetic LM stream."""
 
     def __init__(self, vocab_size: int, seed: int = 0, temperature: float = 1.0):
         rng = np.random.RandomState(seed)
-        logits = rng.randn(vocab_size, vocab_size) * 2.0 / temperature
+        self.succ = None                     # (V, fanout) successor ids
+        width = vocab_size
+        if vocab_size > DENSE_VOCAB_MAX:
+            self.succ = rng.randint(0, vocab_size, (vocab_size, SPARSE_FANOUT))
+            width = SPARSE_FANOUT
+        logits = rng.randn(vocab_size, width) * 2.0 / temperature
         self.P = np.exp(logits - logits.max(1, keepdims=True))
         self.P /= self.P.sum(1, keepdims=True)
         self.vocab_size = vocab_size
@@ -33,11 +45,14 @@ class BigramLM:
         for t in range(seq_len):
             p = self.P[toks[:, t]]                       # (B, V)
             u = self._rng.rand(batch_size, 1)
-            toks[:, t + 1] = (p.cumsum(1) > u).argmax(1)
+            nxt = (p.cumsum(1) > u).argmax(1)
+            toks[:, t + 1] = (nxt if self.succ is None
+                              else self.succ[toks[:, t], nxt])
         return {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
 
     def entropy_floor(self) -> float:
-        """Mean conditional entropy of the chain — the loss floor."""
+        """Mean conditional entropy of the chain — the loss floor (an upper
+        bound on it for a sparse chain whose successors repeat)."""
         h = -(self.P * np.log(np.maximum(self.P, 1e-12))).sum(1)
         return float(h.mean())
 

@@ -27,9 +27,18 @@ this repo's needs (DESIGN.md §9):
   predicated (no FLOPs) and the K/V index maps clamp dead tiles onto the
   last live tile so the pipeline never fetches them (no DMA) — a slot 10
   tokens in pays for 1 tile, not S_max/bk. The cache stays in its storage
-  layout (B, S, KV, hd); the BlockSpec walks it directly, no transpose.
+  layout (B, KV, S, hd); the BlockSpec walks it directly, no transpose.
 
-All compute is f32 on the MXU (``preferred_element_type``); masking uses a
+Layout rule (TPU): the last two dims of every block are either
+(8, 128)-aligned or the whole array dim. So the per-row softmax stats
+travel as (B, H, Sq, 1), decode queries as (B, KV, group, hd) — each KV
+head's query group is one whole trailing (group, hd) slab — and every KV
+cache puts its head axis ahead of the token axis.
+
+Dots accumulate in f32 on the MXU (``preferred_element_type``) at the
+MXU's default precision, which takes f32 operands in one bf16 pass — as
+XLA's default does for the dense reference (measured on v5e: forcing
+full f32 passes made the smollm-360m train step 75% slower). Masking uses a
 finite ``-0.7·f32_max`` (never -inf: ``exp(-inf - -inf)`` NaNs). Every
 kernel has a pure-jnp oracle in ``ref.py``; tests sweep shapes in
 interpret mode (tests/test_attention_kernels.py).
@@ -117,14 +126,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         l = l_ref[...]                                      # (g, bq, 1)
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
-        lse_ref[0] = (m_ref[...] + jnp.log(l_safe))[..., 0]
+        lse_ref[0] = m_ref[...] + jnp.log(l_safe)
 
 
 def flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
               kv_valid: int, scale: float, block_q: int = 128,
               block_k: int = 128, interpret: bool = False):
     """q (B, H, Sq, hd); k, v (B, KV, Sk, hd). Returns (o (B, H, Sq, hd)
-    in q.dtype, lse (B, H, Sq) f32). ``kv_valid`` masks pad keys.
+    in q.dtype, lse (B, H, Sq, 1) f32). ``kv_valid`` masks pad keys.
 
     The grid walks KV heads, not query heads — the whole GQA group shares
     each fetched K/V tile via the in-kernel head loop, so KV HBM traffic
@@ -147,12 +156,12 @@ def flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[
             q_spec,
-            pl.BlockSpec((1, group, block_q),
-                         lambda b, h, iq, ik: (b, h, iq)),
+            pl.BlockSpec((1, group, block_q, 1),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((group, block_q, 1), jnp.float32),   # running max
@@ -194,12 +203,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
                 q, k, dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
             s = jnp.where(mask, s, MASK_VALUE)
-            p = jnp.exp(s - lse_ref[0, g][:, None])          # masked -> 0
+            p = jnp.exp(s - lse_ref[0, g])                   # masked -> 0
             do = do_ref[0, g].astype(jnp.float32)
             dp = jax.lax.dot_general(                        # do · vᵀ
                 do, v, dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            ds = p * (dp - di_ref[0, g][:, None])
+            ds = p * (dp - di_ref[0, g])
             acc_ref[g] += jax.lax.dot(ds, k,
                                       preferred_element_type=jnp.float32)
 
@@ -216,7 +225,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
 def flash_bwd_dq(q, k, v, do, lse, di, *, causal: bool, kv_valid: int,
                  scale: float, block_q: int = 128, block_k: int = 128,
                  interpret: bool = False):
-    """Returns dq (B, H, Sq, hd) f32. lse/di are (B, H, Sq) f32. Same
+    """Returns dq (B, H, Sq, hd) f32. lse/di are (B, H, Sq, 1) f32. Same
     KV-head grid + in-kernel group loop as flash_fwd."""
     B, H, Sq, hd = q.shape
     KV, Sk = k.shape[1], k.shape[2]
@@ -229,8 +238,8 @@ def flash_bwd_dq(q, k, v, do, lse, di, *, causal: bool, kv_valid: int,
                           lambda b, h, iq, ik: (b, h, iq, 0))
     kv_spec = pl.BlockSpec((1, 1, block_k, hd),
                            lambda b, h, iq, ik: (b, h, ik, 0))
-    stat_spec = pl.BlockSpec((1, group, block_q),
-                             lambda b, h, iq, ik: (b, h, iq))
+    stat_spec = pl.BlockSpec((1, group, block_q, 1),
+                             lambda b, h, iq, ik: (b, h, iq, 0))
     return pl.pallas_call(
         kernel,
         grid=(B, KV, n_q, n_k),
@@ -276,14 +285,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
                 q, k, dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
             s = jnp.where(mask, s, MASK_VALUE)
-            p = jnp.exp(s - lse_ref[0, g][:, None])
+            p = jnp.exp(s - lse_ref[0, g])
             dv_acc[...] += jax.lax.dot_general(              # pᵀ @ do
                 p, do, dimension_numbers=(((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             dp = jax.lax.dot_general(
                 do, v, dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            ds = p * (dp - di_ref[0, g][:, None])
+            ds = p * (dp - di_ref[0, g])
             dk_acc[...] += jax.lax.dot_general(              # dsᵀ @ q
                 ds, q, dimension_numbers=(((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -315,8 +324,8 @@ def flash_bwd_dkv(q, k, v, do, lse, di, *, causal: bool, kv_valid: int,
     # h's whole query group
     q_spec = pl.BlockSpec((1, group, block_q, hd),
                           lambda b, h, ik, iq: (b, h, iq, 0))
-    stat_spec = pl.BlockSpec((1, group, block_q),
-                             lambda b, h, ik, iq: (b, h, iq))
+    stat_spec = pl.BlockSpec((1, group, block_q, 1),
+                             lambda b, h, ik, iq: (b, h, iq, 0))
     kv_spec = pl.BlockSpec((1, 1, block_k, hd),
                            lambda b, h, ik, iq: (b, h, ik, 0))
     return pl.pallas_call(
@@ -355,8 +364,8 @@ def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     # unchanged block index means the pipeline skips the fetch).
     @pl.when(ik * block_k < kv_len)
     def _tile():
-        q = q_ref[0].astype(jnp.float32) * scale             # (group, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (bk, hd)
+        q = q_ref[0, 0].astype(jnp.float32) * scale          # (group, hd)
+        k = k_ref[0, 0].astype(jnp.float32)                  # (bk, hd)
         s = jax.lax.dot_general(                             # (group, bk)
             q, k, dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -370,7 +379,7 @@ def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         alpha = jnp.exp(m_prev - m_next)
         m_ref[...] = m_next
         l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
             p, v, preferred_element_type=jnp.float32)
 
@@ -378,7 +387,7 @@ def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     def _finalize():
         l = l_ref[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
 def decode_fwd(q: jax.Array, k: jax.Array, v: jax.Array, kv_len: jax.Array,
@@ -386,11 +395,12 @@ def decode_fwd(q: jax.Array, k: jax.Array, v: jax.Array, kv_len: jax.Array,
                interpret: bool = False):
     """Single-query ring-cache attention.
 
-    q (B, H, hd); k, v (B, S, KV, hd) — the cache's own storage layout, no
-    transpose; kv_len (B,) int32 valid-cell counts (callers pass
+    q (B, KV, group, hd) — query head ``h * group + g`` at ``[:, h, g]``;
+    k, v (B, KV, S, hd) — the cache's own storage layout, no transpose;
+    kv_len (B,) int32 valid-cell counts (callers pass
     ``min(length + 1, S_max)``, which with ring writes at ``length % S``
     makes wrapped slots attend over the whole window). S % block_k == 0.
-    Returns o (B, H, hd) in q.dtype.
+    Returns o (B, KV, group, hd) in q.dtype.
 
     ``kv_len`` rides in as a scalar-prefetch operand so the K/V BlockSpec
     index maps can see it: tiles past a slot's last live tile are clamped
@@ -398,27 +408,27 @@ def decode_fwd(q: jax.Array, k: jax.Array, v: jax.Array, kv_len: jax.Array,
     Pallas pipeline skip their HBM fetch entirely — the dynamic skip
     saves the DMA, not just the FLOPs.
     """
-    B, H, hd = q.shape
-    S, KV = k.shape[1], k.shape[2]
-    group = H // KV
+    B, KV, group, hd = q.shape
+    S = k.shape[2]
     n_k = S // block_k
     kernel = functools.partial(_decode_kernel, scale=scale, n_k=n_k,
                                block_k=block_k)
 
     def kv_map(b, h, ik, lens):
         last = jnp.maximum((lens[b] + block_k - 1) // block_k - 1, 0)
-        return (b, jnp.minimum(ik, last), h, 0)
+        return (b, h, jnp.minimum(ik, last), 0)
 
+    q_spec = pl.BlockSpec((1, 1, group, hd),
+                          lambda b, h, ik, lens: (b, h, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, KV, n_k),
         in_specs=[
-            pl.BlockSpec((1, group, hd), lambda b, h, ik, lens: (b, h, 0)),
-            pl.BlockSpec((1, block_k, 1, hd), kv_map),
-            pl.BlockSpec((1, block_k, 1, hd), kv_map),
+            q_spec,
+            pl.BlockSpec((1, 1, block_k, hd), kv_map),
+            pl.BlockSpec((1, 1, block_k, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, group, hd),
-                               lambda b, h, ik, lens: (b, h, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, 1), jnp.float32),
@@ -428,6 +438,6 @@ def decode_fwd(q: jax.Array, k: jax.Array, v: jax.Array, kv_len: jax.Array,
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
     )(kv_len.astype(jnp.int32), q, k, v)

@@ -88,7 +88,7 @@ def flash_fwd_lse(q, k, v, *, causal: bool, backend: Backend = "xla",
     o, lse = _k.flash_fwd(qk, kk, vk, causal=causal, kv_valid=Sk,
                           scale=scale, block_q=bq, block_k=bk,
                           interpret=(backend == "pallas_interpret"))
-    return _to_kernel(o[:, :, :Sq]), lse[:, :, :Sq]
+    return _to_kernel(o[:, :, :Sq]), lse[:, :, :Sq, 0]
 
 
 def _bwd_impl(causal, backend, block_q, block_k, res, do):
@@ -111,8 +111,8 @@ def _bwd_impl(causal, backend, block_q, block_k, res, do):
         # pad queries carry zero `do`, so their (finite) rebuilt weights
         # contribute exactly zero to dk/dv; pad lse/di of 0 keep exp finite
         dop = _pad_seq(_to_kernel(do), 2, bq)
-        lsep = _pad_seq(lse, 2, bq)
-        dip = _pad_seq(di, 2, bq)
+        lsep = _pad_seq(lse, 2, bq)[..., None]
+        dip = _pad_seq(di, 2, bq)[..., None]
         dq = _k.flash_bwd_dq(qp, kp, vp, dop, lsep, dip, causal=causal,
                              kv_valid=Sk, scale=scale, block_q=bq,
                              block_k=bk, interpret=interp)[:, :, :Sq]
@@ -165,7 +165,7 @@ def decode_attention(q, k, v, kv_len, *, backend: Backend = "xla",
                      block_k: int = 0):
     """Single-query attention over the (ring) KV cache.
 
-    q (B, 1, H, hd); k, v (B, S_max, KV, hd) in the cache's storage layout;
+    q (B, 1, H, hd); k, v (B, KV, S_max, hd) in the cache's storage layout;
     kv_len (B,) int32 — valid cells per slot (``min(length + 1, S_max)``,
     so ring-wrapped slots attend over the whole window). Returns
     (B, 1, H, hd). Tiles beyond a slot's length are skipped dynamically on
@@ -173,7 +173,7 @@ def decode_attention(q, k, v, kv_len, *, backend: Backend = "xla",
     """
     B, one, H, hd = q.shape
     assert one == 1, q.shape
-    S, KV = v.shape[1], v.shape[2]
+    KV, S = v.shape[1], v.shape[2]
     assert H % KV == 0, (H, KV)
     scale = 1.0 / math.sqrt(hd)
     q3 = q[:, 0]                                             # (B, H, hd)
@@ -181,11 +181,16 @@ def decode_attention(q, k, v, kv_len, *, backend: Backend = "xla",
     if backend == "xla":
         return _ref.decode_fwd(q3, k, v, kv_len, scale=scale)[:, None]
     # the block must divide S_max (padding the cache would copy it every
-    # step): honor the configured/default size when it divides, else the
-    # largest divisor not above it — e.g. S_max=96, block_k=128 -> 96
+    # step) and be a multiple of 8 sublanes unless it is all of S_max:
+    # honor the configured/default size when it qualifies, else the
+    # largest qualifying divisor below it — e.g. S_max=96, block_k=128
+    # -> 96; S_max=200 -> 40
     bk = min(block_k or DEFAULT_BLOCK, S)
-    while S % bk:
+    while S % bk or (bk % 8 and bk != S):
         bk -= 1
-    o = _k.decode_fwd(q3, k, v, kv_len[:, 0], scale=scale, block_k=bk,
+        if bk == 0:
+            bk = S
+    o = _k.decode_fwd(q3.reshape(B, KV, H // KV, hd), k, v, kv_len[:, 0],
+                      scale=scale, block_k=bk,
                       interpret=(backend == "pallas_interpret"))
-    return o[:, None]
+    return o.reshape(B, 1, H, hd)

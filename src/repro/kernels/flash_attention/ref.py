@@ -74,14 +74,14 @@ def mha_bwd(q, k, v, o, lse, do, *, causal: bool, kv_valid: int,
 
 
 def decode_fwd(q, k, v, kv_len, *, scale: float):
-    """q (B, H, hd); k, v (B, S, KV, hd); kv_len (B, 1) int32 valid cells.
+    """q (B, H, hd); k, v (B, KV, S, hd); kv_len (B, 1) int32 valid cells.
     Returns o (B, H, hd) q.dtype — the dense full-window re-attend the
     decode kernel replaces."""
     H = q.shape[1]
-    kx = _expand_heads(jnp.moveaxis(k, 2, 1), H).astype(jnp.float32)
-    vx = _expand_heads(jnp.moveaxis(v, 2, 1), H).astype(jnp.float32)
+    kx = _expand_heads(k, H).astype(jnp.float32)
+    vx = _expand_heads(v, H).astype(jnp.float32)
     s = scale * jnp.einsum("bhd,bhkd->bhk", q.astype(jnp.float32), kx)
-    mask = jnp.arange(k.shape[1])[None, None, :] < kv_len[:, :, None]
+    mask = jnp.arange(k.shape[2])[None, None, :] < kv_len[:, :, None]
     s = jnp.where(mask, s, MASK_VALUE)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhk,bhkd->bhd", p, vx).astype(q.dtype)

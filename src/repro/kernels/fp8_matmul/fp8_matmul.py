@@ -72,11 +72,13 @@ def row_quantize(x: jax.Array, *, fmt: str = "e4m3", block_b: int = 256,
 # ---------------------------------------------------------------------------
 
 def _absmax_kernel(x_ref, o_ref):
+    # the running max is a (1, 1) vector, never a scalar: Mosaic cannot
+    # store scalars to VMEM
     @pl.when(pl.program_id(0) == 0)
     def _init():
-        o_ref[0, 0] = jnp.zeros((), jnp.float32)
-    m = jnp.max(jnp.abs(x_ref[...].astype(jnp.float32)))
-    o_ref[0, 0] = jnp.maximum(o_ref[0, 0], m)
+        o_ref[...] = jnp.zeros((1, 1), jnp.float32)
+    m = jnp.max(jnp.abs(x_ref[...].astype(jnp.float32)), keepdims=True)
+    o_ref[...] = jnp.maximum(o_ref[...], m)
 
 
 def _cast_kernel(x_ref, s_ref, q_ref, *, fmt: str):

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from repro.kernels.fp8_matmul import fp8_matmul as _k
 from repro.kernels.fp8_matmul import ref as _ref
 from repro.kernels.switchback.ops import (  # same VMEM heuristics: int8 and
-    _pad_to, choose_blocks)                 # fp8 operands are both 1 byte
+    _pad_to, choose_blocks, quant_block_rows)  # fp8 operands are 1 byte
 
 Backend = Literal["xla", "pallas", "pallas_interpret"]
 BACKENDS: tuple[str, ...] = ("xla", "pallas", "pallas_interpret")
@@ -51,7 +51,8 @@ def tensor_quantize(x: jax.Array, *, fmt: str = "e4m3",
         return _ref.tensor_quantize(x, fmt=fmt)
     interp = backend == "pallas_interpret"
     R = x.shape[0]
-    br = min(512, R)
+    # the cast's f32 grid rounding needs ~10x its input block in VMEM
+    br = quant_block_rows(x, block_bytes=1024 * 1024)
     xp = _pad_to(x, (br, 1))   # zero rows don't change the absmax
     q, s = _k.tensor_quantize(xp, fmt=fmt, block_rows=br, interpret=interp)
     return q[:R], s

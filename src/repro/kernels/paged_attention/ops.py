@@ -1,8 +1,8 @@
 """Jit'd public wrappers + backend dispatch for paged attention.
 
 Model-layout contract (what models/attention.py speaks): decode q
-(B, 1, H, hd), prefill q (B, S, H, hd); k_pool/v_pool (N+1, block_size,
-KV, hd) physical block pools; tables (B, n_blocks_per_slot) int32;
+(B, 1, H, hd), prefill q (B, S, H, hd); k_pool/v_pool (N+1, KV,
+block_size, hd) physical block pools; tables (B, n_blocks_per_slot) int32;
 kv_len (B,) valid cells per slot; prefill additionally takes q_off (B,)
 per-slot absolute offsets of query row 0 (the chunk cursor). On ``xla``
 the path is gather-then-dense (``ref``); on ``pallas``/
@@ -31,7 +31,7 @@ def paged_decode_attention(q, k_pool, v_pool, tables, kv_len, *,
                            backend: Backend = "xla"):
     """Single-query attention over the paged KV cache.
 
-    q (B, 1, H, hd); k_pool/v_pool (N+1, block_size, KV, hd) in the pool's
+    q (B, 1, H, hd); k_pool/v_pool (N+1, KV, block_size, hd) in the pool's
     storage layout (block N is the engine's trash block); tables (B, nb)
     int32 logical→physical block ids; kv_len (B,) int32 — valid cells per
     slot. Returns (B, 1, H, hd). On the pallas backends, blocks past a
@@ -39,7 +39,7 @@ def paged_decode_attention(q, k_pool, v_pool, tables, kv_len, *,
     """
     B, one, H, hd = q.shape
     assert one == 1, q.shape
-    KV = k_pool.shape[2]
+    KV = k_pool.shape[1]
     assert H % KV == 0, (H, KV)
     if backend not in BACKENDS:
         raise ValueError(f"backend {backend!r} not in {BACKENDS}")
@@ -50,10 +50,10 @@ def paged_decode_attention(q, k_pool, v_pool, tables, kv_len, *,
     if backend == "xla":
         return _ref.paged_decode_fwd(q3, k_pool, v_pool, tables, kv_len,
                                      scale=scale)[:, None]
-    o = _k.paged_decode_fwd(q3, k_pool, v_pool, tables, kv_len,
-                            scale=scale,
+    o = _k.paged_decode_fwd(q3.reshape(B, KV, H // KV, hd), k_pool, v_pool,
+                            tables, kv_len, scale=scale,
                             interpret=(backend == "pallas_interpret"))
-    return o[:, None]
+    return o.reshape(B, 1, H, hd)
 
 
 @functools.partial(jax.jit, static_argnames=("backend",))
@@ -78,7 +78,7 @@ def paged_prefill_attention(q, k_pool, v_pool, tables, q_off, kv_len, *,
     greedy accept/reject exact rather than approximate.
     """
     B, S, H, hd = q.shape
-    KV = k_pool.shape[2]
+    KV = k_pool.shape[1]
     assert H % KV == 0, (H, KV)
     if backend not in BACKENDS:
         raise ValueError(f"backend {backend!r} not in {BACKENDS}")

@@ -4,8 +4,10 @@ kernel.
 
 The paged twin of ``kernels/flash_attention``'s ring-cache decode kernel
 (DESIGN.md §10): K/V live in a fixed pool of physical blocks of shape
-(num_blocks + 1, block_size, KV, hd) — the last block is the engine's
-trash block — and each batch slot owns a *block table* row mapping its
+(num_blocks + 1, KV, block_size, hd) — the last block is the engine's
+trash block; the head axis sits ahead of the token axis so one head's
+block is a whole trailing (block_size, hd) slab, as the TPU's block
+layout rule asks — and each batch slot owns a *block table* row mapping its
 logical block j to a physical block id. The kernel walks logical blocks;
 the **block table rides in as a scalar-prefetch operand** so the K/V
 BlockSpec index maps can translate logical tile → physical block before
@@ -65,8 +67,8 @@ def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
     # live physical block (unchanged block index => no fetch).
     @pl.when(ib * block_size < kv_len)
     def _tile():
-        q = q_ref[0].astype(jnp.float32) * scale         # (group, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)        # (bs, hd)
+        q = q_ref[0, 0].astype(jnp.float32) * scale      # (group, hd)
+        k = k_ref[0, 0].astype(jnp.float32)              # (bs, hd)
         s = jax.lax.dot_general(                         # (group, bs)
             q, k, dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -80,7 +82,7 @@ def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         alpha = jnp.exp(m_prev - m_next)
         m_ref[...] = m_next
         l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
             p, v, preferred_element_type=jnp.float32)
 
@@ -88,7 +90,7 @@ def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
     def _finalize():
         l = l_ref[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
 def _paged_prefill_kernel(tables_ref, qoff_ref, lens_ref, q_ref, k_ref,
@@ -113,8 +115,8 @@ def _paged_prefill_kernel(tables_ref, qoff_ref, lens_ref, q_ref, k_ref,
     @pl.when((ib * block_size < kv_len)
              & (ib * block_size <= q_off + (iq + 1) * block_q - 1))
     def _tile():
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (bs, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                  # (bs, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
         kpos = ib * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_size), 1)
         qpos = q_off + iq * block_q + jax.lax.broadcasted_iota(
@@ -154,7 +156,7 @@ def paged_prefill_fwd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
 
     q (B, H, Sq, hd) kernel layout with ``Sq % block_q == 0`` — the
     current chunk's queries, row r of slot b at absolute position
-    ``q_off[b] + r``; k_pool/v_pool (N+1, block_size, KV, hd) with the
+    ``q_off[b] + r``; k_pool/v_pool (N+1, KV, block_size, hd) with the
     chunk's own K/V **already committed** (commit-then-attend); tables
     (B, n_blocks_per_slot) int32; kv_len (B,) int32 valid cells per slot
     (adopted prefix + every committed chunk including this one). Each Q
@@ -170,7 +172,7 @@ def paged_prefill_fwd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     the FLOPs the same way.
     """
     B, H, Sq, hd = q.shape
-    bs, KV = k_pool.shape[1], k_pool.shape[2]
+    KV, bs = k_pool.shape[1], k_pool.shape[2]
     group = H // KV
     n_b = tables.shape[1]
     assert Sq % block_q == 0, (Sq, block_q)
@@ -185,15 +187,15 @@ def paged_prefill_fwd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         last_kv = jnp.maximum((lens[b] + bs - 1) // bs - 1, 0)
         last_causal = (q_off[b] + (iq + 1) * block_q - 1) // bs
         phys = tables[b, jnp.minimum(ib, jnp.minimum(last_kv, last_causal))]
-        return (phys, 0, h, 0)
+        return (phys, h, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, KV, n_q, n_b),
         in_specs=[
             pl.BlockSpec((1, group, block_q, hd), q_map),
-            pl.BlockSpec((1, bs, 1, hd), kv_map),
-            pl.BlockSpec((1, bs, 1, hd), kv_map),
+            pl.BlockSpec((1, 1, bs, hd), kv_map),
+            pl.BlockSpec((1, 1, bs, hd), kv_map),
         ],
         out_specs=pl.BlockSpec((1, group, block_q, hd), q_map),
         scratch_shapes=[
@@ -216,19 +218,19 @@ def paged_decode_fwd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                      interpret: bool = False):
     """Single-query attention through a block table.
 
-    q (B, H, hd); k_pool, v_pool (N+1, block_size, KV, hd) — the physical
-    block pools in storage layout (last block = trash, never attended);
-    tables (B, n_blocks_per_slot) int32 logical→physical block ids;
-    kv_len (B,) int32 valid cells per slot. Returns o (B, H, hd) q.dtype.
+    q (B, KV, group, hd) — query head ``h * group + g`` at ``[:, h, g]``;
+    k_pool, v_pool (N+1, KV, block_size, hd) — the physical block pools in
+    storage layout (last block = trash, never attended); tables
+    (B, n_blocks_per_slot) int32 logical→physical block ids; kv_len (B,)
+    int32 valid cells per slot. Returns o (B, KV, group, hd) q.dtype.
 
     ``tables`` and ``kv_len`` are scalar-prefetch operands: the K/V index
     maps read them to aim each grid step's DMA at the right physical
     block, and to clamp logical blocks past ``cdiv(kv_len, bs)`` onto the
     last live one so the pipeline never fetches dead blocks.
     """
-    B, H, hd = q.shape
-    bs, KV = k_pool.shape[1], k_pool.shape[2]
-    group = H // KV
+    B, KV, group, hd = q.shape
+    bs = k_pool.shape[2]
     n_b = tables.shape[1]
     kernel = functools.partial(_paged_decode_kernel, scale=scale, n_b=n_b,
                                block_size=bs)
@@ -236,19 +238,19 @@ def paged_decode_fwd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     def kv_map(b, h, ib, tables, lens):
         last = jnp.maximum((lens[b] + bs - 1) // bs - 1, 0)
         phys = tables[b, jnp.minimum(ib, last)]
-        return (phys, 0, h, 0)
+        return (phys, h, 0, 0)
 
+    q_spec = pl.BlockSpec((1, 1, group, hd),
+                          lambda b, h, ib, tables, lens: (b, h, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, KV, n_b),
         in_specs=[
-            pl.BlockSpec((1, group, hd),
-                         lambda b, h, ib, tables, lens: (b, h, 0)),
-            pl.BlockSpec((1, bs, 1, hd), kv_map),
-            pl.BlockSpec((1, bs, 1, hd), kv_map),
+            q_spec,
+            pl.BlockSpec((1, 1, bs, hd), kv_map),
+            pl.BlockSpec((1, 1, bs, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, group, hd),
-                               lambda b, h, ib, tables, lens: (b, h, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, 1), jnp.float32),
@@ -258,6 +260,6 @@ def paged_decode_fwd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
     )(tables.astype(jnp.int32), kv_len.astype(jnp.int32), q, k_pool, v_pool)

@@ -17,20 +17,21 @@ from repro.kernels.flash_attention.flash_attention import MASK_VALUE
 
 
 def gather_blocks(pool: jax.Array, tables: jax.Array) -> jax.Array:
-    """(N+1, bs, KV, hd) pool + (B, nb) int32 table -> (B, nb·bs, KV, hd)
+    """(N+1, KV, bs, hd) pool + (B, nb) int32 table -> (B, nb·bs, KV, hd)
     dense cache in logical order (cell j·bs+o of slot b is the pool cell
-    (tables[b, j], o)). Out-of-range ids clamp (jax gather semantics)."""
+    (tables[b, j], :, o)). Out-of-range ids clamp (jax gather semantics)."""
     B, nb = tables.shape
-    g = pool[tables]                                 # (B, nb, bs, KV, hd)
-    return g.reshape(B, nb * pool.shape[1], pool.shape[2], pool.shape[3])
+    _, KV, bs, hd = pool.shape
+    g = jnp.swapaxes(pool[tables], 2, 3)             # (B, nb, bs, KV, hd)
+    return g.reshape(B, nb * bs, KV, hd)
 
 
 def paged_decode_fwd(q, k_pool, v_pool, tables, kv_len, *, scale: float):
-    """q (B, H, hd); pools (N+1, bs, KV, hd); tables (B, nb) int32;
+    """q (B, H, hd); pools (N+1, KV, bs, hd); tables (B, nb) int32;
     kv_len (B,) int32. Returns o (B, H, hd) q.dtype — the gather-then-
     dense re-attend the paged kernel replaces."""
-    k = gather_blocks(k_pool, tables)
-    v = gather_blocks(v_pool, tables)
+    k = jnp.swapaxes(gather_blocks(k_pool, tables), 1, 2)   # (B, KV, L, hd)
+    v = jnp.swapaxes(gather_blocks(v_pool, tables), 1, 2)
     return _flash_ref.decode_fwd(q, k, v, kv_len.reshape(-1, 1),
                                  scale=scale)
 
@@ -40,14 +41,14 @@ def paged_prefill_fwd(q, k_pool, v_pool, tables, q_off, kv_len, *,
     """Chunked-prefill oracle with per-slot query offsets.
 
     q (B, Sq, H, hd) *model* layout — chunk queries, row r of slot b at
-    absolute position ``q_off[b] + r``; pools (N+1, bs, KV, hd) with the
+    absolute position ``q_off[b] + r``; pools (N+1, KV, bs, hd) with the
     chunk's K/V already committed; tables (B, nb) int32; kv_len (B,)
     int32 valid cells. Returns (B, Sq, H, hd) q.dtype. Rows with no live
     key (``kv_len == 0`` — non-admitted slots) emit exact zeros, matching
     the kernel's dry-row convention.
     """
     B, Sq, H, hd = q.shape
-    KV = k_pool.shape[2]
+    KV = k_pool.shape[1]
     k = gather_blocks(k_pool, tables)                # (B, L, KV, hd)
     v = gather_blocks(v_pool, tables)
     kx = jnp.repeat(k, H // KV, axis=2)

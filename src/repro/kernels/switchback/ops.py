@@ -27,6 +27,21 @@ VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 # (DESIGN.md §3).
 FUSED_MAX_CONTRACT = 2048
 
+# Tensor-wise quantize streams full-width row blocks; each input block is
+# kept near this size so its double-buffered pipeline fits v5e's 16 MiB
+# scoped VMEM (a 512-row f32 block of a 5120-wide weight needs 20 MiB).
+QUANT_BLOCK_BYTES = 2 * 1024 * 1024
+
+
+def quant_block_rows(x: jax.Array, block_bytes: int = QUANT_BLOCK_BYTES
+                     ) -> int:
+    """Rows per tensor-quantize block: at most 512 and about
+    ``block_bytes`` of input, a multiple of 32 (int8 sublane packing) —
+    or all of x's rows when fewer."""
+    R, C = x.shape
+    rows = block_bytes // (C * x.dtype.itemsize) // 32 * 32
+    return min(max(rows, 32), 512, R)
+
 
 def choose_blocks(B: int, K: int, M: int) -> tuple[int, int, int]:
     """Static replacement for Triton autotune: largest MXU-aligned tiles
@@ -93,7 +108,7 @@ def tensor_quantize(x: jax.Array, backend: Backend = "xla"):
         return _ref.tensor_quantize(x)
     interp = backend == "pallas_interpret"
     R = x.shape[0]
-    br = min(512, R)
+    br = quant_block_rows(x)
     xp = _pad_to(x, (br, 1))   # zero rows don't change the absmax
     q, s = _k.tensor_quantize(xp, block_rows=br, interpret=interp)
     return q[:R], s

@@ -1,11 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# (the two lines above MUST run before any jax import — jax locks the device
-#  count at first init. Tests may override via REPRO_DRYRUN_DEVICES.)
-if os.environ.get("REPRO_DRYRUN_DEVICES"):
-    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
-                               + os.environ["REPRO_DRYRUN_DEVICES"])
-
 """Multi-pod dry-run: lower + compile every (architecture × input shape ×
 mesh) cell with ShapeDtypeStruct inputs (zero allocation), record
 memory_analysis / cost_analysis / collective schedule, and emit the
@@ -30,9 +22,17 @@ Usage:
         --mesh single --out results/dryrun
     python -m repro.launch.dryrun --all --mesh both
 """
+if __name__ == "__main__":
+    # run as a script: 512 fake host devices (REPRO_DRYRUN_DEVICES=N
+    # overrides), appended before jax's first backend init; importing the
+    # module leaves XLA_FLAGS alone
+    from repro.host_devices import force_host_device_count
+    force_host_device_count(default=512)
+
 import argparse
 import functools
 import json
+import os
 import time
 import traceback
 from typing import Any, Dict, Optional
@@ -56,14 +56,7 @@ from repro.models import encdec as ED
 from repro.models.params import (ParamSpec, abstract_params, default_rules,
                                  logical_to_pspec, specs_to_shardings,
                                  _divisible)
-from repro.train.engine import (batch_shardings, make_engine, make_shard_ctx,
-                                set_mesh)
-
-# mesh/sharding-context helpers now live in the engine (train/engine.py);
-# the serve cells and probes below use the same ones the train step does.
-_set_mesh = set_mesh
-_shard_ctx = make_shard_ctx
-
+from repro.train.engine import batch_shardings, make_engine, make_shard_ctx
 
 # ---------------------------------------------------------------------------
 # per-arch parallel runbook (what makes each model FIT; see DESIGN.md §6)
@@ -131,17 +124,8 @@ def parallel_for(arch: str, multi_pod: bool, overrides: Optional[Dict] = None
 # metrics extraction
 # ---------------------------------------------------------------------------
 
-def _cost_analysis(compiled) -> Dict[str, float]:
-    """compiled.cost_analysis() returns one dict in jax >= 0.5 but a
-    one-per-device list in 0.4.x — normalize to the dict."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def metrics_of(compiled, n_devices: int) -> Dict[str, float]:
-    ca = _cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     colls = collective_summary(hlo, n_devices)
     dots = count_dot_flops_by_dtype(hlo)
@@ -234,7 +218,7 @@ def run_train_cell(arch, cfg, shape, mesh, par, n_micro, policy, probes=True):
     compile_s = time.time() - t0
     print(f"  [full] compiled in {compile_s:.1f}s")
     print("  memory:", compiled.memory_analysis())
-    ca = _cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     print("  cost: flops/dev=%.3e bytes/dev=%.3e" % (
         ca.get("flops", 0), ca.get("bytes accessed", 0)))
     parts = [("full", 1, metrics_of(compiled, mesh.size))]
@@ -287,7 +271,7 @@ def train_probes(arch, cfg, shape, mesh, par, n_micro, policy, rules,
             return jax.grad(lambda p: bundle.loss_fn(
                 p, mb, policy, par)[0])(params)
 
-        with _set_mesh(mesh), _shard_ctx(mesh, par):
+        with jax.set_mesh(mesh), make_shard_ctx(mesh, par):
             c = jax.jit(micro, in_shardings=(params_shard, mb_shard)) \
                 .lower(params_abs, mb_inputs).compile()
         parts.append(("micro", n_micro - 1, metrics_of(c, mesh.size)))
@@ -329,7 +313,7 @@ def train_probes(arch, cfg, shape, mesh, par, n_micro, policy, rules,
                     f = TF._maybe_remat(f, par)
                     return jax.grad(f, argnums=(0, 1))(gp, x)
                 args, shards = (gp_abs, x_abs), (gp_shard, act_sh)
-            with _set_mesh(mesh), _shard_ctx(mesh, par):
+            with jax.set_mesh(mesh), make_shard_ctx(mesh, par):
                 c = jax.jit(probe, in_shardings=shards).lower(*args).compile()
             parts.append((which, count * max(n_micro, 1),
                           metrics_of(c, mesh.size)))
@@ -351,7 +335,7 @@ def train_probes(arch, cfg, shape, mesh, par, n_micro, policy, rules,
             f = TF._maybe_remat(f, par)
             return jax.grad(f, argnums=(0, 1))(gp, x)
 
-        with _set_mesh(mesh), _shard_ctx(mesh, par):
+        with jax.set_mesh(mesh), make_shard_ctx(mesh, par):
             c = jax.jit(probe, in_shardings=(gp_shard, act_sh)) \
                 .lower(gp_abs, x_abs).compile()
         parts.append(("group", (G - 1) * max(n_micro, 1),
@@ -384,7 +368,7 @@ def clip_probes(cfg: CLIPConfig, mesh, par, policy, rules):
             f = TF._maybe_remat(f, par)
             return jax.grad(f, argnums=(0, 1))(gp, x)
 
-        with _set_mesh(mesh), _shard_ctx(mesh, par):
+        with jax.set_mesh(mesh), make_shard_ctx(mesh, par):
             c = jax.jit(probe, in_shardings=(gp_shard, x_sh)) \
                 .lower(gp_abs, x_abs).compile()
         parts.append((name, L - 1, metrics_of(c, mesh.size)))
@@ -403,7 +387,7 @@ def run_serve_cell(arch, cfg, shape, mesh, par, policy, probes=True):
     B, S = shape.global_batch, shape.seq_len
     parts = []
 
-    with _set_mesh(mesh), _shard_ctx(mesh, par):
+    with jax.set_mesh(mesh), make_shard_ctx(mesh, par):
         if shape.kind == "prefill":
             if cfg.family == "encdec":
                 def prefill(params, batch):
@@ -448,9 +432,9 @@ def run_serve_cell(arch, cfg, shape, mesh, par, policy, probes=True):
                     parallel=par, batch=B, max_len=S),
                     params_abs, sds((B, src_len, cfg.d_model), jnp.bfloat16))
                 cache_sh = NamedSharding(mesh, _divisible(
-                    (cfg.n_layers, B, S, cfg.n_kv_heads, cfg.hd),
+                    (cfg.n_layers, B, cfg.n_kv_heads, S, cfg.hd),
                     logical_to_pspec(
-                        ("layers", "batch", "cache_seq", "kv_heads", None),
+                        ("layers", "batch", "kv_heads", "cache_seq", None),
                         rules), mesh))
                 state_shard = ED.EncDecDecodeState(
                     type(state_abs.self_caches)(
@@ -517,7 +501,7 @@ def serve_group_probe(cfg, shape, mesh, par, policy, rules, *, decode):
             out, ns, _ = TF.group_apply(x, gp, cfg, policy, par,
                                         positions=jnp.arange(1), states=st)
             return out, ns
-        with _set_mesh(mesh), _shard_ctx(mesh, par):
+        with jax.set_mesh(mesh), make_shard_ctx(mesh, par):
             c = jax.jit(probe, in_shardings=(gp_shard, st_shard, x_sh),
                         donate_argnums=(1,)) \
                 .lower(gp_abs, st_abs, x_abs).compile()
@@ -533,7 +517,7 @@ def serve_group_probe(cfg, shape, mesh, par, policy, rules, *, decode):
             out, _, _ = TF.group_apply(x, gp, cfg, policy, par,
                                        positions=positions)
             return out
-        with _set_mesh(mesh), _shard_ctx(mesh, par):
+        with jax.set_mesh(mesh), make_shard_ctx(mesh, par):
             c = jax.jit(probe, in_shardings=(gp_shard, x_sh)) \
                 .lower(gp_abs, x_abs).compile()
     return [("group", G - 1, metrics_of(c, mesh.size))]

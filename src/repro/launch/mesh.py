@@ -9,16 +9,10 @@ traffic is the once-per-step gradient reduction.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 names explicit/auto mesh axis types
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - older jax: all axes are Auto
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

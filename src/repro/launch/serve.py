@@ -5,12 +5,15 @@ and benchmarks exercise; ``--quant-mode int8_switchback`` +
 ``--kernel-backend pallas_interpret`` serves through the SwitchBack int8
 kernels (DESIGN.md §8).
 
-    PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m \
+    PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m-reduced \
         --max-batch 4 --n-requests 8 --new-tokens 16
 
     # sharded serving on forced host devices:
-    PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m \
+    PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m-reduced \
         --mesh test --devices 8 --n-requests 8
+
+``--arch NAME`` serves the published config of NAME; ``NAME-reduced`` (the
+default, ``smollm-360m-reduced``) is its shrunken CPU-sized twin.
 """
 from __future__ import annotations
 
@@ -24,7 +27,8 @@ force_host_device_count()
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro.configs import ALL_ARCHS, get_reduced_config  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
+from repro.configs import ALL_ARCHS, REDUCED_ARCHS, get_config  # noqa: E402
 from repro.configs.base import ServeConfig  # noqa: E402
 from repro.models import build  # noqa: E402
 from repro.serve import make_serve_engine  # noqa: E402
@@ -76,7 +80,8 @@ def decode_step_fallback(cfg, args, *, reason: str):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="smollm-360m", choices=ALL_ARCHS)
+    ap.add_argument("--arch", default="smollm-360m-reduced",
+                    choices=ALL_ARCHS + REDUCED_ARCHS)
     ap.add_argument("--max-batch", type=int, default=4,
                     help="decode-batch slots (continuous batching width)")
     ap.add_argument("--max-len", type=int, default=128,
@@ -142,7 +147,8 @@ def main():
     args = ap.parse_args()
 
     from repro.launch.mesh import make_cli_mesh
-    cfg = get_reduced_config(args.arch)
+    enable_compile_cache()
+    cfg = get_config(args.arch)
     mesh = make_cli_mesh(args.mesh)
     scfg = ServeConfig(max_batch=args.max_batch, max_len=args.max_len,
                        temperature=args.temperature,

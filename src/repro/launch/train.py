@@ -1,13 +1,14 @@
 """Training launcher CLI — every mode runs the same sharded TrainEngine.
 
-* default (this CPU container): trains the reduced config of ``--arch`` on
-  synthetic data end-to-end through the engine on a mesh over the local
-  devices — the same jitted, donated, sharded step the production path and
-  the dry-run compile.
+* ``--arch NAME`` trains the published config of NAME; ``NAME-reduced``
+  (the default, ``smollm-360m-reduced``) is its shrunken CPU-sized twin.
+  Either trains on synthetic data end-to-end through the engine on a mesh
+  over the local devices — the same jitted, donated, sharded step the
+  production path and the dry-run compile.
 * ``--mesh test`` with forced host devices exercises real partitioning:
 
     PYTHONPATH=src REPRO_DRYRUN_DEVICES=8 python -m repro.launch.train \
-        --arch smollm-360m --steps 20 --mesh test
+        --arch smollm-360m-reduced --steps 20 --mesh test
 
 * ``--mesh single|multi`` builds the production runbook meshes (shrunk
   proportionally when fewer devices exist, as in the dry-run).
@@ -24,7 +25,8 @@ force_host_device_count()
 import jax
 import jax.numpy as jnp
 
-from repro.configs import ALL_ARCHS, get_reduced_config
+from repro.compile_cache import enable_compile_cache
+from repro.configs import ALL_ARCHS, REDUCED_ARCHS, get_config
 from repro.configs.base import (CLIPConfig, ParallelConfig, SupervisorConfig,
                                 TelemetryConfig, TrainConfig)
 from repro.core.precision import QuantPolicy
@@ -59,7 +61,8 @@ def make_data(cfg, batch: int, seq: int):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="smollm-360m", choices=ALL_ARCHS)
+    ap.add_argument("--arch", default="smollm-360m-reduced",
+                    choices=ALL_ARCHS + REDUCED_ARCHS)
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
@@ -113,7 +116,8 @@ def main():
     ap.add_argument("--profile-dir", default="/tmp/repro-profile")
     args = ap.parse_args()
 
-    cfg = get_reduced_config(args.arch)
+    enable_compile_cache()
+    cfg = get_config(args.arch)
     bundle = build(cfg)
     mesh = make_cli_mesh(args.mesh)
     par = ParallelConfig(mesh_shape=tuple(mesh.devices.shape),

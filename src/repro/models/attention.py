@@ -29,8 +29,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.precision import QuantPolicy, quant_linear
+from repro.core.sharding import kernel_call
 from repro.kernels.flash_attention import ops as FA
 from repro.kernels.paged_attention import ops as PA
+from repro.kernels.paged_attention.ref import gather_blocks
 from repro.models import params as PRM
 from repro.models.common import apply_rope, apply_rope_cached
 
@@ -54,9 +56,12 @@ class KVCache(NamedTuple):
       so sequences longer than the cache keep the last ``S_max`` tokens)
       and attention masks each slot to its own valid prefix. This is what
       continuous batching needs: slots admit/evict independently.
+
+    The head axis sits ahead of the token axis so the decode kernel reads
+    one head's tile as a whole trailing (tokens, hd) slab.
     """
-    k: Array          # (B, S_max, n_kv, hd)
-    v: Array          # (B, S_max, n_kv, hd)
+    k: Array          # (B, n_kv, S_max, hd)
+    v: Array          # (B, n_kv, S_max, hd)
     length: Array     # int32 — scalar, or (B,) per-slot (see above)
 
 
@@ -183,9 +188,11 @@ def _core_attention(q: Array, k: Array, v: Array, *, causal: bool,
     XLA paths expand heads with ``jnp.repeat`` as before. ``impl="dense"``
     forces the oracle regardless of backend."""
     if impl != "dense" and policy.backend in FLASH_BACKENDS:
-        return FA.flash_attention(q, k, v, causal=causal,
-                                  backend=policy.backend,
-                                  block_q=block_q, block_k=block_k)
+        return kernel_call(
+            functools.partial(FA.flash_attention, causal=causal,
+                              backend=policy.backend, block_q=block_q,
+                              block_k=block_k),
+            q, k, v, batch_dims=(0, 0, 0), backend=policy.backend)
     n_heads = q.shape[2]
     kx = _expand_kv(k, n_heads)
     vx = _expand_kv(v, n_heads)
@@ -218,7 +225,7 @@ def attention_block(x: Array, p: dict, cfg, policy: QuantPolicy, *,
 # ---------------------------------------------------------------------------
 
 def init_kv_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
-    shape = (batch, max_len, cfg.n_kv_heads, cfg.hd)
+    shape = (batch, cfg.n_kv_heads, max_len, cfg.hd)
     return KVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype),
                    jnp.zeros((), jnp.int32))
 
@@ -247,7 +254,7 @@ def attention_decode_step(x: Array, cache: KVCache, p: dict, cfg,
     """
     B = x.shape[0]
     per_slot = cache.length.ndim == 1
-    S_max = cache.k.shape[1]
+    S_max = cache.k.shape[2]
     if per_slot:
         pos = cache.length[:, None]                      # (B, 1) per-slot pos
     else:
@@ -262,23 +269,30 @@ def attention_decode_step(x: Array, cache: KVCache, p: dict, cfg,
     if per_slot:
         write_at = cache.length % S_max                  # ring write position
         rows = jnp.arange(B)
-        k_cache = cache.k.at[rows, write_at].set(k[:, 0].astype(cache.k.dtype))
-        v_cache = cache.v.at[rows, write_at].set(v[:, 0].astype(cache.v.dtype))
+        k_cache = cache.k.at[rows, :, write_at].set(
+            k[:, 0].astype(cache.k.dtype))
+        v_cache = cache.v.at[rows, :, write_at].set(
+            v[:, 0].astype(cache.v.dtype))
         valid = jnp.minimum(cache.length + 1, S_max)     # (B,)
         kv_len = valid[:, None, None, None]
     else:
         k_cache = jax.lax.dynamic_update_slice_in_dim(
-            cache.k, k.astype(cache.k.dtype), cache.length, axis=1)
+            cache.k, jnp.swapaxes(k, 1, 2).astype(cache.k.dtype),
+            cache.length, axis=2)
         v_cache = jax.lax.dynamic_update_slice_in_dim(
-            cache.v, v.astype(cache.v.dtype), cache.length, axis=1)
+            cache.v, jnp.swapaxes(v, 1, 2).astype(cache.v.dtype),
+            cache.length, axis=2)
         valid = jnp.broadcast_to(cache.length + 1, (B,))
         kv_len = cache.length + 1
     if impl != "dense" and policy.backend in FLASH_BACKENDS:
-        o = FA.decode_attention(q, k_cache, v_cache, valid,
-                                backend=policy.backend, block_k=block_k)
+        o = kernel_call(
+            functools.partial(FA.decode_attention, backend=policy.backend,
+                              block_k=block_k),
+            q, k_cache, v_cache, valid, batch_dims=(0, 0, 0, 0),
+            backend=policy.backend)
     else:
-        kx = _expand_kv(k_cache, cfg.n_heads)
-        vx = _expand_kv(v_cache, cfg.n_heads)
+        kx = _expand_kv(jnp.swapaxes(k_cache, 1, 2), cfg.n_heads)
+        vx = _expand_kv(jnp.swapaxes(v_cache, 1, 2), cfg.n_heads)
         o = dense_attention(q, kx, vx, causal=False, kv_len=kv_len)
     o = o.reshape(B, 1, cfg.n_heads * cfg.hd)
     wo = PRM.use_weight(p["wo"], ("heads", "embed"), policy.compute_dtype)
@@ -323,10 +337,10 @@ def attention_prefill(x: Array, cache: KVCache, p: dict, cfg,
     wo = PRM.use_weight(p["wo"], ("heads", "embed"), policy.compute_dtype)
     out = quant_linear(o, wo, policy=policy)
     sel = admit[:, None, None, None]
-    k_cache = jnp.where(sel, cache.k.at[:, :S].set(k.astype(cache.k.dtype)),
-                        cache.k)
-    v_cache = jnp.where(sel, cache.v.at[:, :S].set(v.astype(cache.v.dtype)),
-                        cache.v)
+    k_cache = jnp.where(sel, cache.k.at[:, :, :S].set(
+        jnp.swapaxes(k, 1, 2).astype(cache.k.dtype)), cache.k)
+    v_cache = jnp.where(sel, cache.v.at[:, :, :S].set(
+        jnp.swapaxes(v, 1, 2).astype(cache.v.dtype)), cache.v)
     return out, KVCache(k_cache, v_cache, cache.length)
 
 
@@ -338,27 +352,27 @@ class PagedKVCache(NamedTuple):
     """Block-pool KV cache for paged serving.
 
     ``k``/``v`` are pools of physical blocks shared by every batch slot,
-    shape (num_blocks + 1, block_size, n_kv, hd); the **last** block is
+    shape (num_blocks + 1, n_kv, block_size, hd); the **last** block is
     the trash block that absorbs writes from idle slots and masked pad
     positions (racy writes there are by construction never read). Which
     logical cell of which slot lives in which physical block is decided
     host-side (serve/paged/block_pool.py) and rides into the jitted steps
     as a (B, n_blocks_per_slot) int32 **block table**: cell ``j*bs + o``
-    of slot b is pool cell ``(tables[b, j], o)``. ``length`` is the
+    of slot b is pool cell ``(tables[b, j], :, o)``. ``length`` is the
     per-slot absolute token count — same semantics as the per-slot ring
     cache, but cache memory scales with allocated blocks (live tokens),
     not max_batch × max_len.
     """
-    k: Array          # (num_blocks + 1, block_size, n_kv, hd)
+    k: Array          # (num_blocks + 1, n_kv, block_size, hd)
     v: Array
     length: Array     # (B,) int32 absolute tokens per slot
 
 
 def _paged_commit(buf: Array, vals: Array, phys: Array, off: Array) -> Array:
-    """Scatter token KVs into pool cells. buf (N+1, bs, KV, hd); vals
+    """Scatter token KVs into pool cells. buf (N+1, KV, bs, hd); vals
     (T, KV, hd); phys/off (T,) int32. Masked writes are routed to the
     trash block by the caller; duplicate targets only ever occur there."""
-    return buf.at[phys, off].set(vals.astype(buf.dtype))
+    return buf.at[phys, :, off].set(vals.astype(buf.dtype))
 
 
 def attention_paged_prefill(x: Array, cache: PagedKVCache, tables: Array,
@@ -395,7 +409,7 @@ def attention_paged_prefill(x: Array, cache: PagedKVCache, tables: Array,
     dtype agree — which is what the parity tests pin."""
     B, S, _ = x.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    bs, nb = cache.k.shape[1], tables.shape[1]
+    bs, nb = cache.k.shape[2], tables.shape[1]
     trash = cache.k.shape[0] - 1
     positions = pref_lens[:, None] + jnp.arange(S)[None, :]   # (B, S) abs
     q, k, v = qkv_project(x, p, cfg, policy)
@@ -424,8 +438,10 @@ def attention_paged_prefill(x: Array, cache: PagedKVCache, tables: Array,
         # kernel reads prefix and suffix through the table in one sweep —
         # no (B, nb*bs, H, hd) gather+concat materialisation
         kv_valid = jnp.where(admit, prompt_lens, 0)
-        o = PA.paged_prefill_attention(q, k_buf, v_buf, tables, pref_lens,
-                                       kv_valid, backend=backend)
+        o = kernel_call(
+            functools.partial(PA.paged_prefill_attention, backend=backend),
+            q, k_buf, v_buf, tables, pref_lens, kv_valid,
+            batch_dims=(0, None, None, 0, 0, 0), backend=backend)
     else:
         # resident prefix, gathered through the table in logical order
         # (from the pre-commit pools — commit cells are masked dead below,
@@ -440,8 +456,8 @@ def attention_paged_prefill(x: Array, cache: PagedKVCache, tables: Array,
         # token parity.
         k_suf = k.astype(cache.k.dtype).astype(k.dtype)
         v_suf = v.astype(cache.v.dtype).astype(v.dtype)
-        k_pref = cache.k[tables].reshape(B, nb * bs, KV, hd)
-        v_pref = cache.v[tables].reshape(B, nb * bs, KV, hd)
+        k_pref = gather_blocks(cache.k, tables)       # (B, nb*bs, KV, hd)
+        v_pref = gather_blocks(cache.v, tables)
         kx = jnp.concatenate([_expand_kv(k_pref, H), _expand_kv(k_suf, H)],
                              axis=1)
         vx = jnp.concatenate([_expand_kv(v_pref, H), _expand_kv(v_suf, H)],
@@ -487,7 +503,7 @@ def attention_paged_decode_step(x: Array, cache: PagedKVCache,
     """
     B = x.shape[0]
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    bs, nb = cache.k.shape[1], tables.shape[1]
+    bs, nb = cache.k.shape[2], tables.shape[1]
     pos = cache.length[:, None]                              # (B, 1) abs
     q, k, v = qkv_project(x, p, cfg, policy)
     if rope_cache is not None:
@@ -504,8 +520,10 @@ def attention_paged_decode_step(x: Array, cache: PagedKVCache,
     valid = jnp.minimum(cache.length + 1, nb * bs)           # (B,)
     backend = (policy.backend if impl != "dense"
                and policy.backend in FLASH_BACKENDS else "xla")
-    o = PA.paged_decode_attention(q, k_buf, v_buf, tables, valid,
-                                  backend=backend)
+    o = kernel_call(
+        functools.partial(PA.paged_decode_attention, backend=backend),
+        q, k_buf, v_buf, tables, valid, batch_dims=(0, None, None, 0, 0),
+        backend=backend)
     o = o.reshape(B, 1, H * hd)
     wo = PRM.use_weight(p["wo"], ("heads", "embed"), policy.compute_dtype)
     out = quant_linear(o, wo, policy=policy)

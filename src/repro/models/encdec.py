@@ -150,8 +150,8 @@ def init_decode_state(params, frames, cfg, policy, parallel, batch: int,
     enc_out = encode(params, frames, cfg, policy, parallel)
     L = cfg.n_layers
     caches = ATT.KVCache(
-        jnp.zeros((L, batch, max_len, cfg.n_kv_heads, cfg.hd), dtype),
-        jnp.zeros((L, batch, max_len, cfg.n_kv_heads, cfg.hd), dtype),
+        jnp.zeros((L, batch, cfg.n_kv_heads, max_len, cfg.hd), dtype),
+        jnp.zeros((L, batch, cfg.n_kv_heads, max_len, cfg.hd), dtype),
         jnp.zeros((L,), jnp.int32))
     return EncDecDecodeState(caches, enc_out)
 

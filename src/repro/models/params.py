@@ -12,11 +12,15 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Callable, Dict, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding
+
+# model code reaches the sharding context through this module (PRM.*)
+from repro.core.sharding import (  # noqa: F401
+    ShardCtx, _divisible, constrain, logical_to_pspec)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -119,39 +123,6 @@ def default_rules(parallel) -> Dict[str, object]:
     return rules
 
 
-def logical_to_pspec(logical: Tuple[Optional[str], ...], rules) -> P:
-    axes = []
-    used = set()
-    for name in logical:
-        if name is None:
-            axes.append(None)
-            continue
-        mesh_axis = rules.get(name)
-        # a mesh axis may appear once per pspec; later duplicates unshard
-        parts = (mesh_axis if isinstance(mesh_axis, tuple)
-                 else (mesh_axis,)) if mesh_axis is not None else ()
-        if mesh_axis is None or any(p in used for p in parts):
-            axes.append(None)
-        else:
-            axes.append(mesh_axis)
-            used.update(parts)
-    return P(*axes)
-
-
-def _divisible(shape, pspec: P, mesh: Mesh) -> P:
-    """Drop shardings that don't divide the dim (e.g. kv_heads=1 over 16)."""
-    out = []
-    for dim, ax in zip(shape, tuple(pspec) + (None,) * (len(shape) - len(pspec))):
-        if ax is None:
-            out.append(None)
-            continue
-        size = 1
-        for a in (ax if isinstance(ax, tuple) else (ax,)):
-            size *= mesh.shape[a]
-        out.append(ax if dim % size == 0 else None)
-    return P(*out)
-
-
 def specs_to_shardings(specs, mesh: Mesh, rules):
     def one(s: ParamSpec):
         ps = logical_to_pspec(s.logical, rules)
@@ -167,59 +138,8 @@ def specs_to_pspecs(specs, mesh: Mesh, rules):
 
 
 # ---------------------------------------------------------------------------
-# activation sharding constraint helper
+# weights under the active ShardCtx
 # ---------------------------------------------------------------------------
-
-class ShardCtx:
-    """Carries (mesh, rules) so model code can pin activation shardings:
-        x = ctx.constrain(x, ("batch", "seq", "embed"))
-    Outside jit/mesh (smoke tests on 1 device) it is a no-op.
-
-    Enter it around TRACING (e.g. ``with ShardCtx(...): f.lower(...)``) —
-    the constraints are staged into the jaxpr at trace time.
-
-    ``gather_fsdp``: when True, `use_weight` inserts an explicit
-    resharding of FSDP(data)-sharded weights to their no-FSDP sharding in
-    the compute dtype before each use — an all-gather of *weights* (ZeRO-3
-    semantics) instead of letting GSPMD partial-sum *activations*. §Perf
-    iteration 1.
-    """
-    _current: Optional["ShardCtx"] = None
-
-    def __init__(self, mesh: Optional[Mesh], rules: Optional[dict],
-                 rules_nofsdp: Optional[dict] = None,
-                 gather_fsdp: bool = False, gather_wire: str = "bf16",
-                 moe_grouped: bool = True):
-        self.mesh = mesh
-        self.rules = rules
-        self.rules_nofsdp = rules_nofsdp or rules
-        self.gather_fsdp = gather_fsdp
-        self.gather_wire = gather_wire
-        self.moe_grouped = moe_grouped
-
-    def constrain(self, x: jax.Array, logical: Tuple[Optional[str], ...]):
-        if self.mesh is None or self.rules is None:
-            return x
-        ps = logical_to_pspec(logical, self.rules)
-        ps = _divisible(x.shape, ps, self.mesh)
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(self.mesh, ps))
-
-    def __enter__(self):
-        self._prev = ShardCtx._current
-        ShardCtx._current = self
-        return self
-
-    def __exit__(self, *a):
-        ShardCtx._current = self._prev
-
-
-def constrain(x: jax.Array, logical: Tuple[Optional[str], ...]) -> jax.Array:
-    ctx = ShardCtx._current
-    if ctx is None:
-        return x
-    return ctx.constrain(x, logical)
-
 
 def use_weight(w: jax.Array, logical: Tuple[Optional[str], ...],
                dtype=None) -> jax.Array:

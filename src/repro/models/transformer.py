@@ -413,8 +413,8 @@ def init_decode_state(cfg: ModelConfig, batch: int, max_len: int,
         kind = cfg.layer_kind(i)
         if kind == "attn":
             return ATT.KVCache(
-                jnp.zeros((G, batch, max_len, cfg.n_kv_heads, cfg.hd), dtype),
-                jnp.zeros((G, batch, max_len, cfg.n_kv_heads, cfg.hd), dtype),
+                jnp.zeros((G, batch, cfg.n_kv_heads, max_len, cfg.hd), dtype),
+                jnp.zeros((G, batch, cfg.n_kv_heads, max_len, cfg.hd), dtype),
                 jnp.zeros((G,), jnp.int32))
         if kind == "mamba":
             d_in = cfg.mamba.expand * cfg.d_model
@@ -438,7 +438,7 @@ def decode_state_logical_axes(cfg: ModelConfig):
     def one(i):
         kind = cfg.layer_kind(i)
         if kind == "attn":
-            ax = ("layers", "batch", "cache_seq", "kv_heads", None)
+            ax = ("layers", "batch", "kv_heads", "cache_seq", None)
             return ATT.KVCache(ax, ax, ("layers",))
         if kind == "mamba":
             return MambaState(("layers", "batch", None, "mlp"),
@@ -507,14 +507,14 @@ def init_serve_state(cfg: ModelConfig, max_batch: int, max_len: int,
     """Per-slot (continuous-batching) KV caches, stacked over groups.
 
     Layout per position-in-period: ``KVCache`` with k/v of shape
-    (G, max_batch, max_len, n_kv_heads, hd) and per-slot lengths (G, B).
+    (G, max_batch, n_kv_heads, max_len, hd) and per-slot lengths (G, B).
     Unlike ``init_decode_state`` every batch slot tracks its own length, so
     slots can hold sequences at different positions (admit/evict freely).
     """
     _require_all_attention(cfg, "init_serve_state")
     P = period(cfg)
     G = n_groups(cfg)
-    shape = (G, max_batch, max_len, cfg.n_kv_heads, cfg.hd)
+    shape = (G, max_batch, cfg.n_kv_heads, max_len, cfg.hd)
     return {f"pos{i}": ATT.KVCache(jnp.zeros(shape, dtype),
                                    jnp.zeros(shape, dtype),
                                    jnp.zeros((G, max_batch), jnp.int32))
@@ -526,7 +526,7 @@ def serve_state_logical_axes(cfg: ModelConfig):
     decode state (batch over data, kv_heads over model); lengths shard
     over batch with the slots they describe."""
     P = period(cfg)
-    ax = ("layers", "batch", "cache_seq", "kv_heads", None)
+    ax = ("layers", "batch", "kv_heads", "cache_seq", None)
     return {f"pos{i}": ATT.KVCache(ax, ax, ("layers", "batch"))
             for i in range(P)}
 
@@ -537,7 +537,7 @@ def init_paged_serve_state(cfg: ModelConfig, num_blocks: int,
     """Block-pool KV caches, stacked over groups (DESIGN.md §10).
 
     Layout per position-in-period: ``PagedKVCache`` with k/v pools of
-    shape (G, num_blocks + 1, block_size, n_kv_heads, hd) — one extra
+    shape (G, num_blocks + 1, n_kv_heads, block_size, hd) — one extra
     *trash* block at index ``num_blocks`` absorbs masked writes — and
     per-slot absolute lengths (G, max_batch). Unlike
     :func:`init_serve_state` the cache footprint scales with
@@ -548,7 +548,7 @@ def init_paged_serve_state(cfg: ModelConfig, num_blocks: int,
     _require_all_attention(cfg, "init_paged_serve_state")
     P = period(cfg)
     G = n_groups(cfg)
-    shape = (G, num_blocks + 1, block_size, cfg.n_kv_heads, cfg.hd)
+    shape = (G, num_blocks + 1, cfg.n_kv_heads, block_size, cfg.hd)
     return {f"pos{i}": ATT.PagedKVCache(jnp.zeros(shape, dtype),
                                         jnp.zeros(shape, dtype),
                                         jnp.zeros((G, max_batch), jnp.int32))
@@ -582,7 +582,7 @@ def paged_state_logical_axes(cfg: ModelConfig):
     cache's batch dim does — it replicates there and shards kv_heads over
     ``model``; lengths shard over batch with the slots they describe."""
     P = period(cfg)
-    ax = ("layers", None, None, "kv_heads", None)
+    ax = ("layers", None, "kv_heads", None, None)
     return {f"pos{i}": ATT.PagedKVCache(ax, ax, ("layers", "batch"))
             for i in range(P)}
 

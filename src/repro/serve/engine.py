@@ -69,7 +69,7 @@ from repro.models.params import default_rules, init_params, specs_to_shardings
 from repro.serve.scheduler import SlotScheduler
 from repro.serve.spec import NgramProposer
 from repro.telemetry import MetricsRegistry, as_telemetry
-from repro.train.engine import _axes_to_shardings, make_shard_ctx, set_mesh
+from repro.train.engine import _axes_to_shardings, make_shard_ctx
 
 #: supervisor counters surfaced in the stats row (stability_source=)
 SUPERVISOR_KEYS = ("rewinds", "data_steps_skipped", "incidents",
@@ -175,14 +175,14 @@ class ServeEngine:
         round-trip). Every slot starts empty (length 0). The jitted init is
         built once in ``make_serve_engine`` so per-generate() calls hit the
         compile cache."""
-        with set_mesh(self.mesh), self.shard_ctx():
+        with jax.set_mesh(self.mesh), self.shard_ctx():
             return self.jit_init_cache()
 
     def init_params(self, seed: int = 0):
         """Randomly initialized params already placed on the engine's
         param shardings (for synthetic serving / benchmarks; real
         deployments restore a checkpoint and ``shard_params`` it)."""
-        with set_mesh(self.mesh), self.shard_ctx():
+        with jax.set_mesh(self.mesh), self.shard_ctx():
             return jax.jit(lambda k: init_params(self.specs, k),
                            out_shardings=self.param_shardings)(
                 jax.random.PRNGKey(seed))
@@ -203,7 +203,7 @@ class ServeEngine:
         The input cache's buffers are donated. Only admitted slots' cache
         rows and lengths change — live slots are byte-identical.
         """
-        with set_mesh(self.mesh), self.shard_ctx():
+        with jax.set_mesh(self.mesh), self.shard_ctx():
             return self.jit_prefill(params, cache,
                                     jnp.asarray(tokens, jnp.int32),
                                     jnp.asarray(prompt_lens, jnp.int32),
@@ -215,7 +215,7 @@ class ServeEngine:
         one (empty slots decode garbage that admission later overwrites);
         the input cache is donated so the ring buffer updates in place.
         """
-        with set_mesh(self.mesh), self.shard_ctx():
+        with jax.set_mesh(self.mesh), self.shard_ctx():
             return self.jit_decode(params, cache,
                                    jnp.asarray(tokens, jnp.int32))
 
@@ -227,7 +227,7 @@ class ServeEngine:
         prompt_lens: full prompt lengths; tables: (max_batch,
         blocks_per_slot) int32. Returns ``(logits (B, 1, V), new_cache)``
         — each slot's last valid prompt position."""
-        with set_mesh(self.mesh), self.shard_ctx():
+        with jax.set_mesh(self.mesh), self.shard_ctx():
             return self.jit_prefill(params, cache,
                                     jnp.asarray(tables, jnp.int32),
                                     jnp.asarray(tokens, jnp.int32),
@@ -239,7 +239,7 @@ class ServeEngine:
         """One paged decode step: tokens (max_batch, 1) int32 appended
         through the block table. Same donation/lockstep-length semantics
         as :meth:`decode`."""
-        with set_mesh(self.mesh), self.shard_ctx():
+        with jax.set_mesh(self.mesh), self.shard_ctx():
             return self.jit_decode(params, cache,
                                    jnp.asarray(tables, jnp.int32),
                                    jnp.asarray(tokens, jnp.int32))
@@ -254,7 +254,7 @@ class ServeEngine:
         Returns ``(argmax (B, spec_k+1) int32, new_cache)`` — the
         model's greedy token at every verified position; the host
         compares drafts against it to find the accepted prefix."""
-        with set_mesh(self.mesh), self.shard_ctx():
+        with jax.set_mesh(self.mesh), self.shard_ctx():
             return self.jit_verify(params, cache,
                                    jnp.asarray(tables, jnp.int32),
                                    jnp.asarray(tokens, jnp.int32),
@@ -267,7 +267,7 @@ class ServeEngine:
         (``lens`` (max_batch,) int32, cache donated) — the lazy re-sync
         after a speculative rejection left the device leaf over-counting
         (see ``transformer.set_serve_lengths``)."""
-        with set_mesh(self.mesh), self.shard_ctx():
+        with jax.set_mesh(self.mesh), self.shard_ctx():
             return self.jit_set_len(cache, jnp.asarray(lens, jnp.int32))
 
     def sample(self, logits, key, uids, steps):

@@ -2,7 +2,7 @@
 
 Pure-Python host-side bookkeeping (no jax dependency — the same
 discipline as ``serve/scheduler.py``): the *device* side is a fixed pool
-of ``(num_blocks + 1, block_size, KV, hd)`` K/V blocks per layer (the
+of ``(num_blocks + 1, KV, block_size, hd)`` K/V blocks per layer (the
 last block is the shared **trash block** that absorbs writes from idle
 slots and masked pad positions); this module decides which physical
 block holds which request's logical block.

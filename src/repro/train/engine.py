@@ -38,27 +38,6 @@ from repro.models.params import (_divisible, abstract_params, default_rules,
 from repro.train.train_step import (TrainState, make_train_setup,
                                     make_train_step)
 
-def _pin_sharding_invariant_rng():
-    """Sharding-invariant RNG (the default from jax 0.5): without it the
-    partitioned init draws different values per mesh, so a sharded run
-    could never match the single-device trajectory it must reproduce.
-    Called from make_engine — importing this module has no side effect,
-    but any process that builds an engine opts in (the flag changes the
-    values drawn for a given key on jax 0.4.x)."""
-    try:
-        jax.config.update("jax_threefry_partitionable", True)
-    except Exception as e:  # pragma: no cover - flag removed in future jax
-        import warnings
-        warnings.warn(f"could not enable jax_threefry_partitionable ({e}); "
-                      "sharded init may not match single-device init")
-
-
-def set_mesh(mesh):
-    """jax.set_mesh appeared in jax 0.5; older jax uses the Mesh itself as
-    the context manager with identical scoping semantics."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-
-
 def make_shard_ctx(mesh, parallel: ParallelConfig) -> PRM.ShardCtx:
     """Trace-time sharding context: activates activation constraints and
     (when parallel.fsdp_gather_weights) the explicit ZeRO-3 weight gathers."""
@@ -124,7 +103,7 @@ class TrainEngine:
 
     def step(self, state: TrainState, batch) -> tuple:
         """(state, batch) -> (state, metrics); state buffers are donated."""
-        with set_mesh(self.mesh), self.shard_ctx():
+        with jax.set_mesh(self.mesh), self.shard_ctx():
             return self.jit_step(state, batch)
 
     def init_state(self, seed: int = 0) -> TrainState:
@@ -136,7 +115,7 @@ class TrainEngine:
                               self.scaler.init(),
                               jnp.zeros((), jnp.int32),
                               jax.random.PRNGKey(seed))
-        with set_mesh(self.mesh), self.shard_ctx():
+        with jax.set_mesh(self.mesh), self.shard_ctx():
             return jax.jit(init, out_shardings=self.state_shardings)(
                 jax.random.PRNGKey(seed))
 
@@ -162,7 +141,7 @@ class TrainEngine:
     def lower(self, batch_abs=None):
         """Lower the train step against abstract inputs (dry-run path)."""
         batch_abs = self.batch_spec if batch_abs is None else batch_abs
-        with set_mesh(self.mesh), self.shard_ctx():
+        with jax.set_mesh(self.mesh), self.shard_ctx():
             return self.jit_step.lower(self.state_abs, batch_abs)
 
 
@@ -177,7 +156,6 @@ def make_engine(model, train_cfg: TrainConfig, parallel: ParallelConfig,
     global batch's shapes (only shapes/dtypes are used).
     ``donate=False`` exists for the benchmark's no-donation baseline.
     """
-    _pin_sharding_invariant_rng()
     from repro.models import build
     if isinstance(model, str):
         from repro.configs import get_config

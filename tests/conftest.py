@@ -15,10 +15,6 @@ import jax  # noqa: E402
 import pytest  # noqa: E402
 
 jax.config.update("jax_enable_x64", False)
-# The engine pins partitionable threefry at make_engine time (sharding-
-# invariant RNG); pin it for the whole test session so RNG draws don't
-# depend on whether an engine test ran earlier in the collection order.
-jax.config.update("jax_threefry_partitionable", True)
 
 
 @pytest.fixture(scope="session")

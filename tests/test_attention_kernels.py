@@ -265,7 +265,8 @@ def test_decode_matches_dense_per_slot_lengths():
                               causal=False,
                               kv_len=lens[:, None, None, None])
     for be in ("xla", "pallas_interpret"):
-        got = FA.decode_attention(q, k, v, lens, backend=be)
+        got = FA.decode_attention(q, jnp.swapaxes(k, 1, 2),
+                                  jnp.swapaxes(v, 1, 2), lens, backend=be)
         assert _rel(ref, got) <= TOL_F32, be
 
 
@@ -274,8 +275,8 @@ def test_decode_shape_sweep(s, kvh, hd):
     """Odd S_max (non-divisible block fallback), GQA, random lengths."""
     H = 2 * kvh
     q = jax.random.normal(kq, (2, 1, H, hd), jnp.float32)
-    k = jax.random.normal(kk, (2, s, kvh, hd), jnp.float32)
-    v = jax.random.normal(kv, (2, s, kvh, hd), jnp.float32)
+    k = jax.random.normal(kk, (2, kvh, s, hd), jnp.float32)
+    v = jax.random.normal(kv, (2, kvh, s, hd), jnp.float32)
     lens = jnp.array([1 + s // 3, s], jnp.int32)
     ref = FA.decode_attention(q, k, v, lens, backend="xla")
     got = FA.decode_attention(q, k, v, lens, backend="pallas_interpret")
@@ -295,8 +296,8 @@ def test_decode_step_ring_wrap_backend_parity():
          for i, nm in enumerate(("wq", "wk", "wv", "wo"))}
     S_max = 8
     cache = ATT.KVCache(
-        jax.random.normal(kk, (3, S_max, 2, cfg.hd), jnp.float32),
-        jax.random.normal(kv, (3, S_max, 2, cfg.hd), jnp.float32),
+        jax.random.normal(kk, (3, 2, S_max, cfg.hd), jnp.float32),
+        jax.random.normal(kv, (3, 2, S_max, cfg.hd), jnp.float32),
         jnp.array([3, 8, 13], jnp.int32))          # pre-, at-, post-wrap
     x = jax.random.normal(kq, (3, 1, D), jnp.float32)
     outs, caches = {}, {}
@@ -324,8 +325,8 @@ def test_decode_step_scalar_cache_backend_parity():
          for i, (nm, m) in enumerate(
              [("wq", D), ("wk", KVd), ("wv", KVd), ("wo", D)])}
     cache = ATT.KVCache(
-        jax.random.normal(kk, (2, 16, 2, cfg.hd), jnp.float32),
-        jax.random.normal(kv, (2, 16, 2, cfg.hd), jnp.float32),
+        jax.random.normal(kk, (2, 2, 16, cfg.hd), jnp.float32),
+        jax.random.normal(kv, (2, 2, 16, cfg.hd), jnp.float32),
         jnp.asarray(5, jnp.int32))                 # scalar length
     x = jax.random.normal(kq, (2, 1, D), jnp.float32)
     outs = {}

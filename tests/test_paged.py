@@ -215,8 +215,8 @@ def test_paged_kernel_matches_oracle(H, KV, hd, bs, nb):
     B, N = 3, 12
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(keys[0], (B, 1, H, hd))
-    k_pool = jax.random.normal(keys[1], (N + 1, bs, KV, hd))
-    v_pool = jax.random.normal(keys[2], (N + 1, bs, KV, hd))
+    k_pool = jax.random.normal(keys[1], (N + 1, KV, bs, hd))
+    v_pool = jax.random.normal(keys[2], (N + 1, KV, bs, hd))
     rng = np.random.default_rng(3)
     tables = jnp.asarray(rng.permutation(N)[:B * nb].reshape(B, nb))
     # ragged: empty-ish, mid-block, full
@@ -235,8 +235,8 @@ def test_paged_kernel_ignores_dead_table_entries():
     B, H, KV, hd, bs, nb, N = 2, 4, 2, 8, 4, 4, 8
     keys = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(keys[0], (B, 1, H, hd))
-    k_pool = jax.random.normal(keys[1], (N + 1, bs, KV, hd))
-    v_pool = jax.random.normal(keys[2], (N + 1, bs, KV, hd))
+    k_pool = jax.random.normal(keys[1], (N + 1, KV, bs, hd))
+    v_pool = jax.random.normal(keys[2], (N + 1, KV, bs, hd))
     kv_len = jnp.asarray([5, 3], jnp.int32)         # 2 live blocks / 1
     t1 = jnp.asarray([[0, 1, 2, 3], [4, 5, 6, 7]], jnp.int32)
     t2 = jnp.asarray([[0, 1, N, N], [4, N, N, N]], jnp.int32)  # dead->trash
@@ -580,8 +580,8 @@ def test_paged_prefill_kernel_matches_oracle(H, KV, hd, bs, nb):
     B, S, N = 3, 8, 20
     keys = jax.random.split(jax.random.PRNGKey(5), 3)
     q = jax.random.normal(keys[0], (B, S, H, hd))
-    k_pool = jax.random.normal(keys[1], (N + 1, bs, KV, hd))
-    v_pool = jax.random.normal(keys[2], (N + 1, bs, KV, hd))
+    k_pool = jax.random.normal(keys[1], (N + 1, KV, bs, hd))
+    v_pool = jax.random.normal(keys[2], (N + 1, KV, bs, hd))
     rng = np.random.default_rng(5)
     tables = jnp.asarray(rng.permutation(N)[:B * nb].reshape(B, nb))
     q_off = jnp.asarray([0, 5, 0], jnp.int32)
@@ -603,8 +603,8 @@ def test_paged_prefill_kernel_chunked_matches_monolithic():
     N, L = 12, 24
     keys = jax.random.split(jax.random.PRNGKey(6), 3)
     q = jax.random.normal(keys[0], (B, L, H, hd))
-    k_pool = jax.random.normal(keys[1], (N + 1, bs, KV, hd))
-    v_pool = jax.random.normal(keys[2], (N + 1, bs, KV, hd))
+    k_pool = jax.random.normal(keys[1], (N + 1, KV, bs, hd))
+    v_pool = jax.random.normal(keys[2], (N + 1, KV, bs, hd))
     tables = jnp.arange(B * nb, dtype=jnp.int32).reshape(B, nb)
     ones = jnp.ones((B,), jnp.int32)
     for backend in ("xla", "pallas_interpret"):

@@ -98,19 +98,15 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, %r)
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 from repro.distributed.compression import compressed_allreduce_mean, wire_bytes_saved
-try:
-    from jax import shard_map               # jax >= 0.6
-    smap_kw = {"check_vma": False}          # all_gather output is replicated
-except ImportError:
-    from jax.experimental.shard_map import shard_map
-    smap_kw = {"check_rep": False}
 
 mesh = jax.make_mesh((8,), ("data",))
 g = jax.random.normal(jax.random.PRNGKey(0), (8, 64, 32), jnp.float32)
 
 f = shard_map(lambda x: compressed_allreduce_mean(x[0], "data"),
-              mesh=mesh, in_specs=P("data"), out_specs=P(), **smap_kw)
+              mesh=mesh, in_specs=P("data"), out_specs=P(),
+              check_vma=False)          # all_gather output is replicated
 got = f(g)
 want = jnp.mean(g, axis=0)
 err = float(jnp.max(jnp.abs(got - want)))

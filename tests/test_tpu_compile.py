@@ -1,0 +1,142 @@
+"""Compile the main-path Pallas kernels for a described TPU v5e.
+
+No chip is needed: the TPU compiler is installed, and a compile for a
+described ``v5e:2x2`` topology raises what the chip's compiler would —
+misaligned blocks, scalar stores to VMEM, scoped-VMEM overflow. Shapes
+are the published widths: smollm-360m (d_model 960, d_ff 2560, 15 heads
+over 5 KV heads of 64, 4096 tokens) and CLIP ViT-Huge (16 heads of 80,
+77 text tokens, 129 vision tokens after patch dropout).
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and every pytest worker
+imports every test file. Keep these tests in this one file.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels.flash_attention import ops as FA
+from repro.kernels.paged_attention import ops as PA
+from repro.kernels.switchback import ops as SB
+
+D, FF, ROWS = 960, 2560, 4096
+BF, I8, F32, I32 = jnp.bfloat16, jnp.int8, jnp.float32, jnp.int32
+# (heads, kv heads, head dim, sequence)
+SMOLLM = (15, 5, 64, 4096)
+CLIP_TEXT = (16, 16, 80, 77)
+CLIP_VISION = (16, 16, 80, 129)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    return topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep the cache off meanwhile."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def compile_hlo(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+SWITCHBACK = {
+    "tensor_quantize": (lambda w: SB.tensor_quantize(w, backend="pallas"),
+                        [((D, FF), F32)]),
+    "row_quantize": (lambda x: SB.row_quantize(x, backend="pallas"),
+                     [((ROWS, D), BF)]),
+    "fused_fwd": (lambda x, w, s: SB.fused_switchback_fwd(
+        x, w, s, backend="pallas"),
+        [((ROWS, D), BF), ((D, FF), I8), ((1, 1), F32)]),
+    "fused_dgrad": (lambda g, w, s: SB.fused_switchback_dgrad(
+        g, w, s, backend="pallas"),
+        [((ROWS, D), BF), ((FF, D), I8), ((1, 1), F32)]),
+    "wgrad_bf16": (lambda x, g: SB.wgrad_bf16(x, g, backend="pallas"),
+                   [((ROWS, D), BF), ((ROWS, FF), BF)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SWITCHBACK))
+def test_switchback_kernel_compiles_for_v5e(name, one_chip):
+    fn, shapes = SWITCHBACK[name]
+    assert "tpu_custom_call" in compile_hlo(fn, one_chip, *shapes)
+
+
+ATTN_SHAPES = {"smollm": SMOLLM, "clip_text": CLIP_TEXT,
+               "clip_vision": CLIP_VISION}
+
+
+@pytest.mark.parametrize("shape", sorted(ATTN_SHAPES))
+def test_flash_fwd_bwd_compiles_for_v5e(shape, one_chip):
+    H, KV, hd, S = ATTN_SHAPES[shape]
+
+    def fwd_bwd(q, k, v):
+        loss = lambda q, k, v: FA.flash_attention(
+            q, k, v, causal=True, backend="pallas").astype(F32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    hlo = compile_hlo(fwd_bwd, one_chip, ((1, S, H, hd), BF),
+                      ((1, S, KV, hd), BF), ((1, S, KV, hd), BF))
+    assert hlo.count("tpu_custom_call") >= 3      # fwd, dq, dkv
+
+
+@pytest.mark.parametrize("shape", ["smollm", "clip_text"])
+def test_flash_decode_compiles_for_v5e(shape, one_chip):
+    H, KV, hd, _ = ATTN_SHAPES[shape]
+    B, S_max = 8, 1024
+    hlo = compile_hlo(
+        lambda q, k, v, n: FA.decode_attention(q, k, v, n, backend="pallas"),
+        one_chip, ((B, 1, H, hd), BF), ((B, KV, S_max, hd), BF),
+        ((B, KV, S_max, hd), BF), ((B,), I32))
+    assert "tpu_custom_call" in hlo
+
+
+def _pool(KV, hd, N=512, bs=16):
+    return ((N + 1, KV, bs, hd), BF)
+
+
+def test_paged_decode_compiles_for_v5e(one_chip):
+    H, KV, hd, _ = SMOLLM
+    B, nb = 8, 64
+    hlo = compile_hlo(
+        lambda q, k, v, t, n: PA.paged_decode_attention(
+            q, k, v, t, n, backend="pallas"),
+        one_chip, ((B, 1, H, hd), BF), _pool(KV, hd), _pool(KV, hd),
+        ((B, nb), I32), ((B,), I32))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("S", [256, 5])      # prefill chunk, spec verify
+def test_paged_prefill_compiles_for_v5e(S, one_chip):
+    H, KV, hd, _ = SMOLLM
+    B, nb = 8, 64
+    hlo = compile_hlo(
+        lambda q, k, v, t, o, n: PA.paged_prefill_attention(
+            q, k, v, t, o, n, backend="pallas"),
+        one_chip, ((B, S, H, hd), BF), _pool(KV, hd), _pool(KV, hd),
+        ((B, nb), I32), ((B,), I32), ((B,), I32))
+    assert "tpu_custom_call" in hlo
