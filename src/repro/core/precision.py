@@ -119,25 +119,27 @@ def quant_linear(x: Array, w: Array, b: Optional[Array] = None, *,
     Quantized modes run the SwitchBack custom-VJP; 16/32-bit modes run a
     plain dot in the compute dtype with f32 accumulation.
     """
-    if policy.is_quantized:
-        args = (x.astype(policy.compute_dtype), w.astype(jnp.float32))
-        args += () if b is None else (b,)
-        sb = functools.partial(
-            SB.switchback_linear,
-            variant=_SB_VARIANT[policy.mode],
-            fwd_fmt=policy.fwd_fmt, bwd_fmt=policy.bwd_fmt,
-            backend=policy.backend,
-            block_rows=policy.fp8_block_rows,
-            block_cols=policy.fp8_block_cols,
-            fallback_ratio=policy.fp8_fallback_ratio)
-        return kernel_call(sb, *args, batch_dims=(0, None, None)[:len(args)],
-                           backend=policy.backend)
-    cd = (jnp.float32 if policy.mode == "fp32" else policy.compute_dtype)
-    y = jax.lax.dot_general(
-        x.astype(cd), w.astype(cd),
-        dimension_numbers=(((x.ndim - 1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).astype(cd)
-    if b is not None:
-        y = y + b.astype(y.dtype)
-    return y
+    with jax.named_scope("linear"):
+        if policy.is_quantized:
+            args = (x.astype(policy.compute_dtype), w.astype(jnp.float32))
+            args += () if b is None else (b,)
+            sb = functools.partial(
+                SB.switchback_linear,
+                variant=_SB_VARIANT[policy.mode],
+                fwd_fmt=policy.fwd_fmt, bwd_fmt=policy.bwd_fmt,
+                backend=policy.backend,
+                block_rows=policy.fp8_block_rows,
+                block_cols=policy.fp8_block_cols,
+                fallback_ratio=policy.fp8_fallback_ratio)
+            return kernel_call(sb, *args,
+                               batch_dims=(0, None, None)[:len(args)],
+                               backend=policy.backend)
+        cd = (jnp.float32 if policy.mode == "fp32" else policy.compute_dtype)
+        y = jax.lax.dot_general(
+            x.astype(cd), w.astype(cd),
+            dimension_numbers=(((x.ndim - 1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ).astype(cd)
+        if b is not None:
+            y = y + b.astype(y.dtype)
+        return y

@@ -168,6 +168,7 @@ def flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
             pltpu.VMEM((group, block_q, 1), jnp.float32),   # running denom
             pltpu.VMEM((group, block_q, hd), jnp.float32),  # unnormed out
         ],
+        name="flash_fwd",
         interpret=interpret,
     )(q, k, v)
 
@@ -247,6 +248,7 @@ def flash_bwd_dq(q, k, v, do, lse, di, *, causal: bool, kv_valid: int,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), jnp.float32),
         scratch_shapes=[pltpu.VMEM((group, block_q, hd), jnp.float32)],
+        name="flash_bwd_dq",
         interpret=interpret,
     )(q, k, v, do, lse, di)
 
@@ -337,6 +339,7 @@ def flash_bwd_dkv(q, k, v, do, lse, di, *, causal: bool, kv_valid: int,
                    jax.ShapeDtypeStruct((B, KV, Sk, hd), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((block_k, hd), jnp.float32),
                         pltpu.VMEM((block_k, hd), jnp.float32)],
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(q, k, v, do, lse, di)
 
@@ -439,5 +442,6 @@ def decode_fwd(q: jax.Array, k: jax.Array, v: jax.Array, kv_len: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        name="flash_decode",
         interpret=interpret,
     )(kv_len.astype(jnp.int32), q, k, v)

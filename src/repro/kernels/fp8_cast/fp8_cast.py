@@ -43,5 +43,6 @@ def fp8_cast_tensorwise(x: jax.Array, absmax: jax.Array, *, fmt: str = "e4m3",
                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((block_rows, C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, C), jnp.float32),
+        name="fp8_cast_tensorwise",
         interpret=interpret,
     )(x, absmax.reshape(1, 1))

@@ -63,6 +63,7 @@ def row_quantize(x: jax.Array, *, fmt: str = "e4m3", block_b: int = 256,
             jax.ShapeDtypeStruct((B, K), FMT_DTYPE[fmt]),
             jax.ShapeDtypeStruct((B, 1), jnp.float32),
         ],
+        name="fp8_row_quantize",
         interpret=interpret,
     )(x)
 
@@ -99,6 +100,7 @@ def tensor_quantize(x: jax.Array, *, fmt: str = "e4m3",
         in_specs=[pl.BlockSpec((block_rows, C), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
+        name="fp8_tensor_quantize",
         interpret=interpret,
     )(x)
     absmax = jnp.maximum(absmax, 1e-12)
@@ -109,6 +111,7 @@ def tensor_quantize(x: jax.Array, *, fmt: str = "e4m3",
                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((block_rows, C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, C), FMT_DTYPE[fmt]),
+        name="fp8_tensor_quantize",
         interpret=interpret,
     )(x, absmax)
     return q, absmax
@@ -148,6 +151,7 @@ def block_quantize(x: jax.Array, *, fmt: str = "e4m3",
             jax.ShapeDtypeStruct((R, C), FMT_DTYPE[fmt]),
             jax.ShapeDtypeStruct((grid[0], grid[1]), jnp.float32),
         ],
+        name="fp8_block_quantize",
         interpret=interpret,
     )(x)
 
@@ -211,6 +215,7 @@ def fp8_matmul_dequant(x_q: jax.Array, w_q: jax.Array, row_scale: jax.Array,
         out_specs=pl.BlockSpec((block_b, block_m), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, M), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_b, block_m), jnp.float32)],
+        name="fp8_matmul_dequant",
         interpret=interpret,
     )(x_q, w_q, row_scale)
 
@@ -299,5 +304,6 @@ def fp8_mixed_matmul(x16: jax.Array, x_q: jax.Array, s_blk: jax.Array,
         out_specs=pl.BlockSpec((block_b, block_m), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, M), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_b, block_m), jnp.float32)],
+        name="fp8_mixed_matmul",
         interpret=interpret,
     )(x16.astype(jnp.bfloat16), x_q, s_blk, fb_blk, w_q, s_w)

@@ -208,6 +208,7 @@ def paged_prefill_fwd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
+        name="paged_prefill",
         interpret=interpret,
     )(tables.astype(jnp.int32), q_off.astype(jnp.int32),
       kv_len.astype(jnp.int32), q, k_pool, v_pool)
@@ -261,5 +262,6 @@ def paged_decode_fwd(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        name="paged_decode",
         interpret=interpret,
     )(tables.astype(jnp.int32), kv_len.astype(jnp.int32), q, k_pool, v_pool)

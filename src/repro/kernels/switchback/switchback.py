@@ -63,6 +63,7 @@ def row_quantize(x: jax.Array, *, block_b: int = 256,
             jax.ShapeDtypeStruct((B, K), jnp.int8),
             jax.ShapeDtypeStruct((B, 1), jnp.float32),
         ],
+        name="row_quantize",
         interpret=interpret,
     )(x)
 
@@ -100,6 +101,7 @@ def col_quantize(x: jax.Array, *, block_c: int = 256,
             jax.ShapeDtypeStruct((R, C), jnp.int8),
             jax.ShapeDtypeStruct((1, C), jnp.float32),
         ],
+        name="col_quantize",
         interpret=interpret,
     )(x)
 
@@ -136,6 +138,7 @@ def tensor_quantize(x: jax.Array, *, block_rows: int = 512,
         in_specs=[pl.BlockSpec((block_rows, C), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
+        name="tensor_quantize",
         interpret=interpret,
     )(x)
     q = pl.pallas_call(
@@ -145,6 +148,7 @@ def tensor_quantize(x: jax.Array, *, block_rows: int = 512,
                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((block_rows, C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, C), jnp.int8),
+        name="tensor_quantize",
         interpret=interpret,
     )(x, absmax)
     return q, absmax
@@ -249,6 +253,7 @@ def int8_matmul_dequant(x_q: jax.Array, w_q: jax.Array, row_scale: jax.Array,
         out_specs=pl.BlockSpec((block_b, block_m), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, M), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_b, block_m), jnp.int32)],
+        name="int8_matmul_dequant",
         interpret=interpret,
     )(*operands)
 
@@ -291,6 +296,7 @@ def fused_switchback_fwd(x: jax.Array, w_q: jax.Array, s_w: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((block_b, block_m), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, M), out_dtype),
+        name="fused_switchback_fwd",
         interpret=interpret,
     )(x, w_q, s_w.reshape(1, 1))
 
@@ -338,6 +344,7 @@ def fused_switchback_dgrad(g: jax.Array, w_q: jax.Array, s_w: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((block_b, block_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, N), out_dtype),
+        name="fused_switchback_dgrad",
         interpret=interpret,
     )(g, w_q, s_w.reshape(1, 1))
 
@@ -386,5 +393,6 @@ def wgrad_bf16(x: jax.Array, g: jax.Array, *, block_k: int = 256,
         out_specs=pl.BlockSpec((block_k, block_m), lambda i, j, b: (i, j)),
         out_shape=jax.ShapeDtypeStruct((K, M), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_k, block_m), jnp.float32)],
+        name="wgrad_bf16",
         interpret=interpret,
     )(x.astype(jnp.bfloat16), g.astype(jnp.bfloat16))

@@ -186,19 +186,22 @@ def _core_attention(q: Array, k: Array, v: Array, *, causal: bool,
     (B, Sk, KV, hd) with KV heads *folded* — the Pallas kernels consume
     GQA natively (BlockSpec maps query head h to KV head h // group); the
     XLA paths expand heads with ``jnp.repeat`` as before. ``impl="dense"``
-    forces the oracle regardless of backend."""
-    if impl != "dense" and policy.backend in FLASH_BACKENDS:
-        return kernel_call(
-            functools.partial(FA.flash_attention, causal=causal,
-                              backend=policy.backend, block_q=block_q,
-                              block_k=block_k),
-            q, k, v, batch_dims=(0, 0, 0), backend=policy.backend)
-    n_heads = q.shape[2]
-    kx = _expand_kv(k, n_heads)
-    vx = _expand_kv(v, n_heads)
-    if impl == "flash_scan" and q.shape[1] > 2048:
-        return flash_scan_attention(q, kx, vx, causal=causal)
-    return dense_attention(q, kx, vx, causal=causal)
+    forces the oracle regardless of backend. Every op here, the layout
+    moves and padding around the kernels included, sits in the
+    ``attention`` scope."""
+    with jax.named_scope("attention"):
+        if impl != "dense" and policy.backend in FLASH_BACKENDS:
+            return kernel_call(
+                functools.partial(FA.flash_attention, causal=causal,
+                                  backend=policy.backend, block_q=block_q,
+                                  block_k=block_k),
+                q, k, v, batch_dims=(0, 0, 0), backend=policy.backend)
+        n_heads = q.shape[2]
+        kx = _expand_kv(k, n_heads)
+        vx = _expand_kv(v, n_heads)
+        if impl == "flash_scan" and q.shape[1] > 2048:
+            return flash_scan_attention(q, kx, vx, causal=causal)
+        return dense_attention(q, kx, vx, causal=causal)
 
 
 def attention_block(x: Array, p: dict, cfg, policy: QuantPolicy, *,

@@ -50,9 +50,10 @@ def param_specs(cfg: CLIPConfig) -> Dict[str, Any]:
 def text_forward(params, tokens: Array, cfg: CLIPConfig,
                  policy: QuantPolicy, parallel: ParallelConfig):
     tp = params["text"]
-    x = jnp.asarray(tp["embed"], policy.compute_dtype)[tokens]
-    x = x + tp["pos_embed"][:, :x.shape[1]].astype(x.dtype)
-    x = PRM.constrain(x, ("batch", "seq", "embed"))
+    with jax.named_scope("embed"):
+        x = jnp.asarray(tp["embed"], policy.compute_dtype)[tokens]
+        x = x + tp["pos_embed"][:, :x.shape[1]].astype(x.dtype)
+        x = PRM.constrain(x, ("batch", "seq", "embed"))
 
     def body(xx, lp):
         xx, _ = vit_block(xx, lp, cfg.text_heads, policy, causal=True,
@@ -68,10 +69,12 @@ def text_forward(params, tokens: Array, cfg: CLIPConfig,
     else:
         for i in range(cfg.text_layers):
             x, _ = blk(x, jax.tree.map(lambda p: p[i], tp["blocks"]))
-    x = layer_norm(x, tp["final_norm"]["scale"], tp["final_norm"]["bias"])
-    pooled = x[:, -1]   # last token (EOT)
-    return jnp.einsum("bd,de->be", pooled,
-                      jnp.asarray(tp["proj"], pooled.dtype))
+    with jax.named_scope("head"):
+        x = layer_norm(x, tp["final_norm"]["scale"],
+                       tp["final_norm"]["bias"])
+        pooled = x[:, -1]   # last token (EOT)
+        return jnp.einsum("bd,de->be", pooled,
+                          jnp.asarray(tp["proj"], pooled.dtype))
 
 
 def clip_forward(params, batch: Dict[str, Array], cfg: CLIPConfig,
@@ -82,11 +85,13 @@ def clip_forward(params, batch: Dict[str, Array], cfg: CLIPConfig,
         params["visual"], batch["images"], cfg, policy, parallel,
         patch_drop_rng=patch_drop_rng, collect_stats=collect_stats)
     txt_emb = text_forward(params, batch["texts"], cfg, policy, parallel)
-    img_emb = img_emb / jnp.linalg.norm(
-        img_emb.astype(jnp.float32), axis=-1, keepdims=True)
-    txt_emb = txt_emb / jnp.linalg.norm(
-        txt_emb.astype(jnp.float32), axis=-1, keepdims=True)
-    return img_emb.astype(jnp.float32), txt_emb.astype(jnp.float32), stats
+    with jax.named_scope("head"):
+        img_emb = img_emb / jnp.linalg.norm(
+            img_emb.astype(jnp.float32), axis=-1, keepdims=True)
+        txt_emb = txt_emb / jnp.linalg.norm(
+            txt_emb.astype(jnp.float32), axis=-1, keepdims=True)
+        return (img_emb.astype(jnp.float32), txt_emb.astype(jnp.float32),
+                stats)
 
 
 def clip_loss(params, batch, cfg: CLIPConfig, policy: QuantPolicy,
@@ -96,18 +101,20 @@ def clip_loss(params, batch, cfg: CLIPConfig, policy: QuantPolicy,
     img, txt, stats = clip_forward(params, batch, cfg, policy, parallel,
                                    patch_drop_rng=patch_drop_rng,
                                    collect_stats=collect_stats)
-    # paper §3.2: clip the logit_scale parameter (ln 100 cap)
-    scale = jnp.exp(jnp.clip(params["logit_scale"].astype(jnp.float32),
-                             -cfg.logit_scale_max, cfg.logit_scale_max))
-    # (B, E) x (B, E) -> (B, B): GSPMD all-gathers the data-sharded features
-    logits = scale * (img @ txt.T)
-    labels = jnp.arange(logits.shape[0])
-    l_i = -jnp.mean(jnp.take_along_axis(
-        jax.nn.log_softmax(logits, axis=-1), labels[:, None], -1))
-    l_t = -jnp.mean(jnp.take_along_axis(
-        jax.nn.log_softmax(logits.T, axis=-1), labels[:, None], -1))
-    loss = 0.5 * (l_i + l_t)
-    acc = jnp.mean(jnp.argmax(logits, -1) == labels)
+    with jax.named_scope("head"):
+        # paper §3.2: clip the logit_scale parameter (ln 100 cap)
+        scale = jnp.exp(jnp.clip(params["logit_scale"].astype(jnp.float32),
+                                 -cfg.logit_scale_max, cfg.logit_scale_max))
+        # (B, E) x (B, E) -> (B, B): GSPMD all-gathers the data-sharded
+        # features
+        logits = scale * (img @ txt.T)
+        labels = jnp.arange(logits.shape[0])
+        l_i = -jnp.mean(jnp.take_along_axis(
+            jax.nn.log_softmax(logits, axis=-1), labels[:, None], -1))
+        l_t = -jnp.mean(jnp.take_along_axis(
+            jax.nn.log_softmax(logits.T, axis=-1), labels[:, None], -1))
+        loss = 0.5 * (l_i + l_t)
+        acc = jnp.mean(jnp.argmax(logits, -1) == labels)
     return loss, {"contrastive_acc": acc, "logit_scale": scale,
                   "feature_stats": stats}
 

@@ -10,21 +10,23 @@ Array = jax.Array
 
 
 def rms_norm(x: Array, gain: Array, eps: float = 1e-5) -> Array:
-    xf = x.astype(jnp.float32)
-    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    out = xf * jax.lax.rsqrt(var + eps)
-    return (out * gain.astype(jnp.float32)).astype(x.dtype)
+    with jax.named_scope("norm"):
+        xf = x.astype(jnp.float32)
+        var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+        out = xf * jax.lax.rsqrt(var + eps)
+        return (out * gain.astype(jnp.float32)).astype(x.dtype)
 
 
 def layer_norm(x: Array, gain: Array, bias: Array | None = None,
                eps: float = 1e-5) -> Array:
-    xf = x.astype(jnp.float32)
-    mu = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.var(xf, axis=-1, keepdims=True)
-    out = (xf - mu) * jax.lax.rsqrt(var + eps) * gain.astype(jnp.float32)
-    if bias is not None:
-        out = out + bias.astype(jnp.float32)
-    return out.astype(x.dtype)
+    with jax.named_scope("norm"):
+        xf = x.astype(jnp.float32)
+        mu = jnp.mean(xf, axis=-1, keepdims=True)
+        var = jnp.var(xf, axis=-1, keepdims=True)
+        out = (xf - mu) * jax.lax.rsqrt(var + eps) * gain.astype(jnp.float32)
+        if bias is not None:
+            out = out + bias.astype(jnp.float32)
+        return out.astype(x.dtype)
 
 
 def apply_norm(x: Array, p: dict, kind: str, eps: float) -> Array:
@@ -111,9 +113,10 @@ def embed_tokens(emb: Array, tokens: Array, dtype) -> Array:
 def cross_entropy_loss(logits: Array, labels: Array,
                        softcap: float = 0.0) -> Array:
     """Mean token cross-entropy, f32 log-softmax (stable under bf16 logits)."""
-    lf = logits.astype(jnp.float32)
-    if softcap:
-        lf = softcap * jnp.tanh(lf / softcap)
-    lse = jax.nn.logsumexp(lf, axis=-1)
-    picked = jnp.take_along_axis(lf, labels[..., None], axis=-1)[..., 0]
-    return jnp.mean(lse - picked)
+    with jax.named_scope("head"):
+        lf = logits.astype(jnp.float32)
+        if softcap:
+            lf = softcap * jnp.tanh(lf / softcap)
+        lse = jax.nn.logsumexp(lf, axis=-1)
+        picked = jnp.take_along_axis(lf, labels[..., None], axis=-1)[..., 0]
+        return jnp.mean(lse - picked)

@@ -326,31 +326,35 @@ def _maybe_remat(fn, parallel: ParallelConfig):
 
 def embed_input(params, tokens: Array, cfg: ModelConfig,
                 policy: QuantPolicy, extra_embeds: Optional[Array] = None):
-    x = jnp.asarray(params["embed"], policy.compute_dtype)[tokens]
-    if extra_embeds is not None:
-        fe = quant_linear(extra_embeds.astype(policy.compute_dtype),
-                          PRM.use_weight(params["frontend_proj"],
-                                         ("embed", "embed"),
-                                         policy.compute_dtype), policy=policy)
-        x = jnp.concatenate([fe, x], axis=1)
-    return PRM.constrain(x, ("batch", "seq", "embed"))
+    with jax.named_scope("embed"):
+        x = jnp.asarray(params["embed"], policy.compute_dtype)[tokens]
+        if extra_embeds is not None:
+            fe = quant_linear(extra_embeds.astype(policy.compute_dtype),
+                              PRM.use_weight(params["frontend_proj"],
+                                             ("embed", "embed"),
+                                             policy.compute_dtype),
+                              policy=policy)
+            x = jnp.concatenate([fe, x], axis=1)
+        return PRM.constrain(x, ("batch", "seq", "embed"))
 
 
 def lm_head(params, x: Array, cfg: ModelConfig, policy: QuantPolicy):
-    x = apply_norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-    if cfg.tie_embeddings:
-        w = jnp.swapaxes(jnp.asarray(params["embed"], policy.compute_dtype),
-                         0, 1)
-        logits = jnp.einsum("btd,dv->btv", x, w)
-    else:
-        # head stays un-quantized: the paper quantizes transformer linears,
-        # not the (huge-vocab) classifier; also numerically sensitive.
-        logits = jnp.einsum(
-            "btd,dv->btv", x.astype(policy.compute_dtype),
-            PRM.use_weight(params["head"], ("embed", "vocab"),
-                           policy.compute_dtype))
-    # vocab gets the model axis (takes precedence over seq under SP)
-    return PRM.constrain(logits, ("batch", None, "vocab"))
+    with jax.named_scope("head"):
+        x = apply_norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
+        if cfg.tie_embeddings:
+            w = jnp.swapaxes(
+                jnp.asarray(params["embed"], policy.compute_dtype), 0, 1)
+            logits = jnp.einsum("btd,dv->btv", x, w)
+        else:
+            # head stays un-quantized: the paper quantizes transformer
+            # linears, not the (huge-vocab) classifier; also numerically
+            # sensitive.
+            logits = jnp.einsum(
+                "btd,dv->btv", x.astype(policy.compute_dtype),
+                PRM.use_weight(params["head"], ("embed", "vocab"),
+                               policy.compute_dtype))
+        # vocab gets the model axis (takes precedence over seq under SP)
+        return PRM.constrain(logits, ("batch", None, "vocab"))
 
 
 def forward(params, tokens: Array, cfg: ModelConfig, policy: QuantPolicy,

@@ -143,31 +143,33 @@ def vision_forward(params, images_or_patches: Array, cfg: CLIPConfig,
 
     ``images_or_patches``: (B, H, W, 3) images or (B, N, 3p²) pre-patchified.
     """
-    if images_or_patches.ndim == 4:
-        patches = patchify(images_or_patches, cfg.patch_size)
-    else:
-        patches = images_or_patches
-    B, N, _ = patches.shape
-    x = quant_linear(patches.astype(policy.compute_dtype),
-                     PRM.use_weight(params["patch_embed"],
-                                    ("embed", "heads"),
-                                    policy.compute_dtype), policy=policy)
-    x = x + params["pos_embed"][:, 1:N + 1].astype(x.dtype)
+    with jax.named_scope("embed"):
+        if images_or_patches.ndim == 4:
+            patches = patchify(images_or_patches, cfg.patch_size)
+        else:
+            patches = images_or_patches
+        B, N, _ = patches.shape
+        x = quant_linear(patches.astype(policy.compute_dtype),
+                         PRM.use_weight(params["patch_embed"],
+                                        ("embed", "heads"),
+                                        policy.compute_dtype), policy=policy)
+        x = x + params["pos_embed"][:, 1:N + 1].astype(x.dtype)
 
-    # patch dropout (paper §2.2.2: 0.5) — keep a random half at train time
-    if patch_drop_rng is not None and cfg.patch_dropout > 0:
-        n_keep = max(1, int(N * (1 - cfg.patch_dropout)))
-        idx = jax.random.permutation(patch_drop_rng, N)[:n_keep]
-        x = jnp.take(x, idx, axis=1)
+        # patch dropout (paper §2.2.2: 0.5) — keep a random half at train
+        # time
+        if patch_drop_rng is not None and cfg.patch_dropout > 0:
+            n_keep = max(1, int(N * (1 - cfg.patch_dropout)))
+            idx = jax.random.permutation(patch_drop_rng, N)[:n_keep]
+            x = jnp.take(x, idx, axis=1)
 
-    cls = (params["cls_token"].astype(x.dtype)
-           + params["pos_embed"][:, :1].astype(x.dtype))
-    x = jnp.concatenate([jnp.broadcast_to(cls, (B, 1, x.shape[-1])), x],
-                        axis=1)
-    if cfg.post_embed_norm:   # paper §3.2: LN after patch embed
-        x = layer_norm(x, params["post_embed_norm"]["scale"],
-                       params["post_embed_norm"]["bias"])
-    x = PRM.constrain(x, ("batch", "seq", "embed"))
+        cls = (params["cls_token"].astype(x.dtype)
+               + params["pos_embed"][:, :1].astype(x.dtype))
+        x = jnp.concatenate([jnp.broadcast_to(cls, (B, 1, x.shape[-1])), x],
+                            axis=1)
+        if cfg.post_embed_norm:   # paper §3.2: LN after patch embed
+            x = layer_norm(x, params["post_embed_norm"]["scale"],
+                           params["post_embed_norm"]["bias"])
+        x = PRM.constrain(x, ("batch", "seq", "embed"))
 
     def body(carry, lp):
         xx = carry
@@ -187,9 +189,10 @@ def vision_forward(params, images_or_patches: Array, cfg: CLIPConfig,
             x, s = blk(x, jax.tree.map(lambda p: p[i], params["blocks"]))
             stats.append(s)
         stats = jnp.stack(stats)
-    x = layer_norm(x, params["final_norm"]["scale"],
-                   params["final_norm"]["bias"])
-    pooled = x[:, 0]    # CLS
-    emb = jnp.einsum("bd,de->be", pooled,
-                     jnp.asarray(params["proj"], pooled.dtype))
+    with jax.named_scope("head"):
+        x = layer_norm(x, params["final_norm"]["scale"],
+                       params["final_norm"]["bias"])
+        pooled = x[:, 0]    # CLS
+        emb = jnp.einsum("bd,de->be", pooled,
+                         jnp.asarray(params["proj"], pooled.dtype))
     return emb, stats

@@ -527,50 +527,54 @@ class ServeEngine:
                 peak_live_blocks = max(peak_live_blocks, mgr.live_blocks)
             prefilling = sched.prefilling
             if prefilling:
-                t_pf = time.perf_counter()
-                decoding = [s for s, _ in sched.running]
-                if paged and scfg.prefill_chunk_tokens:
-                    # fixed per-step token budget: live decode tokens eat
-                    # into it first, the rest is split across prefills
-                    budget = max(
-                        scfg.prefill_chunk_tokens - len(decoding), 1)
-                    slice_ = max(budget // len(prefilling), 1)
-                else:
-                    slice_ = scfg.max_len          # monolithic prefill
-                chunks = {s: min(len(r.context) - r.prefilled, slice_)
-                          for s, r in prefilling}
-                # clamp: the bucket may round past a non-pow2 max_len, but
-                # the scheduler guarantees every prompt fits the cache
-                S = min(prefill_bucket(max(chunks.values()),
-                                       scfg.prefill_bucket), scfg.max_len)
-                toks = np.zeros((B, S), np.int32)
-                toks_l = np.ones((B,), np.int32)   # dummy 1 for idle slots
-                pref_l = np.zeros((B,), np.int32)
-                mask = np.zeros((B,), bool)
-                for slot, r in prefilling:
-                    c = chunks[slot]
-                    toks[slot, :c] = r.context[r.prefilled:r.prefilled + c]
-                    toks_l[slot] = r.prefilled + c
-                    pref_l[slot] = r.prefilled
-                    mask[slot] = True
-                if paged:
-                    logits, cache = self.prefill_paged(
-                        params, cache, mgr.tables, toks, pref_l, toks_l,
-                        mask)
-                else:
-                    logits, cache = self.prefill(params, cache, toks,
-                                                 toks_l, mask)
-                # sample here too: a max_new_tokens=1 run finishes at
-                # prefill and never reaches the decode-branch sample
-                uids_a = np.zeros((B,), np.int32)
-                steps_a = np.zeros((B,), np.int32)
-                for slot, r in prefilling:
-                    uids_a[slot] = r.uid
-                    steps_a[slot] = len(r.generated)
-                tok = np.asarray(self.sample(logits[:, 0], key,
-                                             uids_a, steps_a))
-                now = time.perf_counter()
-                dur = now - t_pf
+                with tele.span("serve.prefill_wave", record="prefill_wave",
+                               wave=wave) as span:
+                    t_pf = time.perf_counter()
+                    decoding = [s for s, _ in sched.running]
+                    if paged and scfg.prefill_chunk_tokens:
+                        # fixed per-step token budget: live decode tokens eat
+                        # into it first, the rest is split across prefills
+                        budget = max(
+                            scfg.prefill_chunk_tokens - len(decoding), 1)
+                        slice_ = max(budget // len(prefilling), 1)
+                    else:
+                        slice_ = scfg.max_len          # monolithic prefill
+                    chunks = {s: min(len(r.context) - r.prefilled, slice_)
+                              for s, r in prefilling}
+                    # clamp: the bucket may round past a non-pow2 max_len, but
+                    # the scheduler guarantees every prompt fits the cache
+                    S = min(prefill_bucket(max(chunks.values()),
+                                           scfg.prefill_bucket), scfg.max_len)
+                    span.update(slots=len(prefilling),
+                                tokens=int(sum(chunks.values())), bucket=S)
+                    toks = np.zeros((B, S), np.int32)
+                    toks_l = np.ones((B,), np.int32)   # dummy 1 for idle slots
+                    pref_l = np.zeros((B,), np.int32)
+                    mask = np.zeros((B,), bool)
+                    for slot, r in prefilling:
+                        c = chunks[slot]
+                        toks[slot, :c] = r.context[r.prefilled:r.prefilled + c]
+                        toks_l[slot] = r.prefilled + c
+                        pref_l[slot] = r.prefilled
+                        mask[slot] = True
+                    if paged:
+                        logits, cache = self.prefill_paged(
+                            params, cache, mgr.tables, toks, pref_l, toks_l,
+                            mask)
+                    else:
+                        logits, cache = self.prefill(params, cache, toks,
+                                                     toks_l, mask)
+                    # sample here too: a max_new_tokens=1 run finishes at
+                    # prefill and never reaches the decode-branch sample
+                    uids_a = np.zeros((B,), np.int32)
+                    steps_a = np.zeros((B,), np.int32)
+                    for slot, r in prefilling:
+                        uids_a[slot] = r.uid
+                        steps_a[slot] = len(r.generated)
+                    tok = np.asarray(self.sample(logits[:, 0], key,
+                                                 uids_a, steps_a))
+                    now = time.perf_counter()
+                    dur = now - t_pf
                 for slot, r in prefilling:
                     r.prefilled += chunks[slot]
                     if tele.enabled:
@@ -602,10 +606,6 @@ class ServeEngine:
                 n_prefills += 1
                 prefill_s += dur
                 if tele.enabled:
-                    tele.emit_span("prefill_wave", time.time() - dur, dur,
-                                   wave=wave, slots=len(prefilling),
-                                   tokens=int(sum(chunks.values())),
-                                   bucket=S)
                     tele.emit("wave", wave=wave, mode="prefill",
                               dur_s=dur, slots=len(prefilling),
                               tokens=int(sum(chunks.values())))
@@ -656,25 +656,27 @@ class ServeEngine:
             t_dec = time.perf_counter()
             if drafts:
                 # -- speculative wave: one k-query verify call ----------
-                S_v = scfg.spec_k + 1         # static shape: one trace
-                toks = np.zeros((B, S_v), np.int32)
-                pref = np.zeros((B,), np.int32)
-                lens = np.ones((B,), np.int32)
-                mask = np.zeros((B,), bool)
-                for slot, r in running:
-                    if slot in dead:
-                        continue
-                    d = drafts.get(slot, ())
-                    L = r.total_len - 1       # KV-resident tokens
-                    toks[slot, 0] = cur[slot]
-                    toks[slot, 1:1 + len(d)] = d
-                    pref[slot] = L
-                    lens[slot] = L + 1 + len(d)
-                    mask[slot] = True
-                arg, cache = self.verify_paged(params, cache, mgr.tables,
-                                               toks, pref, lens, mask)
-                arg = np.asarray(arg)
-                now = time.perf_counter()
+                with tele.span("serve.verify_wave", record="verify_wave",
+                               wave=wave):
+                    S_v = scfg.spec_k + 1         # static shape: one trace
+                    toks = np.zeros((B, S_v), np.int32)
+                    pref = np.zeros((B,), np.int32)
+                    lens = np.ones((B,), np.int32)
+                    mask = np.zeros((B,), bool)
+                    for slot, r in running:
+                        if slot in dead:
+                            continue
+                        d = drafts.get(slot, ())
+                        L = r.total_len - 1       # KV-resident tokens
+                        toks[slot, 0] = cur[slot]
+                        toks[slot, 1:1 + len(d)] = d
+                        pref[slot] = L
+                        lens[slot] = L + 1 + len(d)
+                        mask[slot] = True
+                    arg, cache = self.verify_paged(params, cache, mgr.tables,
+                                                   toks, pref, lens, mask)
+                    arg = np.asarray(arg)
+                    now = time.perf_counter()
                 for slot, r in running:
                     if slot in dead:
                         continue
@@ -722,41 +724,40 @@ class ServeEngine:
                 n_steps += 1
                 n_verify += 1
                 if tele.enabled:
-                    v_dur = now - t_dec
-                    tele.emit_span("verify_wave", time.time() - v_dur,
-                                   v_dur, wave=wave)
                     tele.emit("wave", wave=wave, mode="verify",
-                              dur_s=v_dur, drafted=sum(
+                              dur_s=now - t_dec, drafted=sum(
                                   len(d) for d in drafts.values()),
                               slots=len(drafts))
             else:
                 # -- plain wave: ordinary one-token decode --------------
-                if paged and len_dirty:
-                    # the only step that reads the device length leaf;
-                    # restore host truth (prefilling slots keep their
-                    # chunk cursor, idle slots write to trash anyway)
-                    lens = np.zeros((B,), np.int32)
-                    pf_now = {s for s, _ in sched.prefilling}
-                    for slot, r in sched.occupied:
-                        lens[slot] = (r.prefilled if slot in pf_now
-                                      else r.total_len - 1)
-                    cache = self.set_lengths(cache, lens)
-                    len_dirty = False
-                if paged:
-                    logits, cache = self.decode_paged(
-                        params, cache, mgr.tables, cur[:, None])
-                else:
-                    logits, cache = self.decode(params, cache,
-                                                cur[:, None])
-                uids_a = np.zeros((B,), np.int32)
-                steps_a = np.zeros((B,), np.int32)
-                for slot, r in running:
-                    if slot not in dead:
-                        uids_a[slot] = r.uid
-                        steps_a[slot] = len(r.generated)
-                tok = np.asarray(self.sample(logits[:, 0], key,
-                                             uids_a, steps_a))
-                now = time.perf_counter()
+                with tele.span("serve.decode_wave", record="decode_wave",
+                               wave=wave):
+                    if paged and len_dirty:
+                        # the only step that reads the device length leaf;
+                        # restore host truth (prefilling slots keep their
+                        # chunk cursor, idle slots write to trash anyway)
+                        lens = np.zeros((B,), np.int32)
+                        pf_now = {s for s, _ in sched.prefilling}
+                        for slot, r in sched.occupied:
+                            lens[slot] = (r.prefilled if slot in pf_now
+                                          else r.total_len - 1)
+                        cache = self.set_lengths(cache, lens)
+                        len_dirty = False
+                    if paged:
+                        logits, cache = self.decode_paged(
+                            params, cache, mgr.tables, cur[:, None])
+                    else:
+                        logits, cache = self.decode(params, cache,
+                                                    cur[:, None])
+                    uids_a = np.zeros((B,), np.int32)
+                    steps_a = np.zeros((B,), np.int32)
+                    for slot, r in running:
+                        if slot not in dead:
+                            uids_a[slot] = r.uid
+                            steps_a[slot] = len(r.generated)
+                    tok = np.asarray(self.sample(logits[:, 0], key,
+                                                 uids_a, steps_a))
+                    now = time.perf_counter()
                 n_live = 0
                 for slot, r in running:
                     if slot in dead:          # preempted mid-step: its
@@ -778,11 +779,8 @@ class ServeEngine:
                 n_slot_passes += n_live
                 n_steps += 1
                 if tele.enabled:
-                    d_dur = now - t_dec
-                    tele.emit_span("decode_wave", time.time() - d_dur,
-                                   d_dur, wave=wave)
                     tele.emit("wave", wave=wave, mode="decode",
-                              dur_s=d_dur, slots=n_live)
+                              dur_s=now - t_dec, slots=n_live)
             decode_s += now - t_dec
             wave += 1
         dt = time.perf_counter() - t0

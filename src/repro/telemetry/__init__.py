@@ -4,13 +4,15 @@ One ``Telemetry`` object bundles the three observability primitives:
 
   * a :class:`~repro.telemetry.sink.JsonlSink` writing schema-versioned
     event records (``--telemetry PATH``),
-  * host-side :meth:`Telemetry.span` phase timing (records with
-    ``kind="span"`` — exported to Chrome traces by ``telemetry/report``),
+  * host-side :meth:`Telemetry.span` phases: annotations in the
+    ``jax.profiler`` trace, always, and with a sink records with
+    ``kind="span"`` (exported to Chrome traces by ``telemetry/report``),
   * a ``jax.profiler`` window (``--profile-steps A:B``) started/stopped
     by :meth:`Telemetry.maybe_profile` at step granularity.
 
-Everything degrades to a no-op when built without a path: the disabled
-object is safe to thread through Trainer/ServeEngine unconditionally,
+Built without a path, everything but the spans' profiler annotations is
+a no-op: the disabled object is safe to thread through
+Trainer/ServeEngine unconditionally,
 and the hot loops never branch on more than one attribute check — the
 no-extra-sync contract (telemetry never calls ``device_get`` or
 ``block_until_ready``; it only records what the host already knows) is
@@ -52,10 +54,10 @@ def parse_profile_steps(spec: Optional[str]) -> Optional[Tuple[int, int]]:
 class Telemetry:
     """Sink + spans + profiler window behind one object.
 
-    ``Telemetry()`` (no path, no profile window) is fully disabled:
-    ``emit``/``span`` are no-ops and ``enabled`` is False, so callers
-    thread it unconditionally and skip building event payloads with one
-    ``if tele.enabled`` check.
+    ``Telemetry()`` (no path, no profile window) is disabled: ``emit``
+    is a no-op, ``span`` only annotates the profiler trace, and
+    ``enabled`` is False, so callers thread it unconditionally and skip
+    building event payloads with one ``if tele.enabled`` check.
     """
 
     def __init__(self, path: Optional[str] = None, *,
@@ -98,16 +100,24 @@ class Telemetry:
                            dur_s=dur_s, **fields)
 
     @contextlib.contextmanager
-    def span(self, name: str, **fields):
-        """Time a host-side phase; no-op (no clock reads) when disabled."""
-        if self.sink is None:
-            yield
-            return
+    def span(self, name: str, record: Optional[str] = None, **fields):
+        """Mark a host-side phase.
+
+        The phase is a ``jax.profiler.TraceAnnotation`` named ``name``, on
+        the profiler's clock beside the device's operations, whether or not
+        a sink exists (about a microsecond when no trace is active). With a
+        sink, a phase that completes is also written as a ``span`` record
+        named ``record`` (``name`` by default). Yields ``fields``: what the
+        phase adds to it goes into both.
+        """
+        from jax.profiler import TraceAnnotation
         t0 = time.time()
-        try:
-            yield
-        finally:
-            self.emit_span(name, t0, time.time() - t0, **fields)
+        with TraceAnnotation(name) as ann:
+            yield fields
+            if fields:
+                ann.set_metadata(**fields)
+        if self.sink is not None:
+            self.emit_span(record or name, t0, time.time() - t0, **fields)
 
     # -- profiler window ----------------------------------------------------
     def maybe_profile(self, step: int) -> None:

@@ -32,7 +32,6 @@ only a fresh process — ``maybe_resume`` — survives a process death.
 from __future__ import annotations
 
 import math
-import time
 from typing import Callable, Dict, List, Optional
 
 from repro.checkpoint import CheckpointWriteError
@@ -173,10 +172,28 @@ class TrainSupervisor:
             self._ema_update(h["loss"], h["grad_norm"])
 
     def _recover(self, a: _Anomaly) -> None:
-        cfg, t = self.config, self.trainer
         self.telemetry.emit("anomaly", step=a.step, anomaly=a.kind,
                             detail=a.detail)
-        t_rw = time.time()
+        # the rewind_log entry doubles as a trace span: the span covers
+        # checkpoint drain + restore + host-state rollback
+        with self.telemetry.span("train.rewind", record="rewind",
+                                 step=a.step, anomaly=a.kind) as span:
+            start, skip = self._rewind(a)
+            span.update(restored_step=start, attempt=self._attempt,
+                        skipped=skip)
+        self.telemetry.emit("rewind", step=a.step, anomaly=a.kind,
+                            detail=a.detail, restored_step=start,
+                            attempt=self._attempt, skipped=skip,
+                            data_offset=self.data_offset)
+        if self.config.log_every:
+            print(f"[supervisor] {a.kind} at step {a.step}: rewound to "
+                  f"step {start} (attempt {self._attempt}), skipping "
+                  f"{skip} data steps (offset {self.data_offset})")
+
+    def _rewind(self, a: _Anomaly):
+        """Restore a checkpoint before ``a`` and move the data offset past
+        it; returns (restored step, data steps skipped)."""
+        cfg, t = self.config, self.trainer
         self.counters["rewinds"] += 1
         self.incident_kinds[a.kind] = self.incident_kinds.get(a.kind, 0) + 1
         if self.counters["rewinds"] > cfg.max_total_rewinds:
@@ -221,20 +238,7 @@ class TrainSupervisor:
               "restored_step": start, "attempt": self._attempt,
               "skipped": skip, "data_offset": self.data_offset}
         self.rewind_log.append(ev)
-        # the rewind_log entry doubles as a trace span: the span covers
-        # checkpoint drain + restore + host-state rollback
-        dur = time.time() - t_rw
-        self.telemetry.emit_span("rewind", t_rw, dur, step=a.step,
-                                 anomaly=a.kind, restored_step=start,
-                                 attempt=self._attempt, skipped=skip)
-        self.telemetry.emit("rewind", step=a.step, anomaly=a.kind,
-                            detail=a.detail, restored_step=start,
-                            attempt=self._attempt, skipped=skip,
-                            data_offset=self.data_offset)
-        if cfg.log_every:
-            print(f"[supervisor] {a.kind} at step {a.step}: rewound to "
-                  f"step {start} (attempt {self._attempt}), skipping "
-                  f"{skip} data steps (offset {self.data_offset})")
+        return start, skip
 
     def _retry_save(self, e: CheckpointWriteError) -> None:
         self.counters["save_failures"] += 1

@@ -95,14 +95,15 @@ def make_train_step(bundle, policy: QuantPolicy, parallel: ParallelConfig,
                            if jnp.issubdtype(m.dtype, jnp.inexact)
                            else m[-1]), mb_metrics)
 
-        grads, skip_mask, scaler_state, sstats = scaler.unscale(
-            grads, state.scaler_state)
-        gnorm = global_norm(grads)
-        if train_cfg.grad_clip_norm > 0:
-            grads, _ = clip_by_global_norm(grads, train_cfg.grad_clip_norm)
-
-        params, opt_state, aux = opt.update(state.params, state.opt_state,
-                                            grads, skip_mask=skip_mask)
+        with jax.named_scope("optimizer"):
+            grads, skip_mask, scaler_state, sstats = scaler.unscale(
+                grads, state.scaler_state)
+            gnorm = global_norm(grads)
+            if train_cfg.grad_clip_norm > 0:
+                grads, _ = clip_by_global_norm(grads,
+                                               train_cfg.grad_clip_norm)
+            params, opt_state, aux = opt.update(
+                state.params, state.opt_state, grads, skip_mask=skip_mask)
         out_metrics = {
             **metrics,
             "loss": loss, "grad_norm": gnorm,

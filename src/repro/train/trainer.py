@@ -115,8 +115,9 @@ class Trainer:
             self.hooks.on_slow(ev)
 
     def _flush(self, pending: List) -> None:
-        """Fetch a block of device metrics in one transfer and run the host
-        bookkeeping (spike detector, RMS monitor, watchdog, history, hooks).
+        """Fetch a block of device metrics in one transfer (``train.wait``)
+        and run the host bookkeeping (spike detector, RMS monitor,
+        watchdog, history, hooks; ``train.bookkeeping``).
 
         device_get blocks until every step in the window has executed, so
         (now - window start) / len(window) is the true amortized per-step
@@ -125,8 +126,16 @@ class Trainer:
         if not pending:
             return
         tele = self.telemetry
-        t_fl = time.time()
-        fetched = jax.device_get([m for _, m in pending])
+        # the one blocking device_get for the whole window: host sync time
+        # is this span (its record is the flight recorder's "flush" span)
+        with tele.span("train.wait", record="flush", step=pending[-1][0],
+                       n_steps=len(pending)):
+            fetched = jax.device_get([m for _, m in pending])
+        with jax.profiler.TraceAnnotation("train.bookkeeping"):
+            self._bookkeeping(pending, fetched)
+
+    def _bookkeeping(self, pending: List, fetched: List) -> None:
+        tele = self.telemetry
         dt = (time.monotonic() - self._window_t0) / len(pending)
         for (i, _), metrics in zip(pending, fetched):
             timing = self.watchdog.record(i, dt)
@@ -156,22 +165,17 @@ class Trainer:
                 print(f"[trainer] step {i} loss {loss:.4f} "
                       f"gnorm {rec['grad_norm']:.3f} dt {timing['dt']*1e3:.0f}ms"
                       + (" SLOW" if timing["slow"] else ""))
-        # the flush span covers the one blocking device_get for the whole
-        # window — in a Chrome trace, host sync time is this span
-        tele.emit_span("flush", t_fl, time.time() - t_fl,
-                       step=pending[-1][0], n_steps=len(pending))
         tele.emit("flush", step=pending[-1][0], n_steps=len(pending))
         pending.clear()
         self._window_t0 = time.monotonic()
 
     def _save(self, step: int) -> None:
-        t_sv = time.time()
-        self.ckpt.save_async(step, self.state)
-        self._last_saved_step = step
         # the span times the synchronous device->host snapshot inside
         # save_async (the write itself is off-thread)
-        self.telemetry.emit_span("checkpoint_save", t_sv,
-                                 time.time() - t_sv, step=step)
+        with self.telemetry.span("train.checkpoint", record="checkpoint_save",
+                                 step=step):
+            self.ckpt.save_async(step, self.state)
+        self._last_saved_step = step
         self.telemetry.emit("checkpoint", step=step)
         if self.hooks.on_checkpoint:
             self.hooks.on_checkpoint(step)
@@ -180,8 +184,13 @@ class Trainer:
         self._window_t0 = time.monotonic()
 
     def run(self, batch_iter, n_steps: int) -> List[Dict]:
-        start = int(self.state.step)
-        plan = self.fault_plan
+        """Run ``n_steps`` steps. Each is a ``train.step`` annotation in
+        the profiler trace (``step_num`` its index), holding ``train.batch``
+        (``batch_iter``; ``nbytes`` the batch's size), ``train.dispatch``
+        (the step function) and, at flush and checkpoint boundaries,
+        ``train.wait``, ``train.bookkeeping`` and ``train.checkpoint``."""
+        with jax.profiler.TraceAnnotation("train.wait"):
+            start = int(self.state.step)
         # Metrics stay on device between flush boundaries so the step can
         # dispatch asynchronously — float(loss) every step would block the
         # host on every device step and serialize the pipeline. The cost:
@@ -192,6 +201,16 @@ class Trainer:
         self._window_t0 = time.monotonic()
         for i in range(start, start + n_steps):
             self.telemetry.maybe_profile(i)
+            with jax.profiler.StepTraceAnnotation("train.step", step_num=i):
+                self._step(i, batch_iter, pending)
+        self._flush(pending)
+        if self.ckpt is not None:
+            self.ckpt.wait()
+        return self.history
+
+    def _step(self, i: int, batch_iter, pending: List) -> None:
+        plan = self.fault_plan
+        with jax.profiler.TraceAnnotation("train.batch") as ann:
             if hasattr(batch_iter, "__next__"):
                 data_idx, batch = next(batch_iter)
             else:
@@ -200,39 +219,38 @@ class Trainer:
                                           and len(out) == 2) else (i, out)
             if plan is not None:
                 batch = plan.apply_batch(data_idx, batch)
+            ann.set_metadata(nbytes=sum(getattr(x, "nbytes", 0)
+                                        for x in jax.tree.leaves(batch)))
+        with jax.profiler.TraceAnnotation("train.dispatch"):
             self.state, metrics = self.step_fn(self.state, batch)
-            if plan is not None:
-                self.state, metrics = plan.apply_post_step(
-                    i, data_idx, self.state, metrics)
-                plan.maybe_crash(i)
-            pending.append((i, metrics))
+        if plan is not None:
+            self.state, metrics = plan.apply_post_step(
+                i, data_idx, self.state, metrics)
+            plan.maybe_crash(i)
+        pending.append((i, metrics))
 
-            at_ckpt = (self.ckpt is not None and self.checkpoint_every
-                       and (i + 1) % self.checkpoint_every == 0)
-            if at_ckpt or not self.log_every or i % self.log_every == 0:
-                self._flush(pending)
-                if self.ckpt is not None:
-                    # a failed async write surfaces here, at the next
-                    # checkpoint/flush boundary, attributed to its step
-                    self.ckpt.poll_error()
-            if at_ckpt:
+        at_ckpt = (self.ckpt is not None and self.checkpoint_every
+                   and (i + 1) % self.checkpoint_every == 0)
+        if at_ckpt or not self.log_every or i % self.log_every == 0:
+            self._flush(pending)
+            if self.ckpt is not None:
+                # a failed async write surfaces here, at the next
+                # checkpoint/flush boundary, attributed to its step
+                self.ckpt.poll_error()
+        if at_ckpt:
+            self._save(i + 1)
+        elif self._early_ckpt_wanted and self.early_checkpoint_on_slow \
+                and self.ckpt is not None and self.checkpoint_every:
+            # straggler watchdog fired: bank progress now, a failing
+            # host should lose minimal work.  At most one early save
+            # per checkpoint window.
+            self._flush(pending)
+            if self._last_saved_step is None or \
+                    i + 1 - self._last_saved_step >= \
+                    max(self.checkpoint_every // 2, 1):
                 self._save(i + 1)
-            elif self._early_ckpt_wanted and self.early_checkpoint_on_slow \
-                    and self.ckpt is not None and self.checkpoint_every:
-                # straggler watchdog fired: bank progress now, a failing
-                # host should lose minimal work.  At most one early save
-                # per checkpoint window.
-                self._flush(pending)
-                if self._last_saved_step is None or \
-                        i + 1 - self._last_saved_step >= \
-                        max(self.checkpoint_every // 2, 1):
-                    self._save(i + 1)
-                    self.counters["early_checkpoints"] += 1
-            self._early_ckpt_wanted = False
-        self._flush(pending)
-        if self.ckpt is not None:
-            self.ckpt.wait()
-        return self.history
+                self.counters["early_checkpoints"] += 1
+        self._early_ckpt_wanted = False
 
     # ------------------------------------------------------------------
     def rollback(self, step: int) -> None:

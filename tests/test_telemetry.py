@@ -118,6 +118,20 @@ def _records(path):
         return [json.loads(line) for line in f if line.strip()]
 
 
+def _spans(d, prefix="train."):
+    """(name, start, end, stats) of every host event named ``prefix...``
+    in the profile that ``jax.profiler`` wrote under ``d``, by start."""
+    import glob
+    from jax.profiler import ProfileData
+    path, = glob.glob(str(d / "**" / "*.xplane.pb"), recursive=True)
+    pd = ProfileData.from_file(path)
+    evs = [(e.name, e.start_ns, e.end_ns, dict(e.stats))
+           for p in pd.planes if p.name.startswith("/host:")
+           for ln in p.lines for e in ln.events
+           if e.name.startswith(prefix)]
+    return sorted(evs, key=lambda e: e[1])
+
+
 def test_sink_meta_first_and_validates(tmp_path):
     p = str(tmp_path / "t.jsonl")
     with Telemetry(p, program="test", meta={"arch": "x"}) as t:
@@ -162,6 +176,31 @@ def test_disabled_telemetry_is_inert(tmp_path):
         pass
     t.maybe_profile(0)
     t.close()
+
+
+def test_span_annotates_the_profiler_trace_sink_or_not(tmp_path):
+    """A span is a profiler annotation under its own name whether or not
+    a sink exists; with a sink it is also a JSONL record under its record
+    name. Fields added inside the span go into both; a span left by an
+    exception writes no record."""
+    path = str(tmp_path / "t.jsonl")
+    sink = Telemetry(path, program="test")
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        for t in (Telemetry(), sink):
+            with t.span("unit.phase", record="phase", k=1) as fields:
+                fields["m"] = 2
+        with pytest.raises(RuntimeError):
+            with sink.span("unit.failed"):
+                raise RuntimeError("x")
+    finally:
+        jax.profiler.stop_trace()
+        sink.close()
+    evs = _spans(tmp_path / "trace", "unit.")
+    assert [(e[0], e[3]) for e in evs] == [
+        ("unit.phase", {"k": 1, "m": 2})] * 2 + [("unit.failed", {})]
+    recs = [r for r in _records(path) if r["kind"] == "span"]
+    assert [(r["name"], r["k"], r["m"]) for r in recs] == [("phase", 1, 2)]
 
 
 def test_report_cli_validate_exit_codes(tmp_path, capsys):
@@ -319,6 +358,51 @@ def test_telemetry_on_off_bit_identical_same_transfers(qloop, tmp_path,
     assert any(k.startswith("qh/") for k in steps[0])
     assert {r["kind"] for r in recs} >= {"meta", "train_step", "flush",
                                          "span"}
+
+
+def test_trainer_spans_in_profiler_trace(qloop, tmp_path, monkeypatch):
+    """With telemetry disabled the Trainer's phases still land in the
+    profiler trace, on its clock: each step is a ``train.step`` with its
+    ``step_num`` holding ``train.batch`` (with the batch's bytes) and
+    ``train.dispatch``; each flush is a ``train.wait`` carrying
+    ``n_steps`` and a ``train.bookkeeping``. No JSONL is written and the
+    device->host transfers are the flushes' alone."""
+    make, data_fn = qloop
+    fn, fresh = make(True)
+    real_get = jax.device_get
+    counts = {"n": 0}
+
+    def counting_get(x):
+        counts["n"] += 1
+        return real_get(x)
+
+    monkeypatch.setattr(jax, "device_get", counting_get)
+    tr = Trainer(fn, fresh(), log_every=2)
+    assert not tr.telemetry.enabled
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        tr.run(data_fn, 3)
+    finally:
+        jax.profiler.stop_trace()
+    assert counts["n"] == 2                  # flushes after steps 0 and 2
+    assert not list(tmp_path.glob("**/*.jsonl"))
+    evs = _spans(tmp_path / "trace")
+    named = lambda n: sorted((e for e in evs if e[0] == n),
+                             key=lambda e: e[1])
+    steps = named("train.step")
+    assert [e[3]["step_num"] for e in steps] == [0, 1, 2]
+    nbytes = [sum(x.nbytes for x in jax.tree.leaves(data_fn(i)))
+              for i in range(3)]
+    for name in ("train.batch", "train.dispatch"):
+        inner = named(name)
+        assert len(inner) == 3
+        for (_, a, b, _), (_, s0, s1, _) in zip(inner, steps):
+            assert s0 <= a <= b <= s1
+    assert [e[3]["nbytes"] for e in named("train.batch")] == nbytes
+    # the run's first wait reads the state's step; then one per flush
+    waits = named("train.wait")
+    assert [e[3].get("n_steps") for e in waits] == [None, 1, 2]
+    assert len(named("train.bookkeeping")) == 2
 
 
 def test_quant_health_metrics_off_bit_identical(qloop):

@@ -35,8 +35,11 @@ CLIP_VISION = (16, 16, 80, 129)
 def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    return topologies.get_topology_desc(platform="tpu",
-                                        topology_name="v5e:2x2")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
 @pytest.fixture(scope="module")
@@ -140,3 +143,77 @@ def test_paged_prefill_compiles_for_v5e(S, one_chip):
         one_chip, ((B, S, H, hd), BF), _pool(KV, hd), _pool(KV, hd),
         ((B, nb), I32), ((B,), I32), ((B,), I32))
     assert "tpu_custom_call" in hlo
+
+
+# kernel names every main-path Pallas call passes (``name=``): the device
+# trace groups its time under these, never under the function around it
+KERNEL_NAMES = {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_decode",
+                "tensor_quantize", "row_quantize", "col_quantize",
+                "int8_matmul_dequant", "fused_switchback_fwd",
+                "fused_switchback_dgrad", "wgrad_bf16", "paged_prefill",
+                "paged_decode"}
+
+
+@pytest.fixture(scope="module")
+def smollm_step_hlo(topo):
+    """The compiled int8 SwitchBack + Pallas train step of a smollm-360m
+    cut to two layers (published widths) at 2 x 512 tokens, remat per
+    layer, on one described v5e chip."""
+    import dataclasses
+    from jax.sharding import AxisType, Mesh
+    import numpy as np
+    from repro.configs import get_config
+    from repro.configs.base import ParallelConfig, TrainConfig
+    from repro.train import make_engine
+    cfg = dataclasses.replace(get_config("smollm-360m"), n_layers=2)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    tc = TrainConfig(optimizer="stable_adamw", quant_mode="int8_switchback",
+                     kernel_backend="pallas")
+    par = ParallelConfig(mesh_shape=(1, 1), mesh_axes=("data", "model"),
+                         remat="block")
+    tokens = jax.ShapeDtypeStruct((2, 512), I32)
+    engine = make_engine(cfg, tc, par, mesh,
+                         {"tokens": tokens, "labels": tokens})
+    return engine.lower().compile().as_text()
+
+
+def custom_calls(hlo):
+    """(instruction name without its suffix, op-name path) of every Mosaic
+    kernel call in compiled HLO text."""
+    import re
+    out = []
+    for line in hlo.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            name = re.sub(r"(\.\d+)+$", "",
+                          line.split(" = ", 1)[0].split()[-1].lstrip("%"))
+            op = re.search(r'op_name="([^"]*)"', line)
+            out.append((name, op.group(1) if op else ""))
+    return out
+
+
+def test_train_step_kernels_carry_their_own_names(smollm_step_hlo):
+    calls = custom_calls(smollm_step_hlo)
+    names = {n for n, _ in calls}
+    assert {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+            "fused_switchback_fwd", "tensor_quantize"} <= names
+    assert names <= KERNEL_NAMES
+    assert not names & {"checkpoint", "pallas_call"}
+
+
+def test_train_step_ops_carry_layer_scopes(smollm_step_hlo):
+    """Scopes reach the compiled program as op-name metadata: the flash
+    kernels and the layout moves around them sit under ``attention``, the
+    SwitchBack kernels under ``linear``, the update under ``optimizer``."""
+    import re
+    # transforms wrap a scope: jit(train_step)/transpose(jvp(embed))/...
+    parts = lambda op: set(re.split(r"[/()]", op))
+    for name, op in custom_calls(smollm_step_hlo):
+        scope = "attention" if name.startswith("flash_") else "linear"
+        assert scope in parts(op), (name, op)
+    ops = [parts(o) for o in re.findall(r'op_name="([^"]*)"',
+                                        smollm_step_hlo)]
+    attn = [o for o in ops if "attention" in o]
+    assert any("pallas_call" not in o for o in attn)   # pads, transposes
+    for scope in ("norm", "embed", "head", "optimizer"):
+        assert any(scope in o for o in ops), scope
