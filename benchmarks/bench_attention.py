@@ -54,8 +54,7 @@ def _t(flops: float, bytes_: float) -> float:
     return max(flops / PEAK_BF16, bytes_ / HBM_BW)
 
 
-def model_times(B, Sq, Sk, H, KV, hd, causal, *, chunk=1024, block=128,
-                kind="fwd"):
+def model_times(B, Sq, Sk, H, KV, hd, causal, *, chunk=1024, kind="fwd"):
     """Roofline times (s) for one attention op on each backend path."""
     causal_frac = 0.5 if (causal and Sq == Sk) else 1.0
     flops = 4.0 * B * Sq * Sk * H * hd * causal_frac          # QKᵀ + PV
@@ -66,8 +65,8 @@ def model_times(B, Sq, Sk, H, KV, hd, causal, *, chunk=1024, block=128,
     o_bytes = 2 * B * Sq * H * hd
     lse_bytes = 4 * B * H * Sq
     n_chunks = max(1, -(-min(Sk, Sq if causal else Sk) // chunk))
-    n_q_t = max(1, -(-Sq // block))
-    n_k_t = max(1, -(-Sk // block))
+    bq, bk = FA.choose_attn_blocks(Sq, Sk, group=H // KV, hd=hd)
+    n_q_t, n_k_t = -(-Sq // bq), -(-Sk // bk)
     # xla scan: expanded K/V (H heads), f32 score+prob tiles written+read,
     # (m, l, acc) carry rewritten per chunk
     xla_bytes = (q_bytes + kv_bytes * (H // KV) + o_bytes
@@ -90,7 +89,7 @@ def model_times(B, Sq, Sk, H, KV, hd, causal, *, chunk=1024, block=128,
     return {"xla": _t(flops, xla_bytes), "pallas": _t(flops, pallas_bytes)}
 
 
-def model_decode_times(B, S_max, H, KV, hd, *, block=128):
+def model_decode_times(B, S_max, H, KV, hd, *, block=FA.DECODE_BLOCK):
     """Per-step decode attention: dense full-window vs length-bounded
     tiles at the steady-state expected fill S_max/2. Charging the kernel
     only live-tile bytes is faithful: the scalar-prefetch index maps

@@ -145,7 +145,7 @@ class ParallelConfig:
     attn_impl: str = "flash_scan"    # flash_scan | dense — "dense" forces
     # the materialized-scores oracle on EVERY backend (kernels included)
     attn_block_q: int = 0            # flash-attention kernel Q-tile rows;
-    # 0 = auto (min(128, pow2ceil(Sq)) — kernels/flash_attention/ops.py)
+    # 0 = auto (sized to the sequence — kernels/flash_attention/ops.py)
     attn_block_k: int = 0            # KV-tile rows (fwd/bwd and the serve
     # decode ring-cache kernel); 0 = auto
 

@@ -1,10 +1,11 @@
 """Jit'd public wrappers for the flash-attention kernels.
 
 Handles: backend dispatch (pallas TPU / pallas interpret / pure-XLA ref),
-model→kernel layout moves, shape padding to block multiples (pad keys are
+model→kernel layout moves, shape padding to tile multiples (pad keys are
 masked in-kernel via the static ``kv_valid``; pad queries are sliced off),
-the static block-size heuristic, and the ``custom_vjp`` that wires the
-recompute-style backward kernels in (DESIGN.md §9).
+the static tile choice from the call's sequence lengths, and the
+``custom_vjp`` that wires the recompute-style backward kernels in
+(DESIGN.md §9).
 
 Model-layout contract (what models/attention.py speaks): q (B, Sq, H, hd),
 k/v (B, Sk, KV, hd) with H a multiple of KV (GQA); outputs match q.
@@ -24,23 +25,62 @@ from repro.kernels.flash_attention import ref as _ref
 Backend = Literal["xla", "pallas", "pallas_interpret"]
 BACKENDS: tuple[str, ...] = ("xla", "pallas", "pallas_interpret")
 
-# (block_q, block_k) = 128 matches the TPU T(8, 128) lane tiling and keeps
-# the per-grid-cell working set (q/k/v tiles + f32 scores + stats) well
-# under VMEM; shrink to the padded pow2 when the sequence is shorter.
-DEFAULT_BLOCK = 128
+# Largest tiles of the train kernels (fwd, dq, dkv) per sequence axis.
+# Every grid step costs a fixed ~0.35 µs on a v5e whatever its tile, so
+# tiles are as large as the default scoped VMEM allows; among those the
+# chip sweep of PERF.md §5 found 512 query by 1024 key rows fastest at
+# smollm-360m's widths (a 1024-row Q tile overflows VMEM in flash_bwd_dq).
+TILE_CAP_Q = 512
+TILE_CAP_K = 1024
+# A grid step holds its KV head's whole query group: the q, do and o
+# blocks and the f32 accumulators scale with group x block_q x hd (hd
+# padded to the 128 lanes). The Q tile shrinks to keep that product at
+# what 512 rows of smollm-360m's 3 heads of 64 hold.
+Q_GROUP_ELEMS = TILE_CAP_Q * 3 * 128
+
+# Decode tile over the ring cache (choose_decode_block)
+DECODE_BLOCK = 128
 
 
-def _pow2_ceil(n: int) -> int:
-    return 1 << max(0, (n - 1).bit_length())
+def seq_tile(S: int, cap: int) -> int:
+    """Rows per tile of a sequence axis of ``S`` rows: ``n = ceil(S/cap)``
+    equal tiles, each rounded up to the 8 sublanes — one tile with under
+    8 pad rows up to ``cap`` (129 -> 136, 77 -> 80), else ``n`` tiles
+    (4096 at cap 512 -> 8 x 512)."""
+    n = max(1, -(-S // cap))
+    rows = -(-S // n)
+    return -(-rows // 8) * 8
 
 
 def choose_attn_blocks(Sq: int, Sk: int, block_q: int = 0,
-                       block_k: int = 0) -> tuple[int, int]:
-    """Static block-size choice: the configured size when given (>0), else
-    min(128, pow2ceil(S)) per axis — tiny test shapes pad to one block."""
-    bq = block_q or min(DEFAULT_BLOCK, _pow2_ceil(Sq))
-    bk = block_k or min(DEFAULT_BLOCK, _pow2_ceil(Sk))
-    return max(bq, 1), max(bk, 1)
+                       block_k: int = 0, *, group: int = 1,
+                       hd: int = 128) -> tuple[int, int]:
+    """Static (block_q, block_k) of the train kernels: the configured size
+    when given (>0), else :func:`seq_tile` of each axis, the Q cap cut to
+    what a GQA ``group`` of query heads of width ``hd`` fits in VMEM."""
+    lanes = -(-hd // 128) * 128
+    cap_q = min(TILE_CAP_Q, max(8, Q_GROUP_ELEMS // (group * lanes) // 8 * 8))
+    return block_q or seq_tile(Sq, cap_q), block_k or seq_tile(Sk, TILE_CAP_K)
+
+
+def choose_decode_block(S: int, block_k: int = 0) -> int:
+    """KV tile of the decode kernel over a ring cache of ``S`` cells. It
+    must divide S (padding the cache would copy it every step) and be a
+    multiple of 8 sublanes unless it is all of S: the configured size (or
+    DECODE_BLOCK) when it qualifies, else the largest qualifying divisor
+    below it — S=96, block_k=128 -> 96; S=200 -> 40."""
+    bk = min(block_k or DECODE_BLOCK, S)
+    while S % bk or (bk % 8 and bk != S):
+        bk -= 1
+        if bk == 0:
+            bk = S
+    return bk
+
+
+def _blocks(q, k, block_q: int, block_k: int) -> tuple[int, int]:
+    """choose_attn_blocks for model-layout operands."""
+    return choose_attn_blocks(q.shape[1], k.shape[1], block_q, block_k,
+                              group=q.shape[2] // k.shape[2], hd=q.shape[3])
 
 
 def _pad_seq(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -81,7 +121,7 @@ def flash_fwd_lse(q, k, v, *, causal: bool, backend: Backend = "xla",
         o, lse = _ref.mha_fwd(_to_kernel(q), _to_kernel(k), _to_kernel(v),
                               causal=causal, kv_valid=Sk, scale=scale)
         return _to_kernel(o), lse
-    bq, bk = choose_attn_blocks(Sq, Sk, block_q, block_k)
+    bq, bk = _blocks(q, k, block_q, block_k)
     qk = _pad_seq(_to_kernel(q), 2, bq)
     kk = _pad_seq(_to_kernel(k), 2, bk)
     vk = _pad_seq(_to_kernel(v), 2, bk)
@@ -103,7 +143,7 @@ def _bwd_impl(causal, backend, block_q, block_k, res, do):
             _to_kernel(q), _to_kernel(k), _to_kernel(v), _to_kernel(o),
             lse, _to_kernel(do), causal=causal, kv_valid=Sk, scale=scale)
     else:
-        bq, bk = choose_attn_blocks(Sq, Sk, block_q, block_k)
+        bq, bk = _blocks(q, k, block_q, block_k)
         interp = backend == "pallas_interpret"
         qp = _pad_seq(_to_kernel(q), 2, bq)
         kp = _pad_seq(_to_kernel(k), 2, bk)
@@ -180,16 +220,7 @@ def decode_attention(q, k, v, kv_len, *, backend: Backend = "xla",
     kv_len = kv_len.reshape(B, 1).astype(jnp.int32)
     if backend == "xla":
         return _ref.decode_fwd(q3, k, v, kv_len, scale=scale)[:, None]
-    # the block must divide S_max (padding the cache would copy it every
-    # step) and be a multiple of 8 sublanes unless it is all of S_max:
-    # honor the configured/default size when it qualifies, else the
-    # largest qualifying divisor below it — e.g. S_max=96, block_k=128
-    # -> 96; S_max=200 -> 40
-    bk = min(block_k or DEFAULT_BLOCK, S)
-    while S % bk or (bk % 8 and bk != S):
-        bk -= 1
-        if bk == 0:
-            bk = S
+    bk = choose_decode_block(S, block_k)
     o = _k.decode_fwd(q3.reshape(B, KV, H // KV, hd), k, v, kv_len[:, 0],
                       scale=scale, block_k=bk,
                       interpret=(backend == "pallas_interpret"))

@@ -49,36 +49,42 @@ def _rel(a, b):
 # forward parity: interpret kernel vs dense oracle
 # ---------------------------------------------------------------------------
 
-# (B, Sq, Sk, H, KV, hd, causal): pow2-aligned, nothing-aligned (pad on
-# every axis), multi-block (> one 128 tile), GQA 2:1/4:1/8:1, MQA, and
-# non-causal rectangular (cross-attention shape)
+# (B, Sq, Sk, H, KV, hd, causal, block_q, block_k), block 0 = the tile
+# rule's: pow2-aligned, nothing-aligned (pad on every axis), GQA
+# 2:1/4:1/8:1, MQA, non-causal rectangular (cross-attention shape), and
+# multi-tile through explicit small tiles (online-softmax rescale across
+# KV tiles, causal tile skip, block_q != block_k, padded tails); the last
+# two are CLIP's single tiles at head dim 80 (129 -> 136, 77 -> 80)
 FWD_CASES = [
-    (2, 16, 16, 4, 4, 8, True),
-    (1, 13, 13, 4, 2, 16, True),        # GQA 2:1, odd seq (padding)
-    (2, 37, 37, 8, 2, 8, True),         # GQA 4:1, odd seq
-    (1, 16, 16, 8, 1, 8, True),         # MQA
-    (2, 9, 23, 6, 3, 8, False),         # rectangular non-causal
-    (1, 200, 200, 2, 1, 32, True),      # > one 128-block, padded tail
-    (1, 130, 64, 4, 4, 8, False),       # Sq multi-block, Sk one block
+    (2, 16, 16, 4, 4, 8, True, 0, 0),
+    (1, 13, 13, 4, 2, 16, True, 0, 0),  # GQA 2:1, odd seq (padding)
+    (2, 37, 37, 8, 2, 8, True, 0, 0),   # GQA 4:1, odd seq
+    (1, 16, 16, 8, 1, 8, True, 0, 0),   # MQA
+    (2, 9, 23, 6, 3, 8, False, 0, 0),   # rectangular non-causal
+    (1, 200, 200, 2, 1, 32, True, 64, 32),  # 4 x 7 tiles, padded tails
+    (1, 130, 64, 4, 4, 8, False, 64, 32),   # 3 Q tiles, 2 KV tiles
+    (1, 129, 129, 2, 2, 80, False, 0, 0),   # CLIP vision: one 136 tile
+    (1, 77, 77, 2, 2, 80, True, 0, 0),      # CLIP text: one 80 tile
 ]
 
 
 @pytest.mark.parametrize("case", FWD_CASES)
 def test_flash_fwd_matches_dense_oracle(case):
-    B, Sq, Sk, H, KV, hd, causal = case
+    B, Sq, Sk, H, KV, hd, causal, bq, bk = case
     if causal:
         assert Sq == Sk
     q, k, v = _qkv(B, Sq, Sk, H, KV, hd)
     ref = _dense_oracle(q, k, v, causal)
     got = FA.flash_attention(q, k, v, causal=causal,
-                             backend="pallas_interpret")
+                             backend="pallas_interpret", block_q=bq,
+                             block_k=bk)
     assert _rel(ref, got) <= TOL_F32, case
 
 
 @pytest.mark.parametrize("case", FWD_CASES)
 def test_flash_fwd_xla_ref_matches_dense_oracle(case):
     """The backend="xla" path of the ops layer is the same math."""
-    B, Sq, Sk, H, KV, hd, causal = case
+    B, Sq, Sk, H, KV, hd, causal, _, _ = case
     q, k, v = _qkv(B, Sq, Sk, H, KV, hd)
     ref = _dense_oracle(q, k, v, causal)
     got = FA.flash_attention(q, k, v, causal=causal, backend="xla")
@@ -104,19 +110,22 @@ def test_flash_fwd_lse_is_logsumexp():
 # backward parity: dq/dk/dv vs jax.grad of the dense oracle
 # ---------------------------------------------------------------------------
 
+# (B, Sq, Sk, H, KV, hd, causal, block_q, block_k) as FWD_CASES
 BWD_CASES = [
-    (2, 16, 16, 4, 4, 8, True),
-    (1, 13, 13, 4, 2, 16, True),
-    (2, 37, 37, 8, 2, 8, True),
-    (2, 9, 23, 6, 3, 8, False),
-    (1, 150, 150, 4, 1, 8, True),       # multi-block MQA with padding
+    (2, 16, 16, 4, 4, 8, True, 0, 0),
+    (1, 13, 13, 4, 2, 16, True, 0, 0),
+    (2, 37, 37, 8, 2, 8, True, 0, 0),
+    (2, 9, 23, 6, 3, 8, False, 0, 0),
+    (1, 150, 150, 4, 1, 8, True, 32, 64),   # 5 x 3 tiles, MQA, padding
+    (1, 129, 129, 2, 2, 80, False, 0, 0),   # CLIP vision: one 136 tile
+    (1, 77, 77, 2, 2, 80, True, 0, 0),      # CLIP text: one 80 tile
 ]
 
 
 @pytest.mark.parametrize("case", BWD_CASES)
 @pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
 def test_flash_bwd_matches_dense_grads(case, backend):
-    B, Sq, Sk, H, KV, hd, causal = case
+    B, Sq, Sk, H, KV, hd, causal, bq, bk = case
     q, k, v = _qkv(B, Sq, Sk, H, KV, hd)
     g = jax.random.normal(kg, (B, Sq, H, hd), jnp.float32)
 
@@ -125,7 +134,8 @@ def test_flash_bwd_matches_dense_grads(case, backend):
 
     def loss_flash(q, k, v):
         return jnp.vdot(FA.flash_attention(
-            q, k, v, causal=causal, backend=backend).astype(jnp.float32), g)
+            q, k, v, causal=causal, backend=backend, block_q=bq,
+            block_k=bk).astype(jnp.float32), g)
 
     ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     got = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
@@ -415,10 +425,29 @@ def test_backend_validation():
         FA.flash_attention(q, k, v, causal=True, backend="triton")
 
 
-def test_choose_attn_blocks():
-    assert FA.choose_attn_blocks(4096, 4096) == (128, 128)
-    assert FA.choose_attn_blocks(13, 70) == (16, 128)
-    assert FA.choose_attn_blocks(4096, 4096, 256, 64) == (256, 64)
+@pytest.mark.parametrize("Sq,Sk,block_q,block_k,group,hd,want", [
+    (4096, 4096, 0, 0, 1, 64, (FA.TILE_CAP_Q, FA.TILE_CAP_K)),  # the caps
+    (4096, 4096, 0, 0, 3, 64, (512, 1024)),     # smollm-360m: 8 x 4 tiles
+    (129, 129, 0, 0, 1, 80, (136, 136)),   # CLIP vision: one tile, 7 pad rows
+    (77, 77, 0, 0, 1, 64, (80, 80)),       # CLIP text
+    (13, 13, 0, 0, 1, 64, (16, 16)),
+    (13, 70, 0, 0, 1, 64, (16, 72)),       # each axis on its own
+    (600, 600, 0, 0, 1, 64, (304, 600)),   # Q: two equal 8-aligned tiles
+    (1500, 1500, 0, 0, 1, 64, (504, 752)),  # 3 Q tiles, 2 K tiles
+    (4096, 4096, 0, 0, 8, 128, (192, 1024)),   # VMEM cuts the Q tile
+    (4096, 4096, 0, 0, 48, 128, (32, 1024)),
+    (4096, 4096, 256, 64, 3, 64, (256, 64)),   # configured sizes win
+])
+def test_choose_attn_blocks(Sq, Sk, block_q, block_k, group, hd, want):
+    assert FA.choose_attn_blocks(Sq, Sk, block_q, block_k, group=group,
+                                 hd=hd) == want
+
+
+@pytest.mark.parametrize("S,block_k,want", [
+    (4096, 0, 128), (96, 128, 96), (200, 0, 40), (24, 0, 24)])
+def test_choose_decode_block(S, block_k, want):
+    """The decode tile divides the ring cache: 128 where it can."""
+    assert FA.choose_decode_block(S, block_k) == want
 
 
 def test_explicit_block_sizes_reach_kernel():
