@@ -1,4 +1,5 @@
-"""Architecture registry: the 10 assigned architectures + the paper's own
+"""Architecture registry: the 10 assigned architectures, Moonlight-16B-A3B
+(latent attention and a DeepSeek-V3 mixture of experts) and the paper's own
 CLIP ViT-Huge. `get_config(name)` returns the exact published config;
 each arch's same-family shrunken config for CPU runs is registered under
 its own name, ``<arch>-reduced`` (``get_config("smollm-360m-reduced")``,
@@ -10,13 +11,14 @@ import dataclasses
 import importlib
 
 from repro.configs.base import (CLIPConfig, EncDecConfig, MambaConfig,
-                                ModelConfig, MoEConfig, ParallelConfig,
+                                MLAConfig, ModelConfig, MoEConfig,
+                                ParallelConfig,
                                 RWKVConfig, ShapeConfig, SHAPES, TrainConfig)
 
 ARCH_IDS = (
     "qwen3-moe-30b-a3b", "arctic-480b", "rwkv6-1.6b", "internvl2-76b",
     "smollm-360m", "starcoder2-3b", "granite-20b", "minitron-8b",
-    "seamless-m4t-large-v2", "jamba-v0.1-52b",
+    "seamless-m4t-large-v2", "jamba-v0.1-52b", "moonlight-16b-a3b",
 )
 PAPER_ARCH = "clip-vit-huge"
 ALL_ARCHS = ARCH_IDS + (PAPER_ARCH,)
@@ -34,6 +36,7 @@ _MODULES = {
     "minitron-8b": "minitron_8b",
     "seamless-m4t-large-v2": "seamless_m4t_large_v2",
     "jamba-v0.1-52b": "jamba_v0_1_52b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
     "clip-vit-huge": "clip_vit_huge",
 }
 
@@ -62,6 +65,7 @@ def shapes_for(name: str):
     out = ["train_4k", "prefill_32k", "decode_32k"]
     if getattr(cfg, "supports_long_context", False):
         out.append("long_500k")
-    if name == PAPER_ARCH:
-        out = ["train_4k"]   # CLIP is a training-only two-tower model
+    if name == PAPER_ARCH or getattr(cfg, "mla", None) is not None:
+        out = ["train_4k"]   # CLIP is a training-only two-tower model;
+        # latent attention trains but has no decode cache yet
     return [SHAPES[s] if isinstance(s, str) else s for s in out]

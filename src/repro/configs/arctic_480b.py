@@ -12,7 +12,7 @@ CONFIG = ModelConfig(
     n_kv_heads=8,
     d_ff=4864,
     vocab_size=32000,
-    moe=MoEConfig(n_experts=128, top_k=2, capacity_factor=1.25,
+    moe=MoEConfig(n_experts=128, top_k=2,
                   every_n_layers=1, dense_residual=True,
                   dense_residual_ff=7168),   # arctic residual MLP ~ d_model
     rope_theta=1e6,
@@ -28,7 +28,7 @@ REDUCED = ModelConfig(
     n_kv_heads=2,
     d_ff=96,
     vocab_size=512,
-    moe=MoEConfig(n_experts=8, top_k=2, capacity_factor=1.25,
+    moe=MoEConfig(n_experts=8, top_k=2,
                   every_n_layers=1, dense_residual=True,
                   dense_residual_ff=128),
     rope_theta=1e4,

@@ -7,13 +7,37 @@ from typing import Optional, Tuple
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    n_experts: int                   # experts the router scores
     top_k: int
-    capacity_factor: float = 1.25
     every_n_layers: int = 1          # MoE MLP every N layers (1 = all)
     dense_residual: bool = False     # arctic: dense FFN in parallel w/ MoE
     dense_residual_ff: int = 0       # width of the parallel dense FFN
-    router_dtype: str = "float32"
+    expert_ff: int = 0               # routed expert width; 0 => d_model's d_ff
+    scoring: str = "softmax"         # softmax | sigmoid (DeepSeek-V3: the
+    # top-k is chosen on score + a selection-only bias, gates are the raw
+    # chosen scores)
+    norm_topk: bool = True           # gates renormalised to sum 1
+    routed_scale: float = 1.0        # gates times this (V3 routed_scaling_factor)
+    n_shared: int = 0                # shared experts, run as one SwiGLU of
+    # width n_shared * expert_ff through quant_linear
+    balance: str = "switch"          # switch (top-1 density x mean prob) |
+    # seq (DeepSeek-V3 sequence-wise: per sequence f_i * P_i over experts)
+    balance_alpha: float = 0.01      # weight of the balance loss in the loss
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3) without a query LoRA:
+    q straight from x; k and v up-projected from one RMS-normed latent of
+    ``kv_lora_rank``; a ``qk_rope_dim`` key part shared by all heads."""
+    kv_lora_rank: int = 512
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_dim: int = 128
+
+    @property
+    def qk_dim(self) -> int:
+        return self.qk_nope_dim + self.qk_rope_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,6 +73,12 @@ class ModelConfig:
     vocab_size: int
     head_dim: int = 0                # 0 => d_model // n_heads
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None
+    first_k_dense: int = 0           # leading dense layers (d_ff wide) ahead
+    # of the scanned layer groups (DeepSeek-V3 first_k_dense_replace)
+    held_experts: Optional[Tuple[int, int]] = None   # (first id, count) of
+    # the routed experts this chip holds under expert parallelism; None =
+    # all of them. The router still scores moe.n_experts.
     mamba: Optional[MambaConfig] = None
     rwkv: Optional[RWKVConfig] = None
     encdec: Optional[EncDecConfig] = None
@@ -64,9 +94,19 @@ class ModelConfig:
     layer_scale_init: Optional[float] = None   # None = off; 0.0 = paper's zero-init
     logit_softcap: float = 0.0
 
+    def __post_init__(self):
+        if self.held_experts is not None:       # a JSON list -> a tuple
+            object.__setattr__(self, "held_experts",
+                               tuple(int(v) for v in self.held_experts))
+
     @property
     def hd(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first id, count) of the routed experts held here."""
+        return self.held_experts or (0, self.moe.n_experts)
 
     @property
     def is_attention_free(self) -> bool:
@@ -132,8 +172,6 @@ class ParallelConfig:
     # the wire tensor-wise-quantized; free under SwitchBack (§Perf it. 2)
     pure_dp: bool = False            # fold the model axis into data
     # parallelism (models too small to need TP, e.g. 1B CLIP on 256 chips)
-    moe_grouped: bool = True         # grouped (locality-aware) MoE dispatch;
-    # False reverts to the flat global-sort formulation (v1 baseline)
     shard_kv_heads: bool = True      # False: replicate K/V projections —
     # when n_kv_heads < model-axis size, sharding the flat KV dim splits
     # heads across devices and GSPMD regathers at the head reshape

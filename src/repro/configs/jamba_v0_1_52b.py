@@ -12,7 +12,7 @@ CONFIG = ModelConfig(
     n_kv_heads=8,
     d_ff=14336,
     vocab_size=65536,
-    moe=MoEConfig(n_experts=16, top_k=2, capacity_factor=1.25,
+    moe=MoEConfig(n_experts=16, top_k=2,
                   every_n_layers=2),
     mamba=MambaConfig(d_state=16, d_conv=4, expand=2),
     attn_layer_period=8,
@@ -30,7 +30,7 @@ REDUCED = ModelConfig(
     n_kv_heads=2,
     d_ff=256,
     vocab_size=512,
-    moe=MoEConfig(n_experts=4, top_k=2, capacity_factor=1.25,
+    moe=MoEConfig(n_experts=4, top_k=2,
                   every_n_layers=2),
     mamba=MambaConfig(d_state=8, d_conv=4, expand=2),
     attn_layer_period=8,

@@ -12,7 +12,7 @@ CONFIG = ModelConfig(
     d_ff=768,
     vocab_size=151936,
     head_dim=128,            # qwen3 uses head_dim 128 (> d_model/n_heads)
-    moe=MoEConfig(n_experts=128, top_k=8, capacity_factor=1.25,
+    moe=MoEConfig(n_experts=128, top_k=8,
                   every_n_layers=1),
     rope_theta=1e6,
     act="swiglu",
@@ -28,7 +28,7 @@ REDUCED = ModelConfig(
     d_ff=96,
     vocab_size=512,
     head_dim=32,
-    moe=MoEConfig(n_experts=8, top_k=2, capacity_factor=1.25,
+    moe=MoEConfig(n_experts=8, top_k=2,
                   every_n_layers=1),
     rope_theta=1e4,
     act="swiglu",

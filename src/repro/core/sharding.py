@@ -65,14 +65,12 @@ class ShardCtx:
 
     def __init__(self, mesh: Optional[Mesh], rules: Optional[dict],
                  rules_nofsdp: Optional[dict] = None,
-                 gather_fsdp: bool = False, gather_wire: str = "bf16",
-                 moe_grouped: bool = True):
+                 gather_fsdp: bool = False, gather_wire: str = "bf16"):
         self.mesh = mesh
         self.rules = rules
         self.rules_nofsdp = rules_nofsdp or rules
         self.gather_fsdp = gather_fsdp
         self.gather_wire = gather_wire
-        self.moe_grouped = moe_grouped
 
     def constrain(self, x: jax.Array, logical: Tuple[Optional[str], ...]):
         if self.mesh is None or self.rules is None:

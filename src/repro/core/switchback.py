@@ -413,3 +413,53 @@ def switchback_linear(x: Array, w: Array, b: Array | None = None, *,
     if b is not None:
         y = y + b.astype(y.dtype)
     return y
+
+
+# ---------------------------------------------------------------------------
+# grouped SwitchBack: the routed experts of a dropless MoE layer
+# ---------------------------------------------------------------------------
+
+def quantize_per_expert(w: Array) -> Tuple[Array, Array]:
+    """w (E, n, m) -> (int8 (E, n, m), absmax (E, 1, 1) f32): each expert's
+    weight tensor-wise, as SwitchBack quantizes one linear's."""
+    wf = w.astype(jnp.float32)
+    s = jnp.maximum(jnp.max(jnp.abs(wf), axis=(1, 2), keepdims=True), 1e-12)
+    return jnp.round(wf * (Q.INT8_QMAX / s)).astype(jnp.int8), s
+
+
+@functools.lru_cache(maxsize=None)
+def make_grouped_switchback(backend: str = "xla", block_m: int = 256):
+    """``f(x, w, tile_expert, n_live) -> y``: SwitchBack (Alg. 1) for the
+    rows of every expert at once. x (R, n) holds each expert's slots in
+    whole ``block_m``-row tiles, tile i of expert ``tile_expert[i]``;
+    ``n_live`` (1,) counts the tiles that hold slots. Forward and input
+    gradient run int8 (rows per row, each expert's weight per tensor), the
+    weight gradient (E, n, m) in bf16 with f32 accumulation, through the
+    ``moe_gmm_*`` kernels on the Pallas backends. Rows of tiles past
+    ``n_live`` come out undefined in y and dx; the caller masks them."""
+    if backend not in BACKENDS:
+        raise ValueError(f"unknown kernel backend {backend!r}; "
+                         f"expected one of {BACKENDS}")
+
+    def fwd(x, w, tile_expert, n_live):
+        w_q, s_w = quantize_per_expert(w)
+        y = KOPS.moe_gmm_fwd(x, w_q, s_w, tile_expert, n_live,
+                             block_m=block_m, out_dtype=x.dtype,
+                             backend=backend)
+        return y, (x, w_q, s_w, tile_expert, n_live)
+
+    def bwd(res, g):
+        x, w_q, s_w, tile_expert, n_live = res
+        dx = KOPS.moe_gmm_dgrad(g, w_q, s_w, tile_expert, n_live,
+                                block_m=block_m, out_dtype=x.dtype,
+                                backend=backend)
+        dw = KOPS.moe_gmm_wgrad(x, g, tile_expert, n_live, block_m=block_m,
+                                n_experts=w_q.shape[0], backend=backend)
+        return dx, dw, None, None
+
+    @jax.custom_vjp
+    def grouped(x, w, tile_expert, n_live):
+        return fwd(x, w, tile_expert, n_live)[0]
+
+    grouped.defvjp(fwd, bwd)
+    return grouped

@@ -44,8 +44,10 @@ kernel has a pure-jnp oracle in ``ref.py``; tests sweep shapes in
 interpret mode (tests/test_attention_kernels.py).
 
 Shape contract (enforced by ops.py, which pads): kernel-layout operands
-q (B, H, Sq, hd), k/v (B, KV, Sk, hd) with Sq % block_q == 0,
-Sk % block_k == 0, H % KV == 0; ``kv_valid`` is the static true Sk before
+q, k (B, H|KV, S, dqk) and v (B, KV, Sk, dv) with Sq % block_q == 0,
+Sk % block_k == 0, H % KV == 0; the value and output width ``dv`` may
+differ from the query/key width ``dqk`` (latent attention: 192 and 128),
+neither padded to the other. ``kv_valid`` is the static true Sk before
 padding (pad keys are masked in-kernel).
 """
 from __future__ import annotations
@@ -84,8 +86,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def _tile():
-        k = k_ref[0, 0].astype(jnp.float32)                 # (bk, hd)
-        v = v_ref[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                 # (bk, dqk)
+        v = v_ref[0, 0].astype(jnp.float32)                 # (bk, dv)
         kpos = ik * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
         mask = kpos < kv_valid
@@ -96,7 +98,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         for g in range(group):                              # unrolled: the
             # KV tile is fetched ONCE per grid step and reused by every
             # query head in this KV head's GQA group
-            q = q_ref[0, g].astype(jnp.float32) * scale     # (bq, hd)
+            q = q_ref[0, g].astype(jnp.float32) * scale     # (bq, dqk)
             s = jax.lax.dot_general(                        # (bq, bk)
                 q, k, dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -132,41 +134,46 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
 def flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
               kv_valid: int, scale: float, block_q: int = 128,
               block_k: int = 128, interpret: bool = False):
-    """q (B, H, Sq, hd); k, v (B, KV, Sk, hd). Returns (o (B, H, Sq, hd)
-    in q.dtype, lse (B, H, Sq, 1) f32). ``kv_valid`` masks pad keys.
+    """q (B, H, Sq, dqk); k (B, KV, Sk, dqk); v (B, KV, Sk, dv). Returns
+    (o (B, H, Sq, dv) in q.dtype, lse (B, H, Sq, 1) f32). ``kv_valid``
+    masks pad keys.
 
     The grid walks KV heads, not query heads — the whole GQA group shares
     each fetched K/V tile via the in-kernel head loop, so KV HBM traffic
     is group-(H/KV)-fold lower than a query-head grid."""
-    B, H, Sq, hd = q.shape
-    KV, Sk = k.shape[1], k.shape[2]
+    B, H, Sq, dqk = q.shape
+    KV, Sk, dv = k.shape[1], k.shape[2], v.shape[3]
     group = H // KV
     n_q, n_k = Sq // block_q, Sk // block_k
     grid = (B, KV, n_q, n_k)
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, kv_valid=kv_valid,
         group=group, n_k=n_k, block_q=block_q, block_k=block_k)
-    q_spec = pl.BlockSpec((1, group, block_q, hd),
+    q_spec = pl.BlockSpec((1, group, block_q, dqk),
                           lambda b, h, iq, ik: (b, h, iq, 0))
-    kv_spec = pl.BlockSpec((1, 1, block_k, hd),
-                           lambda b, h, iq, ik: (b, h, ik, 0))
+    o_spec = pl.BlockSpec((1, group, block_q, dv),
+                          lambda b, h, iq, ik: (b, h, iq, 0))
+    k_spec = pl.BlockSpec((1, 1, block_k, dqk),
+                          lambda b, h, iq, ik: (b, h, ik, 0))
+    v_spec = pl.BlockSpec((1, 1, block_k, dv),
+                          lambda b, h, iq, ik: (b, h, ik, 0))
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec],
+        in_specs=[q_spec, k_spec, v_spec],
         out_specs=[
-            q_spec,
+            o_spec,
             pl.BlockSpec((1, group, block_q, 1),
                          lambda b, h, iq, ik: (b, h, iq, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Sq, dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((group, block_q, 1), jnp.float32),   # running max
             pltpu.VMEM((group, block_q, 1), jnp.float32),   # running denom
-            pltpu.VMEM((group, block_q, hd), jnp.float32),  # unnormed out
+            pltpu.VMEM((group, block_q, dv), jnp.float32),  # unnormed out
         ],
         name="flash_fwd",
         interpret=interpret,
@@ -226,28 +233,33 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
 def flash_bwd_dq(q, k, v, do, lse, di, *, causal: bool, kv_valid: int,
                  scale: float, block_q: int = 128, block_k: int = 128,
                  interpret: bool = False):
-    """Returns dq (B, H, Sq, hd) f32. lse/di are (B, H, Sq, 1) f32. Same
-    KV-head grid + in-kernel group loop as flash_fwd."""
-    B, H, Sq, hd = q.shape
-    KV, Sk = k.shape[1], k.shape[2]
+    """Returns dq (B, H, Sq, dqk) f32; ``do`` is (B, H, Sq, dv) and lse/di
+    are (B, H, Sq, 1) f32. Same KV-head grid + in-kernel group loop as
+    flash_fwd."""
+    B, H, Sq, dqk = q.shape
+    KV, Sk, dv = k.shape[1], k.shape[2], v.shape[3]
     group = H // KV
     n_q, n_k = Sq // block_q, Sk // block_k
     kernel = functools.partial(
         _bwd_dq_kernel, scale=scale, causal=causal, kv_valid=kv_valid,
         group=group, n_k=n_k, block_q=block_q, block_k=block_k)
-    q_spec = pl.BlockSpec((1, group, block_q, hd),
+    q_spec = pl.BlockSpec((1, group, block_q, dqk),
                           lambda b, h, iq, ik: (b, h, iq, 0))
-    kv_spec = pl.BlockSpec((1, 1, block_k, hd),
-                           lambda b, h, iq, ik: (b, h, ik, 0))
+    do_spec = pl.BlockSpec((1, group, block_q, dv),
+                           lambda b, h, iq, ik: (b, h, iq, 0))
+    k_spec = pl.BlockSpec((1, 1, block_k, dqk),
+                          lambda b, h, iq, ik: (b, h, ik, 0))
+    v_spec = pl.BlockSpec((1, 1, block_k, dv),
+                          lambda b, h, iq, ik: (b, h, ik, 0))
     stat_spec = pl.BlockSpec((1, group, block_q, 1),
                              lambda b, h, iq, ik: (b, h, iq, 0))
     return pl.pallas_call(
         kernel,
         grid=(B, KV, n_q, n_k),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec],
+        in_specs=[q_spec, k_spec, v_spec, do_spec, stat_spec, stat_spec],
         out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((group, block_q, hd), jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq, dqk), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((group, block_q, dqk), jnp.float32)],
         name="flash_bwd_dq",
         interpret=interpret,
     )(q, k, v, do, lse, di)
@@ -271,8 +283,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     def _tile():
-        k = k_ref[0, 0].astype(jnp.float32)                 # (bk, hd)
-        v = v_ref[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                 # (bk, dqk)
+        v = v_ref[0, 0].astype(jnp.float32)                 # (bk, dv)
         kpos = ik * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
         mask = kpos < kv_valid
@@ -281,7 +293,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
                 jnp.int32, (block_q, block_k), 0)
             mask = mask & (kpos <= qpos)
         for g in range(group):                              # unrolled
-            q = q_ref[0, g].astype(jnp.float32)             # (bq, hd)
+            q = q_ref[0, g].astype(jnp.float32)             # (bq, dqk)
             do = do_ref[0, g].astype(jnp.float32)
             s = scale * jax.lax.dot_general(
                 q, k, dimension_numbers=(((1,), (1,)), ((), ())),
@@ -313,10 +325,11 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
 def flash_bwd_dkv(q, k, v, do, lse, di, *, causal: bool, kv_valid: int,
                   scale: float, block_q: int = 128, block_k: int = 128,
                   interpret: bool = False):
-    """Returns (dk, dv) both (B, KV, Sk, hd) f32 — already summed over each
-    KV head's GQA group (the in-kernel head loop)."""
-    B, H, Sq, hd = q.shape
-    KV, Sk = k.shape[1], k.shape[2]
+    """Returns dk (B, KV, Sk, dqk) and dv (B, KV, Sk, dv), both f32 and
+    already summed over each KV head's GQA group (the in-kernel head
+    loop)."""
+    B, H, Sq, dqk = q.shape
+    KV, Sk, dv = k.shape[1], k.shape[2], v.shape[3]
     group = H // KV
     n_q, n_k = Sq // block_q, Sk // block_k
     kernel = functools.partial(
@@ -324,21 +337,25 @@ def flash_bwd_dkv(q, k, v, do, lse, di, *, causal: bool, kv_valid: int,
         group=group, n_q=n_q, block_q=block_q, block_k=block_k)
     # head-block of `group` query heads: block index h covers the KV head
     # h's whole query group
-    q_spec = pl.BlockSpec((1, group, block_q, hd),
+    q_spec = pl.BlockSpec((1, group, block_q, dqk),
                           lambda b, h, ik, iq: (b, h, iq, 0))
+    do_spec = pl.BlockSpec((1, group, block_q, dv),
+                           lambda b, h, ik, iq: (b, h, iq, 0))
     stat_spec = pl.BlockSpec((1, group, block_q, 1),
                              lambda b, h, ik, iq: (b, h, iq, 0))
-    kv_spec = pl.BlockSpec((1, 1, block_k, hd),
-                           lambda b, h, ik, iq: (b, h, ik, 0))
+    k_spec = pl.BlockSpec((1, 1, block_k, dqk),
+                          lambda b, h, ik, iq: (b, h, ik, 0))
+    v_spec = pl.BlockSpec((1, 1, block_k, dv),
+                          lambda b, h, ik, iq: (b, h, ik, 0))
     return pl.pallas_call(
         kernel,
         grid=(B, KV, n_k, n_q),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec],
-        out_specs=[kv_spec, kv_spec],
-        out_shape=[jax.ShapeDtypeStruct((B, KV, Sk, hd), jnp.float32),
-                   jax.ShapeDtypeStruct((B, KV, Sk, hd), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((block_k, hd), jnp.float32),
-                        pltpu.VMEM((block_k, hd), jnp.float32)],
+        in_specs=[q_spec, k_spec, v_spec, do_spec, stat_spec, stat_spec],
+        out_specs=[k_spec, v_spec],
+        out_shape=[jax.ShapeDtypeStruct((B, KV, Sk, dqk), jnp.float32),
+                   jax.ShapeDtypeStruct((B, KV, Sk, dv), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_k, dqk), jnp.float32),
+                        pltpu.VMEM((block_k, dv), jnp.float32)],
         name="flash_bwd_dkv",
         interpret=interpret,
     )(q, k, v, do, lse, di)

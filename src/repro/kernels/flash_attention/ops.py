@@ -7,8 +7,11 @@ the static tile choice from the call's sequence lengths, and the
 ``custom_vjp`` that wires the recompute-style backward kernels in
 (DESIGN.md §9).
 
-Model-layout contract (what models/attention.py speaks): q (B, Sq, H, hd),
-k/v (B, Sk, KV, hd) with H a multiple of KV (GQA); outputs match q.
+Model-layout contract (what models/attention.py speaks): q (B, Sq, H, dqk),
+k (B, Sk, KV, dqk), v (B, Sk, KV, dv) with H a multiple of KV (GQA); the
+output is (B, Sq, H, dv) in q's dtype. The value width ``dv`` may differ
+from the query/key width ``dqk`` (latent attention: 192 and 128); the
+softmax scale is 1/sqrt(dqk).
 """
 from __future__ import annotations
 
@@ -37,6 +40,11 @@ TILE_CAP_K = 1024
 # padded to the 128 lanes). The Q tile shrinks to keep that product at
 # what 512 rows of smollm-360m's 3 heads of 64 hold.
 Q_GROUP_ELEMS = TILE_CAP_Q * 3 * 128
+# Latent attention (Moonlight-16B-A3B: B 2, S 8192, 16 heads, q/k 192 and
+# v 128 wide, no GQA) takes 512 x 1024 on a v5e: 192 lanes pad to 256, and
+# one head's 512 x 256 Q block is under the group cap, so the Q tile stays
+# at TILE_CAP_Q; the grid is 2 x 16 x 16 x 8 steps per kernel call
+# (tests/test_tpu_compile.py compiles it).
 
 # Decode tile over the ring cache (choose_decode_block)
 DECODE_BLOCK = 128
@@ -77,10 +85,12 @@ def choose_decode_block(S: int, block_k: int = 0) -> int:
     return bk
 
 
-def _blocks(q, k, block_q: int, block_k: int) -> tuple[int, int]:
-    """choose_attn_blocks for model-layout operands."""
+def _blocks(q, k, v, block_q: int, block_k: int) -> tuple[int, int]:
+    """choose_attn_blocks for model-layout operands, sized by the wider of
+    the query/key and value widths."""
     return choose_attn_blocks(q.shape[1], k.shape[1], block_q, block_k,
-                              group=q.shape[2] // k.shape[2], hd=q.shape[3])
+                              group=q.shape[2] // k.shape[2],
+                              hd=max(q.shape[3], v.shape[3]))
 
 
 def _pad_seq(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -98,19 +108,27 @@ def _to_kernel(x: jax.Array) -> jax.Array:
 
 
 def _check(q, k, v):
-    B, Sq, H, hd = q.shape
+    """Shapes of one call: k and v share batch, keys and heads; q and k
+    share batch and width (dqk); v's width (dv) is its own. Returns the
+    softmax scale 1/sqrt(dqk)."""
+    B, Sq, H, dqk = q.shape
     KV = k.shape[2]
-    assert k.shape == v.shape and k.shape[0] == B and k.shape[3] == hd, \
-        (q.shape, k.shape, v.shape)
-    assert H % KV == 0, f"GQA needs H % KV == 0, got {H} % {KV}"
-    return 1.0 / math.sqrt(hd)
+    if (k.shape[:3] != v.shape[:3] or k.shape[0] != B
+            or k.shape[3] != dqk):
+        raise ValueError(f"flash attention shapes q {q.shape}, k {k.shape}, "
+                         f"v {v.shape}: k and v must share batch, keys and "
+                         f"heads, q and k batch and width")
+    if H % KV:
+        raise ValueError(f"GQA needs H % KV == 0, got {H} % {KV}")
+    return 1.0 / math.sqrt(dqk)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("causal", "backend", "block_q", "block_k"))
 def flash_fwd_lse(q, k, v, *, causal: bool, backend: Backend = "xla",
                   block_q: int = 0, block_k: int = 0):
-    """Raw forward: (o (B, Sq, H, hd) in q.dtype, lse (B, H, Sq) f32).
+    """Raw forward: (o (B, Sq, H, dv) in q.dtype, lse (B, H, Sq) f32) for
+    q (B, Sq, H, dqk), k (B, Sk, KV, dqk), v (B, Sk, KV, dv).
 
     The non-differentiable entry point (tests, benchmarks, inference
     paths); training goes through :func:`flash_attention`.
@@ -121,7 +139,7 @@ def flash_fwd_lse(q, k, v, *, causal: bool, backend: Backend = "xla",
         o, lse = _ref.mha_fwd(_to_kernel(q), _to_kernel(k), _to_kernel(v),
                               causal=causal, kv_valid=Sk, scale=scale)
         return _to_kernel(o), lse
-    bq, bk = _blocks(q, k, block_q, block_k)
+    bq, bk = _blocks(q, k, v, block_q, block_k)
     qk = _pad_seq(_to_kernel(q), 2, bq)
     kk = _pad_seq(_to_kernel(k), 2, bk)
     vk = _pad_seq(_to_kernel(v), 2, bk)
@@ -143,7 +161,7 @@ def _bwd_impl(causal, backend, block_q, block_k, res, do):
             _to_kernel(q), _to_kernel(k), _to_kernel(v), _to_kernel(o),
             lse, _to_kernel(do), causal=causal, kv_valid=Sk, scale=scale)
     else:
-        bq, bk = _blocks(q, k, block_q, block_k)
+        bq, bk = _blocks(q, k, v, block_q, block_k)
         interp = backend == "pallas_interpret"
         qp = _pad_seq(_to_kernel(q), 2, bq)
         kp = _pad_seq(_to_kernel(k), 2, bk)
@@ -193,9 +211,10 @@ def flash_attention(q, k, v, *, causal: bool, backend: Backend = "xla",
                     block_q: int = 0, block_k: int = 0):
     """Differentiable fused attention, model layout.
 
-    q (B, Sq, H, hd); k, v (B, Sk, KV, hd) — KV heads stay folded (the
-    kernel maps query head h onto KV head h // group; no jnp.repeat).
-    Gradients flow to q, k, v via the recompute-style backward kernels.
+    q (B, Sq, H, dqk); k (B, Sk, KV, dqk); v (B, Sk, KV, dv) — KV heads
+    stay folded (the kernel maps query head h onto KV head h // group; no
+    jnp.repeat). Returns (B, Sq, H, dv). Gradients flow to q, k, v via the
+    recompute-style backward kernels.
     """
     return make_flash_attention(causal, backend, block_q, block_k)(q, k, v)
 
@@ -208,7 +227,9 @@ def decode_attention(q, k, v, kv_len, *, backend: Backend = "xla",
     q (B, 1, H, hd); k, v (B, KV, S_max, hd) in the cache's storage layout;
     kv_len (B,) int32 — valid cells per slot (``min(length + 1, S_max)``,
     so ring-wrapped slots attend over the whole window). Returns
-    (B, 1, H, hd). Tiles beyond a slot's length are skipped dynamically on
+    (B, 1, H, hd). One width ``hd`` serves queries, keys and values here:
+    the decode kernel does not take ``dv != dqk`` (no latent cache is
+    served yet). Tiles beyond a slot's length are skipped dynamically on
     the pallas backends.
     """
     B, one, H, hd = q.shape

@@ -1,7 +1,7 @@
 """Pure-jnp oracles for the flash-attention kernels.
 
-Same kernel-layout contract as flash_attention.py — q (B, H, Sq, hd),
-k/v (B, KV, Sk, hd) — but materialising the full (Sq, Sk) score matrix.
+Same kernel-layout contract as flash_attention.py — q (B, H, Sq, dqk),
+k (B, KV, Sk, dqk), v (B, KV, Sk, dv) — but materialising the full (Sq, Sk) score matrix.
 These are the ``backend="xla"`` implementations behind ops.py AND the
 parity oracles the interpret-mode tests compare against; the backward is
 written out explicitly (the same p/ds algebra the kernels use) rather
@@ -67,9 +67,9 @@ def mha_bwd(q, k, v, o, lse, do, *, causal: bool, kv_valid: int,
     dk = scale * jnp.einsum("bhqk,bhqd->bhkd", ds, q.astype(jnp.float32))
     group = H // KV
     if group > 1:                                           # GQA group-sum
-        B, _, Sk, hd = dk.shape
-        dk = dk.reshape(B, KV, group, Sk, hd).sum(axis=2)
-        dv = dv.reshape(B, KV, group, Sk, hd).sum(axis=2)
+        B, _, Sk, _ = dk.shape
+        dk = dk.reshape(B, KV, group, Sk, dk.shape[-1]).sum(axis=2)
+        dv = dv.reshape(B, KV, group, Sk, dv.shape[-1]).sum(axis=2)
     return dq, dk, dv
 
 

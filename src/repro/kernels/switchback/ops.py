@@ -12,6 +12,7 @@ from typing import Literal
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.switchback import grouped as _g
 from repro.kernels.switchback import ref as _ref
 from repro.kernels.switchback import switchback as _k
 
@@ -75,6 +76,25 @@ def _pad_to(x: jax.Array, mult: tuple[int, int]) -> jax.Array:
     return x
 
 
+# Row-wise quantize holds whole rows: a block's input, int8 output (both
+# double-buffered) and f32 temporaries take about 6 bytes per element of
+# scoped VMEM (256 rows of 11264 asked 16.5 MiB of the default 16).
+ROW_QUANT_VMEM_BYTES = 12 * 1024 * 1024
+
+
+def row_quant_block(B: int, K: int) -> int:
+    """Rows per row-quantize block: 256, or the largest power of two that
+    fits ``ROW_QUANT_VMEM_BYTES`` at width ``K`` (11264 -> 128; at 160 rows
+    the compiler asked more than at 256), at least 32, or all of B when
+    fewer."""
+    if B < 256:
+        return B
+    bb = 256
+    while bb > 32 and 6 * K * bb > ROW_QUANT_VMEM_BYTES:
+        bb //= 2
+    return bb
+
+
 @functools.partial(jax.jit, static_argnames=("backend",))
 def row_quantize(x: jax.Array, backend: Backend = "xla"):
     """x (B, K) -> (q int8 (B, K), state f32 (B, 1))."""
@@ -82,7 +102,7 @@ def row_quantize(x: jax.Array, backend: Backend = "xla"):
         return _ref.row_quantize(x)
     interp = backend == "pallas_interpret"
     B = x.shape[0]
-    bb = 256 if B >= 256 else B
+    bb = row_quant_block(B, x.shape[1])
     xp = _pad_to(x, (bb, 1))
     q, s = _k.row_quantize(xp, block_b=bb, interpret=interp)
     return q[:B], s[:B]
@@ -198,3 +218,96 @@ def wgrad_bf16(x, g, backend: Backend = "xla"):
     y = _k.wgrad_bf16(xp, gp, block_k=bk, block_m=bm, block_b=bb,
                       interpret=interp)
     return y[:K, :M]
+
+
+# ---------------------------------------------------------------------------
+# grouped (mixture-of-experts) SwitchBack matmuls, kernels/switchback/grouped
+# ---------------------------------------------------------------------------
+
+# Rows per tile of the grouped kernels: each expert's slots are padded to a
+# multiple of this, so a tile belongs to one expert.
+MOE_TILE_ROWS = 256
+
+
+def _divisors128(dim: int) -> list:
+    """Multiples of 128 that divide ``dim``, or ``[dim]`` when none does."""
+    return [b for b in range(128, dim + 1, 128) if dim % b == 0] or [dim]
+
+
+# Widest column block of the grouped forward and input gradient. Each
+# column block quantizes its row tile again and walks every tile of the
+# grid, dead ones included, so one block over the whole width is fastest:
+# at Moonlight's widths (2048 x 1408 up, 1408 x 2048 down) a call took
+# 0.65-0.70 ms at 8 live tiles of 392 with whole-width blocks against
+# 0.75-0.77 with 1024-wide ones, and no slower per live tile (a v5e).
+MOE_COL_CAP = 2048
+
+
+def moe_cols(dim: int, cap: int = MOE_COL_CAP) -> int:
+    """Columns per block of a grouped kernel: the largest divisor of
+    ``dim`` that is a multiple of 128 and at most ``cap``, else all of
+    ``dim`` (1408 -> 1408, 2048 -> 2048, 7168 -> 1792)."""
+    return max([b for b in _divisors128(dim) if b <= cap], default=dim)
+
+
+def moe_wgrad_blocks(K: int, N: int, max_elems: int = 1 << 22
+                     ) -> tuple[int, int]:
+    """(block_k, block_n) of the grouped weight gradient: the largest f32
+    output block (at most ``max_elems``, 16 MiB, double-buffered inside
+    the kernels' 64 MiB of VMEM) of 128-multiple divisors. Each row tile
+    is fetched once per output block and the grid walks every tile per
+    block, so fewer, larger blocks read less and step less: 2048 x 1408
+    whole took 0.76 ms at 8 live tiles against 0.98 in 512 x 1408 blocks
+    (a v5e)."""
+    pairs = [(bk, bn) for bk in _divisors128(K) for bn in _divisors128(N)
+             if bk * bn <= max_elems]
+    if not pairs:
+        return min(_divisors128(K)), min(_divisors128(N))
+    return max(pairs, key=lambda p: (p[0] * p[1], p[0]))
+
+
+@functools.partial(jax.jit, static_argnames=("block_m", "out_dtype",
+                                             "backend"))
+def moe_gmm_fwd(x, w_q, s_w, tile_expert, n_live, *, block_m: int,
+                out_dtype=jnp.bfloat16, backend: Backend = "xla"):
+    """Grouped forward: rows of tile i (``block_m`` rows) times expert
+    ``tile_expert[i]``'s int8 weight (w_q (E, K, N), s_w (E, 1, 1)), rows
+    quantized per row. Rows of tiles from ``n_live`` (shape (1,)) on are
+    left unwritten by the kernel (zeros on ``xla``)."""
+    if backend == "xla":
+        return _ref.moe_gmm_fwd(x, w_q, s_w, tile_expert, n_live,
+                                block_m=block_m, out_dtype=out_dtype)
+    return _g.gmm_fwd(x, w_q, s_w, tile_expert, n_live, block_m=block_m,
+                      block_n=moe_cols(w_q.shape[2]),
+                      out_dtype=out_dtype,
+                      interpret=backend == "pallas_interpret")
+
+
+@functools.partial(jax.jit, static_argnames=("block_m", "out_dtype",
+                                             "backend"))
+def moe_gmm_dgrad(g, w_q, s_w, tile_expert, n_live, *, block_m: int,
+                  out_dtype=jnp.bfloat16, backend: Backend = "xla"):
+    """Grouped input gradient: g (R, N) quantized per row times each tile's
+    expert weight transposed (contracting w_q's N, never transposed)."""
+    if backend == "xla":
+        return _ref.moe_gmm_dgrad(g, w_q, s_w, tile_expert, n_live,
+                                  block_m=block_m, out_dtype=out_dtype)
+    return _g.gmm_dgrad(g, w_q, s_w, tile_expert, n_live, block_m=block_m,
+                        block_k=moe_cols(w_q.shape[1]),
+                        out_dtype=out_dtype,
+                        interpret=backend == "pallas_interpret")
+
+
+@functools.partial(jax.jit, static_argnames=("block_m", "n_experts",
+                                             "backend"))
+def moe_gmm_wgrad(x, g, tile_expert, n_live, *, block_m: int, n_experts: int,
+                  backend: Backend = "xla"):
+    """Grouped weight gradient (E, K, N) f32 from bf16 x (R, K) and g
+    (R, N), summed over each expert's rows."""
+    if backend == "xla":
+        return _ref.moe_gmm_wgrad(x, g, tile_expert, n_live, block_m=block_m,
+                                  n_experts=n_experts)
+    bk, bn = moe_wgrad_blocks(x.shape[1], g.shape[1])
+    return _g.gmm_wgrad(x, g, tile_expert, n_live, n_experts=n_experts,
+                        block_m=block_m, block_k=bk, block_n=bn,
+                        interpret=backend == "pallas_interpret")

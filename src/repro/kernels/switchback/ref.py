@@ -53,3 +53,45 @@ def wgrad_bf16(x, g):
         x.astype(jnp.bfloat16), g.astype(jnp.bfloat16),
         dimension_numbers=(((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
+
+
+# grouped (mixture-of-experts) oracles: rows in tiles of ``block_m``, tile i
+# of expert tile_expert[i]; tiles from n_live on hold no slots (zeros here)
+
+def _row_expert(tile_expert, n_live, block_m, n_rows):
+    tile = jnp.arange(n_rows) // block_m
+    live = tile < n_live.reshape(())
+    return tile_expert[tile], live
+
+
+def _group_sizes(tile_expert, n_live, block_m, n_experts):
+    live = jnp.arange(tile_expert.shape[0]) < n_live.reshape(())
+    return jnp.zeros((n_experts + 1,), jnp.int32).at[
+        jnp.where(live, tile_expert, n_experts)].add(block_m)[:n_experts]
+
+
+def moe_gmm_fwd(x, w_q, s_w, tile_expert, n_live, *, block_m,
+                out_dtype=jnp.bfloat16):
+    x_q, s_x = row_quantize(x)
+    sizes = _group_sizes(tile_expert, n_live, block_m, w_q.shape[0])
+    acc = jax.lax.ragged_dot(x_q, w_q, sizes,
+                             preferred_element_type=jnp.int32)
+    e, live = _row_expert(tile_expert, n_live, block_m, x.shape[0])
+    scale = s_x * (s_w.reshape(-1)[e][:, None] / (127.0 * 127.0))
+    return jnp.where(live[:, None], acc.astype(jnp.float32) * scale,
+                     0.0).astype(out_dtype)
+
+
+def moe_gmm_dgrad(g, w_q, s_w, tile_expert, n_live, *, block_m,
+                  out_dtype=jnp.bfloat16):
+    return moe_gmm_fwd(g, jnp.swapaxes(w_q, 1, 2), s_w, tile_expert, n_live,
+                       block_m=block_m, out_dtype=out_dtype)
+
+
+def moe_gmm_wgrad(x, g, tile_expert, n_live, *, block_m, n_experts):
+    sizes = _group_sizes(tile_expert, n_live, block_m, n_experts)
+    bf = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)
+    w0 = jnp.zeros((n_experts, x.shape[1], g.shape[1]), jnp.float32)
+    _, vjp = jax.vjp(lambda w: jax.lax.ragged_dot(
+        bf(x), w, sizes, precision=jax.lax.Precision.HIGHEST), w0)
+    return vjp(bf(g))[0]

@@ -115,7 +115,6 @@ def parallel_for(arch: str, multi_pod: bool, overrides: Optional[Dict] = None
                          pure_dp=rb.pop("pure_dp", False),
                          sequence_parallel=rb.pop("sequence_parallel", False),
                          shard_kv_heads=rb.pop("shard_kv_heads", True),
-                         moe_grouped=rb.pop("moe_grouped", True),
                          attn_impl=rb.pop("attn_impl", "flash_scan"))
     return par, n_micro
 
@@ -329,8 +328,8 @@ def train_probes(arch, cfg, shape, mesh, par, n_micro, policy, rules,
 
         def probe(gp, x):
             def f(gp, x):
-                out, _, aux = TF.group_apply(x, gp, cfg, policy, par,
-                                             positions=positions)
+                out, _, aux, _ = TF.group_apply(x, gp, cfg, policy, par,
+                                                positions=positions)
                 return jnp.sum(out.astype(jnp.float32)) + aux
             f = TF._maybe_remat(f, par)
             return jax.grad(f, argnums=(0, 1))(gp, x)
@@ -498,8 +497,9 @@ def serve_group_probe(cfg, shape, mesh, par, policy, rules, *, decode):
             logical_to_pspec(("batch", None, "embed"), rules), mesh))
 
         def probe(gp, st, x):
-            out, ns, _ = TF.group_apply(x, gp, cfg, policy, par,
-                                        positions=jnp.arange(1), states=st)
+            out, ns, _, _ = TF.group_apply(x, gp, cfg, policy, par,
+                                           positions=jnp.arange(1),
+                                           states=st)
             return out, ns
         with jax.set_mesh(mesh), make_shard_ctx(mesh, par):
             c = jax.jit(probe, in_shardings=(gp_shard, st_shard, x_sh),
@@ -514,8 +514,8 @@ def serve_group_probe(cfg, shape, mesh, par, policy, rules, *, decode):
         positions = jnp.arange(S_eff)
 
         def probe(gp, x):
-            out, _, _ = TF.group_apply(x, gp, cfg, policy, par,
-                                       positions=positions)
+            out, _, _, _ = TF.group_apply(x, gp, cfg, policy, par,
+                                          positions=positions)
             return out
         with jax.set_mesh(mesh), make_shard_ctx(mesh, par):
             c = jax.jit(probe, in_shardings=(gp_shard, x_sh)) \
@@ -644,7 +644,6 @@ def main():
     ap.add_argument("--gather-wire", default=None, choices=[None, "bf16", "int8"])
     ap.add_argument("--pure-dp", default=None, choices=[None, "on", "off"])
     ap.add_argument("--seq-parallel", default=None, choices=[None, "on", "off"])
-    ap.add_argument("--moe-grouped", default=None, choices=[None, "on", "off"])
     ap.add_argument("--n-micro", type=int, default=None)
     ap.add_argument("--remat", default=None)
     ap.add_argument("--attn-impl", default=None)
@@ -664,8 +663,6 @@ def main():
         overrides["pure_dp"] = args.pure_dp == "on"
     if args.seq_parallel is not None:
         overrides["sequence_parallel"] = args.seq_parallel == "on"
-    if args.moe_grouped is not None:
-        overrides["moe_grouped"] = args.moe_grouped == "on"
     if args.n_micro is not None:
         overrides["n_micro"] = args.n_micro
     if args.remat is not None:

@@ -34,7 +34,7 @@ from repro.kernels.flash_attention import ops as FA
 from repro.kernels.paged_attention import ops as PA
 from repro.kernels.paged_attention.ref import gather_blocks
 from repro.models import params as PRM
-from repro.models.common import apply_rope, apply_rope_cached
+from repro.models.common import apply_rope, apply_rope_cached, rms_norm
 
 Array = jax.Array
 NEG_INF = -2.0e38
@@ -143,10 +143,11 @@ def flash_scan_attention(q: Array, k: Array, v: Array, *, causal: bool,
     k = k[:, :n_chunks * chunk]
     v = v[:, :n_chunks * chunk]
     Sk = k.shape[1]
+    dv = v.shape[-1]
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
     qf = q.astype(jnp.float32) * scale
     kc = k.reshape(B, n_chunks, chunk, H, hd)
-    vc = v.reshape(B, n_chunks, chunk, H, hd)
+    vc = v.reshape(B, n_chunks, chunk, H, dv)
     qpos = jnp.arange(Sq)
 
     def body(carry, inp):
@@ -170,7 +171,7 @@ def flash_scan_attention(q: Array, k: Array, v: Array, *, causal: bool,
 
     init = (jnp.full((B, H, Sq), NEG_INF, jnp.float32),
             jnp.zeros((B, H, Sq), jnp.float32),
-            jnp.zeros((B, H, Sq, hd), jnp.float32))
+            jnp.zeros((B, H, Sq, dv), jnp.float32))
     (m, l, acc), _ = jax.lax.scan(
         body, init,
         (jnp.moveaxis(kc, 1, 0), jnp.moveaxis(vc, 1, 0),
@@ -182,8 +183,8 @@ def flash_scan_attention(q: Array, k: Array, v: Array, *, causal: bool,
 def _core_attention(q: Array, k: Array, v: Array, *, causal: bool,
                     policy: QuantPolicy, impl: str = "flash_scan",
                     block_q: int = 0, block_k: int = 0) -> Array:
-    """Backend-dispatched attention core. q (B, Sq, H, hd); k, v
-    (B, Sk, KV, hd) with KV heads *folded* — the Pallas kernels consume
+    """Backend-dispatched attention core. q (B, Sq, H, dqk); k (B, Sk, KV,
+    dqk) and v (B, Sk, KV, dv) with KV heads *folded* — the Pallas kernels consume
     GQA natively (BlockSpec maps query head h to KV head h // group); the
     XLA paths expand heads with ``jnp.repeat`` as before. ``impl="dense"``
     forces the oracle regardless of backend. Every op here, the layout
@@ -221,6 +222,44 @@ def attention_block(x: Array, p: dict, cfg, policy: QuantPolicy, *,
     o = o.reshape(x.shape[0], x.shape[1], cfg.n_heads * cfg.hd)
     wo = PRM.use_weight(p["wo"], ("heads", "embed"), policy.compute_dtype)
     return quant_linear(o, wo, policy=policy)
+
+
+def mla_block(x: Array, p: dict, cfg, policy: QuantPolicy, *,
+              positions: Array, impl: str = "flash_scan", block_q: int = 0,
+              block_k: int = 0) -> Array:
+    """Multi-head latent attention for training (DeepSeek-V2/V3 without a
+    query LoRA): q straight from x; x down to a ``kv_lora_rank`` latent,
+    RMS-normed and up-projected to per-head ``qk_nope_dim`` keys and
+    ``v_dim`` values, plus one ``qk_rope_dim`` key part that every head
+    shares. RoPE (split halves) goes on the query's and that shared key's
+    rope parts; queries and keys are ``qk_nope_dim + qk_rope_dim`` wide and
+    values ``v_dim`` wide, so the core runs at ``dv != dqk`` with the
+    softmax scale 1/sqrt(dqk). Every projection is a ``quant_linear``."""
+    m = cfg.mla
+    B, S, _ = x.shape
+    H, dn, dr, dv, r = (cfg.n_heads, m.qk_nope_dim, m.qk_rope_dim, m.v_dim,
+                        m.kv_lora_rank)
+    cd = policy.compute_dtype
+    with jax.named_scope("mla"):
+        q = quant_linear(x, PRM.use_weight(p["wq"], ("embed", "heads"), cd),
+                         policy=policy).reshape(B, S, H, dn + dr)
+        kv_a = quant_linear(x, PRM.use_weight(p["wkv_a"], ("embed", None),
+                                              cd), policy=policy)
+        c = rms_norm(kv_a[..., :r], p["kv_norm"]["scale"], cfg.norm_eps)
+        k_rope = apply_rope(kv_a[..., None, r:], positions, cfg.rope_theta)
+        kv = quant_linear(c, PRM.use_weight(p["wkv_b"], (None, "heads"), cd),
+                          policy=policy).reshape(B, S, H, dn + dv)
+        q = jnp.concatenate(
+            [q[..., :dn], apply_rope(q[..., dn:], positions, cfg.rope_theta)],
+            axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_rope, (B, S, H, dr))], axis=-1)
+        q = PRM.constrain(q, ("batch", None, "heads", None))
+        k = PRM.constrain(k, ("batch", None, "heads", None))
+        o = _core_attention(q, k, kv[..., dn:], causal=True, policy=policy,
+                            impl=impl, block_q=block_q, block_k=block_k)
+        wo = PRM.use_weight(p["wo"], ("heads", "embed"), cd)
+        return quant_linear(o.reshape(B, S, H * dv), wo, policy=policy)
 
 
 # ---------------------------------------------------------------------------

@@ -45,12 +45,13 @@ def period(cfg: ModelConfig) -> int:
         p = math.lcm(p, cfg.attn_layer_period)
     if cfg.moe is not None and cfg.moe.every_n_layers > 1:
         p = math.lcm(p, cfg.moe.every_n_layers)
-    assert cfg.n_layers % p == 0, (cfg.n_layers, p)
+    assert (cfg.n_layers - cfg.first_k_dense) % p == 0, (cfg.n_layers, p)
     return p
 
 
 def n_groups(cfg: ModelConfig) -> int:
-    return cfg.n_layers // period(cfg)
+    """Scanned period groups, after the ``first_k_dense`` leading layers."""
+    return (cfg.n_layers - cfg.first_k_dense) // period(cfg)
 
 
 # ---------------------------------------------------------------------------
@@ -66,6 +67,21 @@ def _norm_spec(cfg) -> Dict[str, ParamSpec]:
 
 def _attn_specs(cfg) -> Dict[str, ParamSpec]:
     D, H, KV, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    if cfg.mla is not None:
+        m = cfg.mla
+        return {
+            "wq": ParamSpec((D, H * m.qk_dim), ("embed", "heads"), "fan_in",
+                            1.0),
+            "wkv_a": ParamSpec((D, m.kv_lora_rank + m.qk_rope_dim),
+                               ("embed", None), "fan_in", 1.0),
+            "kv_norm": {"scale": ParamSpec((m.kv_lora_rank,), (None,),
+                                           "ones")},
+            "wkv_b": ParamSpec((m.kv_lora_rank,
+                                H * (m.qk_nope_dim + m.v_dim)),
+                               (None, "heads"), "fan_in", 1.0),
+            "wo": ParamSpec((H * m.v_dim, D), ("heads", "embed"), "fan_in",
+                            1.0),
+        }
     return {
         "wq": ParamSpec((D, H * hd), ("embed", "heads"), "fan_in", 1.0),
         "wk": ParamSpec((D, KV * hd), ("embed", "kv_heads"), "fan_in", 1.0),
@@ -86,16 +102,24 @@ def _mlp_specs(cfg, d_ff=None) -> Dict[str, ParamSpec]:
 
 
 def _moe_specs(cfg) -> Dict[str, ParamSpec]:
+    """Router over all ``moe.n_experts``; weights of the held experts only;
+    the selection bias (sigmoid scoring; zero, not trained: no gradient
+    reaches it) and the shared experts when the config has them."""
     moe = cfg.moe
-    D, FF, E = cfg.d_model, cfg.d_ff, moe.n_experts
+    D, FF, E = cfg.d_model, moe.expert_ff or cfg.d_ff, moe.n_experts
+    Eh = cfg.held[1]
     s = {
         "w_router": ParamSpec((D, E), ("embed", None), "fan_in", 1.0),
-        "w_up": ParamSpec((E, D, FF), ("experts", "embed", "mlp"), "fan_in", 1.0),
-        "w_down": ParamSpec((E, FF, D), ("experts", "mlp", "embed"), "fan_in", 1.0),
+        "w_up": ParamSpec((Eh, D, FF), ("experts", "embed", "mlp"), "fan_in", 1.0),
+        "w_down": ParamSpec((Eh, FF, D), ("experts", "mlp", "embed"), "fan_in", 1.0),
     }
     if cfg.act == "swiglu":
-        s["w_gate"] = ParamSpec((E, D, FF), ("experts", "embed", "mlp"),
+        s["w_gate"] = ParamSpec((Eh, D, FF), ("experts", "embed", "mlp"),
                                 "fan_in", 1.0)
+    if moe.scoring == "sigmoid":
+        s["bias"] = ParamSpec((E,), (None,), "zeros")
+    if moe.n_shared:
+        s["shared"] = _mlp_specs(cfg, moe.n_shared * FF)
     return s
 
 
@@ -150,8 +174,11 @@ def _rwkv_specs(cfg) -> Dict[str, ParamSpec]:
     }
 
 
-def layer_specs(cfg: ModelConfig, layer_idx: int) -> Dict[str, Any]:
-    kind = cfg.layer_kind(layer_idx)
+def layer_specs(cfg: ModelConfig, layer_idx: int,
+                dense: bool = False) -> Dict[str, Any]:
+    """Layer ``layer_idx`` of a period group; ``dense``: a leading dense
+    layer (attention and a ``d_ff`` MLP)."""
+    kind = "attn" if dense else cfg.layer_kind(layer_idx)
     specs: Dict[str, Any] = {"norm1": _norm_spec(cfg), "norm2": _norm_spec(cfg)}
     if kind == "attn":
         specs["attn"] = _attn_specs(cfg)
@@ -160,7 +187,7 @@ def layer_specs(cfg: ModelConfig, layer_idx: int) -> Dict[str, Any]:
     elif kind == "rwkv":
         specs["rwkv"] = _rwkv_specs(cfg)
     if kind != "rwkv":   # rwkv channel-mix params live in the rwkv dict
-        if cfg.layer_is_moe(layer_idx):
+        if not dense and cfg.layer_is_moe(layer_idx):
             specs["moe"] = _moe_specs(cfg)
             if cfg.moe.dense_residual:
                 specs["dense_mlp"] = _mlp_specs(cfg, cfg.moe.dense_residual_ff)
@@ -193,6 +220,9 @@ def param_specs(cfg: ModelConfig) -> Dict[str, Any]:
         "blocks": blocks,
         "final_norm": _norm_spec(cfg),
     }
+    if cfg.first_k_dense:
+        specs["dense_blocks"] = {"pos0": _stack_specs(
+            layer_specs(cfg, 0, dense=True), cfg.first_k_dense)}
     if not cfg.tie_embeddings:
         specs["head"] = ParamSpec((cfg.d_model, cfg.vocab_size),
                                   ("embed", "vocab"), "fan_in", 1.0)
@@ -210,8 +240,10 @@ def param_specs(cfg: ModelConfig) -> Dict[str, Any]:
 def _layer_apply(x: Array, lp: Dict, cfg: ModelConfig, policy: QuantPolicy,
                  parallel: ParallelConfig, layer_idx: int, *,
                  positions: Array, state=None, prefill=None,
-                 rope_cache=None, paged=None):
-    """One transformer layer. Returns (x, new_state, aux_loss).
+                 rope_cache=None, paged=None, dense: bool = False):
+    """One transformer layer. Returns (x, new_state, aux_loss, stats):
+    ``stats`` the MoE layer's routing counters ({} without one).
+    ``dense``: a leading dense layer (``first_k_dense``).
 
     ``prefill=(admit, prompt_lens)`` is the serving admission mode: the
     attention sub-block runs ``attention_prefill`` (the exact training
@@ -227,8 +259,9 @@ def _layer_apply(x: Array, lp: Dict, cfg: ModelConfig, policy: QuantPolicy,
     chunked ``attention_paged_prefill`` (suffix only, adopted prefix read
     through the table) and decode appends through the table/trash-block
     discipline. ``pref_lens`` is only read in prefill mode."""
-    kind = cfg.layer_kind(layer_idx)
+    kind = "attn" if dense else cfg.layer_kind(layer_idx)
     aux = jnp.zeros((), jnp.float32)
+    stats = {}
     g1 = lp.get("gamma1")
     g2 = lp.get("gamma2")
     bq, bk = parallel.attn_block_q, parallel.attn_block_k
@@ -251,6 +284,10 @@ def _layer_apply(x: Array, lp: Dict, cfg: ModelConfig, policy: QuantPolicy,
                     block_q=bq, block_k=bk)
             new_state = new_state._replace(
                 length=jnp.where(admit, prompt_lens, new_state.length))
+        elif state is None and cfg.mla is not None:
+            a = ATT.mla_block(h, lp["attn"], cfg, policy, positions=positions,
+                              impl=parallel.attn_impl, block_q=bq,
+                              block_k=bk)
         elif state is None:
             a = ATT.attention_block(h, lp["attn"], cfg, policy,
                                     positions=positions,
@@ -280,15 +317,15 @@ def _layer_apply(x: Array, lp: Dict, cfg: ModelConfig, policy: QuantPolicy,
                                       x_prev=cm_prev)
         if state is not None:
             new_state = new_state._replace(cm_x_prev=cm_last)
-    elif cfg.layer_is_moe(layer_idx):
-        m, aux = moe_block(h2, lp["moe"], cfg, policy)
+    elif not dense and cfg.layer_is_moe(layer_idx):
+        m, aux, stats = moe_block(h2, lp["moe"], cfg, policy)
         if cfg.moe.dense_residual:
             m = m + mlp_block(h2, lp["dense_mlp"], cfg, policy)
     else:
         m = mlp_block(h2, lp["mlp"], cfg, policy)
     x = x + apply_layer_scale(g2, m)
     x = PRM.constrain(x, ("batch", "seq", "embed"))
-    return x, new_state, aux
+    return x, new_state, aux, stats
 
 
 def group_apply(x: Array, gp: Dict[str, Dict], cfg: ModelConfig,
@@ -296,19 +333,23 @@ def group_apply(x: Array, gp: Dict[str, Dict], cfg: ModelConfig,
                 positions: Array, states: Optional[Dict] = None,
                 rope_cache=None, paged=None):
     """Apply one period-group (P heterogeneous layers unrolled).
-    gp: {"pos{i}": layer params (unstacked)}. Returns (x, new_states, aux)."""
+    gp: {"pos{i}": layer params (unstacked)}. Returns (x, new_states, aux,
+    stats): ``stats`` the group's MoE routing counters by layer."""
     P = period(cfg)
     aux_total = jnp.zeros((), jnp.float32)
-    new_states = {}
+    new_states, stats = {}, {}
     for i in range(P):
         st = states.get(f"pos{i}") if states is not None else None
-        x, ns, aux = _layer_apply(x, gp[f"pos{i}"], cfg, policy, parallel, i,
-                                  positions=positions, state=st,
-                                  rope_cache=rope_cache, paged=paged)
+        x, ns, aux, st_i = _layer_apply(x, gp[f"pos{i}"], cfg, policy,
+                                        parallel, i, positions=positions,
+                                        state=st, rope_cache=rope_cache,
+                                        paged=paged)
         aux_total = aux_total + aux
+        if st_i:
+            stats[f"pos{i}"] = st_i
         if states is not None:
             new_states[f"pos{i}"] = ns
-    return x, (new_states if states is not None else None), aux_total
+    return x, (new_states if states is not None else None), aux_total, stats
 
 
 def _maybe_remat(fn, parallel: ParallelConfig):
@@ -357,50 +398,77 @@ def lm_head(params, x: Array, cfg: ModelConfig, policy: QuantPolicy):
         return PRM.constrain(logits, ("batch", None, "vocab"))
 
 
+def _route_stats(per_layer) -> Dict[str, Array]:
+    """The step's routing counters from the MoE layers' (stacked by group,
+    keyed by position in the group): held slots as the mean per layer,
+    load ratios as the worst layer's, balance loss summed."""
+    flat: Dict[str, list] = {}
+    for st in per_layer.values():
+        for k, v in st.items():
+            flat.setdefault(k, []).append(jnp.ravel(v))
+    out = {}
+    for k, vs in flat.items():
+        v = jnp.concatenate(vs)
+        out[k] = (jnp.mean(v) if k == "moe/held_slots" else
+                  jnp.sum(v) if k == "moe/balance_loss" else jnp.max(v))
+    return out
+
+
 def forward(params, tokens: Array, cfg: ModelConfig, policy: QuantPolicy,
-            parallel: ParallelConfig, extra_embeds: Optional[Array] = None):
-    """Training/prefill forward. Returns (logits, aux_loss)."""
+            parallel: ParallelConfig, extra_embeds: Optional[Array] = None,
+            with_stats: bool = False):
+    """Training/prefill forward: the ``first_k_dense`` leading layers, then
+    the scanned period groups. Returns (logits, aux_loss), and with
+    ``with_stats`` the MoE routing counters third ({} without MoE)."""
     x = embed_input(params, tokens, cfg, policy, extra_embeds)
     positions = jnp.arange(x.shape[1])
     body = functools.partial(group_apply, cfg=cfg, policy=policy,
                              parallel=parallel, positions=positions)
 
     def group_fwd(xx, pp):
-        out, _, a = body(xx, pp)
-        return out, a
+        out, _, a, st = body(xx, pp)
+        return out, (a, st)
+
+    def dense_fwd(xx, lp):
+        out, _, _, _ = _layer_apply(xx, lp, cfg, policy, parallel, 0,
+                                    positions=positions, dense=True)
+        return out
+
+    dense_blk = _maybe_remat(dense_fwd, parallel)
+    for i in range(cfg.first_k_dense):
+        x = dense_blk(x, jax.tree.map(lambda p: p[i],
+                                      params["dense_blocks"]["pos0"]))
 
     blk = _maybe_remat(group_fwd, parallel)
-
-    def scan_body(carry, gp):
-        x, aux = carry
-        x2, a = blk(x, gp)
-        return (x2, aux + a), None
-
-    aux0 = jnp.zeros((), jnp.float32)
     if parallel.scan_layers and n_groups(cfg) > 1:
-        (x, aux), _ = jax.lax.scan(scan_body, (x, aux0), params["blocks"])
+        x, (auxs, stats) = jax.lax.scan(blk, x, params["blocks"])
     else:
-        aux = aux0
-        G = n_groups(cfg)
-        for g in range(G):
-            gp = jax.tree.map(lambda p: p[g], params["blocks"])
-            x, a = blk(x, gp)
-            aux = aux + a
+        outs = []
+        for g in range(n_groups(cfg)):
+            x, o = blk(x, jax.tree.map(lambda p: p[g], params["blocks"]))
+            outs.append(o)
+        auxs, stats = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
     logits = lm_head(params, x, cfg, policy)
-    return logits, aux
+    if with_stats:
+        return logits, jnp.sum(auxs), _route_stats(stats)
+    return logits, jnp.sum(auxs)
 
 
 def loss_fn(params, batch: Dict[str, Array], cfg: ModelConfig,
-            policy: QuantPolicy, parallel: ParallelConfig,
-            aux_weight: float = 0.01):
-    logits, aux = forward(params, batch["tokens"], cfg, policy, parallel,
-                          extra_embeds=batch.get("extra_embeds"))
+            policy: QuantPolicy, parallel: ParallelConfig):
+    """Mean next-token cross-entropy plus the MoE layers' balance loss
+    (each already times its ``balance_alpha``); the metrics carry both and
+    the routing counters."""
+    logits, aux, stats = forward(params, batch["tokens"], cfg, policy,
+                                 parallel,
+                                 extra_embeds=batch.get("extra_embeds"),
+                                 with_stats=True)
     # frontend tokens (prepended) carry no next-token target
     n_front = logits.shape[1] - batch["labels"].shape[1]
     if n_front:
         logits = logits[:, n_front:]
     ce = cross_entropy_loss(logits, batch["labels"], cfg.logit_softcap)
-    return ce + aux_weight * aux, {"ce": ce, "moe_aux": aux}
+    return ce + aux, {"ce": ce, "moe_aux": aux, **stats}
 
 
 # ---------------------------------------------------------------------------
@@ -410,6 +478,7 @@ def loss_fn(params, batch: Dict[str, Array], cfg: ModelConfig,
 def init_decode_state(cfg: ModelConfig, batch: int, max_len: int,
                       dtype=jnp.bfloat16):
     """Stacked-over-groups recurrent state for every position-in-period."""
+    _require_cache_layout(cfg, "init_decode_state")
     P = period(cfg)
     G = n_groups(cfg)
 
@@ -470,7 +539,7 @@ def decode_step(params, states, tokens: Array, cfg: ModelConfig,
 
     def scan_body(x, inp):
         gp, st = inp
-        x2, ns, _ = body(x, gp, states=st)
+        x2, ns, _, _ = body(x, gp, states=st)
         return x2, ns
 
     if parallel.scan_layers and n_groups(cfg) > 1:
@@ -493,7 +562,18 @@ def decode_step(params, states, tokens: Array, cfg: ModelConfig,
 # serving (continuous batching: per-slot KV caches)
 # ---------------------------------------------------------------------------
 
+def _require_cache_layout(cfg: ModelConfig, what: str):
+    """Decode and serving caches hold per-head keys and values of one
+    width: latent attention (a latent cache) and leading dense layers are
+    not served yet."""
+    if cfg.mla is not None or cfg.first_k_dense:
+        raise NotImplementedError(
+            f"{what}: {cfg.name} has latent attention or leading dense "
+            "layers, which train but do not decode yet")
+
+
 def _require_all_attention(cfg: ModelConfig, what: str):
+    _require_cache_layout(cfg, what)
     P = period(cfg)
     kinds = {cfg.layer_kind(i) for i in range(P)}
     if kinds != {"attn"}:
@@ -617,7 +697,7 @@ def paged_prefill(params, states, tables: Array, tokens: Array,
         gp, st = inp
         new_st = {}
         for i in range(period(cfg)):
-            xx, new_st[f"pos{i}"], _ = _layer_apply(
+            xx, new_st[f"pos{i}"], _, _ = _layer_apply(
                 xx, gp[f"pos{i}"], cfg, policy, parallel, i,
                 positions=positions, state=st[f"pos{i}"],
                 prefill=(admit, prompt_lens), rope_cache=rope_cache,
@@ -658,7 +738,7 @@ def paged_decode_step(params, states, tables: Array, tokens: Array,
 
     def scan_body(x, inp):
         gp, st = inp
-        x2, ns, _ = body(x, gp, states=st)
+        x2, ns, _, _ = body(x, gp, states=st)
         return x2, ns
 
     if parallel.scan_layers and n_groups(cfg) > 1:
@@ -705,7 +785,7 @@ def serve_prefill(params, states, tokens: Array, prompt_lens: Array,
         gp, st = inp
         new_st = {}
         for i in range(period(cfg)):
-            xx, new_st[f"pos{i}"], _ = _layer_apply(
+            xx, new_st[f"pos{i}"], _, _ = _layer_apply(
                 xx, gp[f"pos{i}"], cfg, policy, parallel, i,
                 positions=positions, state=st[f"pos{i}"],
                 prefill=(admit, prompt_lens), rope_cache=rope_cache)

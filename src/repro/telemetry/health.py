@@ -113,6 +113,13 @@ def qh_items(metrics: Dict) -> Dict[str, float]:
     return {k: float(v) for k, v in metrics.items() if k.startswith("qh/")}
 
 
+def moe_items(metrics: Dict) -> Dict[str, float]:
+    """The MoE routing counters of one fetched metrics dict (``moe/``:
+    slots routed to the held experts, max/mean load over the held experts
+    and over all of them, the balance loss), as floats."""
+    return {k: float(v) for k, v in metrics.items() if k.startswith("moe/")}
+
+
 def summarize_rms(rms_tree) -> Dict[str, float]:
     """Per-group mean of the fetched StableAdamW RMS_t tree — the paper's
     App.-D ``sqrt(E[g²]/v_t)`` spike-precursor signal, grouped like the

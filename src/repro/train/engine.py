@@ -46,8 +46,7 @@ def make_shard_ctx(mesh, parallel: ParallelConfig) -> PRM.ShardCtx:
     return PRM.ShardCtx(mesh, rules, nofsdp,
                         gather_fsdp=parallel.fsdp and
                         parallel.fsdp_gather_weights,
-                        gather_wire=parallel.gather_wire,
-                        moe_grouped=parallel.moe_grouped)
+                        gather_wire=parallel.gather_wire)
 
 
 def batch_shardings(inputs, mesh: Mesh, rules):

@@ -29,7 +29,7 @@ from repro.checkpoint import CheckpointManager
 from repro.distributed.straggler import StragglerWatchdog
 from repro.stability import LossSpikeDetector, RMSMonitor
 from repro.telemetry import as_telemetry
-from repro.telemetry.health import qh_items, summarize_rms
+from repro.telemetry.health import moe_items, qh_items, summarize_rms
 from repro.train.train_step import TrainState
 
 
@@ -152,7 +152,8 @@ class Trainer:
                    "grad_norm": float(metrics["grad_norm"]),
                    "lr": float(metrics["lr"]),
                    "n_skipped": int(metrics["n_skipped_tensors"]),
-                   "dt": timing["dt"], "slow": timing["slow"]}
+                   "dt": timing["dt"], "slow": timing["slow"],
+                   **moe_items(metrics)}
             if tele.enabled:
                 ev = dict(rec, **qh_items(metrics))
                 if "rms" in metrics:

@@ -1,9 +1,5 @@
 """Units for the distributed substrate: HLO collective parsing, the
-roofline model, sharding rules, grouped-MoE equivalence."""
-import dataclasses
-
-import jax
-import jax.numpy as jnp
+roofline model, sharding rules."""
 import numpy as np
 import pytest
 
@@ -124,49 +120,3 @@ class TestShardingRules:
         ps = logical_to_pspec(("batch", "seq", "embed"), rules)
         # embed must NOT re-use 'data' (already claimed by batch)
         assert tuple(ps) == (("data", "model"), None, None)
-
-
-class TestGroupedMoE:
-    def test_grouped_equals_flat_when_capacity_ample(self):
-        """With capacity factor high enough that nothing is dropped, the
-        grouped dispatch (G groups) must equal the G=1 result exactly —
-        grouping only changes locality, not semantics."""
-        import repro.models.moe as MOE
-        from repro.configs import get_reduced_config
-        from repro.core.precision import QuantPolicy
-        from repro.models import build
-        from repro.models.params import init_params
-
-        cfg0 = get_reduced_config("qwen3-moe-30b-a3b")
-        cfg = dataclasses.replace(
-            cfg0, moe=dataclasses.replace(cfg0.moe, capacity_factor=8.0))
-        params = init_params(build(cfg).param_specs, jax.random.PRNGKey(0))
-        lp = jax.tree.map(lambda p: p[0], params["blocks"]["pos0"])
-        x = jax.random.normal(jax.random.PRNGKey(1), (4, 32, cfg.d_model),
-                              jnp.float32)
-        pol = QuantPolicy("bf16", compute_dtype=jnp.float32)
-
-        orig = MOE._data_group_count
-        try:
-            MOE._data_group_count = lambda T: 1
-            y1, aux1 = MOE.moe_block(x, lp["moe"], cfg, pol)
-            MOE._data_group_count = lambda T: 4
-            y4, aux4 = MOE.moe_block(x, lp["moe"], cfg, pol)
-        finally:
-            MOE._data_group_count = orig
-        np.testing.assert_allclose(np.asarray(y1), np.asarray(y4),
-                                   rtol=2e-4, atol=2e-4)
-        np.testing.assert_allclose(float(aux1), float(aux4), rtol=1e-5)
-
-    def test_capacity_drops_respect_group_budget(self):
-        """Adversarial routing: all tokens to one expert — kept tokens per
-        group must equal exactly C (the rest dropped)."""
-        from repro.models.moe import _group_dispatch
-        Tg, d, E, C, k = 64, 8, 4, 8, 1
-        xg = jnp.ones((Tg, d))
-        gates = jnp.ones((Tg, k))
-        experts = jnp.zeros((Tg, k), jnp.int32)       # everyone -> expert 0
-        x_disp, slot_token, slot_w = _group_dispatch(xg, gates, experts,
-                                                     E=E, C=C)
-        assert int(jnp.sum(slot_w > 0)) == C
-        assert x_disp.shape == (E, C, d)

@@ -83,7 +83,7 @@ def test_smoke_full_config_loads_and_counts(arch):
         "internvl2-76b": 70e9, "smollm-360m": 0.36e9, "starcoder2-3b": 3e9,
         "granite-20b": 20e9, "minitron-8b": 8e9,
         "seamless-m4t-large-v2": 2.3e9, "jamba-v0.1-52b": 52e9,
-        "clip-vit-huge": 1.0e9,
+        "clip-vit-huge": 1.0e9, "moonlight-16b-a3b": 16e9,
     }[arch]
     assert 0.4 * expected < n < 2.1 * expected, \
         f"{arch}: {n/1e9:.2f}B params vs expected ~{expected/1e9:.1f}B"
@@ -147,20 +147,6 @@ def test_remat_matches_no_remat():
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-5)
-
-
-def test_moe_capacity_drops_are_bounded(reduced):
-    """With capacity_factor 1.25 and balanced-ish routing, most tokens
-    survive dispatch: the combined output is not mostly zeros."""
-    from repro.models.moe import moe_block
-    cfg, bundle, params = reduced("qwen3-moe-30b-a3b")
-    lp = jax.tree.map(lambda p: p[0], params["blocks"]["pos0"])
-    x = jax.random.normal(KEY, (2, 64, cfg.d_model), jnp.bfloat16)
-    out, aux = moe_block(x, lp["moe"], cfg, QuantPolicy("bf16"))
-    assert out.shape == x.shape
-    nonzero_frac = float(jnp.mean(jnp.any(jnp.abs(out) > 0, axis=-1)))
-    assert nonzero_frac > 0.8
-    assert float(aux) > 0.5        # balance loss near 1 for uniform router
 
 
 def test_layer_scale_zero_init_is_identity():

@@ -118,6 +118,56 @@ def test_flash_fwd_bwd_compiles_for_v5e(shape, one_chip):
     assert hlo.count("tpu_custom_call") >= 3      # fwd, dq, dkv
 
 
+def test_mla_flash_fwd_bwd_compiles_for_v5e(one_chip):
+    """Moonlight-16B-A3B's latent attention: 16 heads, queries and keys 192
+    wide, values 128 wide, 2 x 8192 tokens; the tiles ops.py records."""
+    B, S, H, dqk, dv = 2, 8192, 16, 192, 128
+    assert FA.choose_attn_blocks(S, S, group=1, hd=dqk) == (512, 1024)
+
+    def fwd_bwd(q, k, v):
+        loss = lambda q, k, v: FA.flash_attention(
+            q, k, v, causal=True, backend="pallas").astype(F32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    hlo = compile_hlo(fwd_bwd, one_chip, ((B, S, H, dqk), BF),
+                      ((B, S, H, dqk), BF), ((B, S, H, dv), BF))
+    assert hlo.count("tpu_custom_call") >= 3
+
+
+# Moonlight's routed experts on one chip's share: 2 x 8192 tokens, top-6
+# of 64 with 8 held, so the slot buffer holds T * 6 rows plus a tile of
+# padding per held expert; expert width 1408 on hidden 2048
+MOE_ROWS = 16384 * 6 + 8 * SB.MOE_TILE_ROWS
+MOE_TILES = MOE_ROWS // SB.MOE_TILE_ROWS
+MOE = {
+    "moe_gmm_fwd": lambda n, m: (
+        lambda x, w, s, t, c: SB.moe_gmm_fwd(
+            x, w, s, t, c, block_m=SB.MOE_TILE_ROWS, backend="pallas"),
+        [((MOE_ROWS, n), BF), ((8, n, m), I8), ((8, 1, 1), F32),
+         ((MOE_TILES,), I32), ((1,), I32)]),
+    "moe_gmm_dgrad": lambda n, m: (
+        lambda g, w, s, t, c: SB.moe_gmm_dgrad(
+            g, w, s, t, c, block_m=SB.MOE_TILE_ROWS, backend="pallas"),
+        [((MOE_ROWS, m), BF), ((8, n, m), I8), ((8, 1, 1), F32),
+         ((MOE_TILES,), I32), ((1,), I32)]),
+    "moe_gmm_wgrad": lambda n, m: (
+        lambda x, g, t, c: SB.moe_gmm_wgrad(
+            x, g, t, c, block_m=SB.MOE_TILE_ROWS, n_experts=8,
+            backend="pallas"),
+        [((MOE_ROWS, n), BF), ((MOE_ROWS, m), BF), ((MOE_TILES,), I32),
+         ((1,), I32)]),
+}
+
+
+@pytest.mark.parametrize("nm", [(2048, 1408), (1408, 2048)],
+                         ids=["up", "down"])
+@pytest.mark.parametrize("name", sorted(MOE))
+def test_moe_grouped_kernel_compiles_for_v5e(name, nm, one_chip):
+    fn, shapes = MOE[name](*nm)
+    hlo = compile_hlo(fn, one_chip, *shapes)
+    assert [n for n, _ in custom_calls(hlo)] == [name]
+
+
 @pytest.mark.parametrize("shape", ["smollm", "clip_text"])
 def test_flash_decode_compiles_for_v5e(shape, one_chip):
     H, KV, hd, _ = ATTN_SHAPES[shape]
@@ -162,7 +212,8 @@ KERNEL_NAMES = {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_decode",
                 "tensor_quantize", "row_quantize", "col_quantize",
                 "int8_matmul_dequant", "fused_switchback_fwd",
                 "fused_switchback_dgrad", "wgrad_bf16", "paged_prefill",
-                "paged_decode"}
+                "paged_decode", "moe_gmm_fwd", "moe_gmm_dgrad",
+                "moe_gmm_wgrad"}
 
 
 @pytest.fixture(scope="module")
@@ -227,4 +278,50 @@ def test_train_step_ops_carry_layer_scopes(smollm_step_hlo):
     attn = [o for o in ops if "attention" in o]
     assert any("pallas_call" not in o for o in attn)   # pads, transposes
     for scope in ("norm", "embed", "head", "optimizer"):
+        assert any(scope in o for o in ops), scope
+
+
+@pytest.fixture(scope="module")
+def moonlight_step_hlo(topo):
+    """The compiled int8 SwitchBack + Pallas train step of Moonlight-16B-A3B
+    at published widths cut to its dense layer and one MoE layer with the
+    8 of 64 experts the benchmark's chip holds, at 1 x 512 tokens."""
+    import dataclasses
+    from jax.sharding import AxisType, Mesh
+    import numpy as np
+    from repro.configs import get_config
+    from repro.configs.base import ParallelConfig, TrainConfig
+    from repro.train import make_engine
+    cfg = dataclasses.replace(get_config("moonlight-16b-a3b"), n_layers=2,
+                              vocab_size=4096, held_experts=(0, 8))
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    tc = TrainConfig(optimizer="stable_adamw", quant_mode="int8_switchback",
+                     kernel_backend="pallas")
+    par = ParallelConfig(mesh_shape=(1, 1), mesh_axes=("data", "model"),
+                         remat="block")
+    tokens = jax.ShapeDtypeStruct((1, 512), I32)
+    engine = make_engine(cfg, tc, par, mesh,
+                         {"tokens": tokens, "labels": tokens})
+    return engine.lower().compile().as_text()
+
+
+def test_moonlight_step_runs_its_kernels_under_its_scopes(moonlight_step_hlo):
+    """The routed experts run the ``moe_gmm_*`` kernels under
+    ``moe.experts``; latent attention runs the flash kernels under ``mla``;
+    the router, dispatch, shared experts and combine carry their scopes."""
+    import re
+    parts = lambda op: set(re.split(r"[/()]", op))
+    calls = custom_calls(moonlight_step_hlo)
+    names = {n for n, _ in calls}
+    assert {"moe_gmm_fwd", "moe_gmm_dgrad", "moe_gmm_wgrad", "flash_fwd",
+            "flash_bwd_dq", "flash_bwd_dkv"} <= names <= KERNEL_NAMES
+    for name, op in calls:
+        if name.startswith("moe_"):
+            assert "moe.experts" in parts(op), (name, op)
+        if name.startswith("flash_"):
+            assert {"mla", "attention"} <= parts(op), (name, op)
+    ops = [parts(o) for o in re.findall(r'op_name="([^"]*)"',
+                                        moonlight_step_hlo)]
+    for scope in ("moe.router", "moe.dispatch", "moe.shared", "moe.combine"):
         assert any(scope in o for o in ops), scope
