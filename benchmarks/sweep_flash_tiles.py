@@ -1,10 +1,11 @@
 """Chip sweep of the flash-attention train kernels' tile sizes.
 
-Times ``flash_fwd``, ``flash_bwd_dq`` and ``flash_bwd_dkv`` per call on a
-TPU at the benchmark's attention shapes (smollm-360m: 4 x 4096 tokens, 15
-heads over 5 KV heads of 64, causal; CLIP ViT-Huge: 128 x 129 vision
-tokens, 16 heads of 80, non-causal, and 128 x 77 text tokens, 16 heads of
-64, causal), for every (Q cap, K cap) pair of ``--caps`` through
+Times ``flash_fwd``, the one-pass backward ``flash_bwd`` and the split
+backward pair ``flash_bwd_dq`` + ``flash_bwd_dkv`` (its fallback where the
+resident dq does not fit) per call on a TPU at the benchmark's attention
+shapes (smollm-360m: 4 x 4096 tokens, 15 heads over 5 KV heads of 64,
+causal; CLIP ViT-Huge: 128 x 129 vision tokens, 16 heads of 80,
+non-causal, and 128 x 77 text tokens, 16 heads of 64, causal), for every (Q cap, K cap) pair of ``--caps`` through
 ``ops.seq_tile`` and every explicit ``--tiles`` pair. Operands are padded
 to the tiles as ``ops.py`` pads them. A pair the compiler refuses (scoped
 VMEM) is recorded with its error. Exits non-zero without a TPU.
@@ -70,6 +71,8 @@ def sweep_shape(name, pairs, iters):
                    (q, kk, vv, do, stat, stat)),
             "dkv": (jax.jit(lambda *a: K.flash_bwd_dkv(*a, **kw)),
                     (q, kk, vv, do, stat, stat)),
+            "bwd": (jax.jit(lambda *a: K.flash_bwd(*a, **kw)),
+                    (q, kk, vv, do, stat, stat)),
         }
         row = {"shape": name, "block_q": bq, "block_k": bk,
                "grid_steps": B * KV * (Sq // bq) * (Sk // bk)}
@@ -79,6 +82,8 @@ def sweep_shape(name, pairs, iters):
             except Exception as e:          # compile refused: record it
                 row[f"{kind}_ms"] = None
                 row[f"{kind}_error"] = str(e).splitlines()[0][:200]
+        if row["dq_ms"] is not None and row["dkv_ms"] is not None:
+            row["dq_plus_dkv_ms"] = row["dq_ms"] + row["dkv_ms"]
         print(json.dumps(row), flush=True)
         rows.append(row)
     return rows

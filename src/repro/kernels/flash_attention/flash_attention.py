@@ -16,11 +16,16 @@ this repo's needs (DESIGN.md §9):
   and K/V tiles are never re-streamed per query head either.
 * **Causal tile skip**: KV tiles entirely above the diagonal are skipped
   with ``pl.when`` — ~2x fewer FLOPs at training shapes.
-* **Backward**: recompute-style. Two kernels (different iteration orders):
-  dq accumulates over KV tiles for a fixed Q tile; dk/dv accumulate over Q
-  tiles for a fixed KV tile and land directly in KV-head layout (the
-  group-sum is free). Attention weights are rebuilt from (q, k, lse) —
-  nothing quadratic is saved between fwd and bwd.
+* **Backward**: recompute-style. Attention weights are rebuilt from
+  (q, k, lse) — nothing quadratic is saved between fwd and bwd. One
+  kernel (``flash_bwd``) walks Q tiles inside each KV tile and builds a
+  tile's weights and ``ds`` once for all three gradients: dk/dv
+  accumulate in scratch and land directly in KV-head layout (the
+  group-sum is free), dq accumulates in an output block that holds the
+  KV head's whole query group over the whole sequence in VMEM. Where that
+  block does not fit, two kernels in opposite orders do the same
+  arithmetic (``flash_bwd_dq``: KV tiles inner per Q tile;
+  ``flash_bwd_dkv``), rebuilding the weights in each.
 * **Decode**: one query per batch slot against the ring KV cache, with
   the per-slot lengths as a scalar-prefetch operand. Tiles beyond a
   slot's length are skipped dynamically on BOTH sides: the kernel body is
@@ -70,6 +75,34 @@ def _causal_tile_live(iq, ik, block_q, block_k):
     return ik * block_k <= iq * block_q + block_q - 1
 
 
+def _tile_mask(iq, ik, *, causal, kv_valid, block_q, block_k):
+    """(bq, bk) mask of tile (iq, ik): keys below ``kv_valid`` (pad keys
+    are out) and, when causal, not after their query."""
+    kpos = ik * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1)
+    mask = kpos < kv_valid
+    if causal:
+        qpos = iq * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        mask = mask & (kpos <= qpos)
+    return mask
+
+
+def _rebuild(q, k, v, do, lse, di, mask, scale):
+    """One query head's tile of the backward, rebuilt from (q, k, lse):
+    the weights p = exp(s - lse) (masked -> 0) and the score gradient
+    ds = p * (do·vᵀ - di), both (bq, bk) f32."""
+    s = scale * jax.lax.dot_general(
+        q, k, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    s = jnp.where(mask, s, MASK_VALUE)
+    p = jnp.exp(s - lse)
+    dp = jax.lax.dot_general(                                # do · vᵀ
+        do, v, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return p, p * (dp - di)
+
+
 # ---------------------------------------------------------------------------
 # forward: online softmax over KV tiles, saving logsumexp
 # ---------------------------------------------------------------------------
@@ -88,13 +121,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
     def _tile():
         k = k_ref[0, 0].astype(jnp.float32)                 # (bk, dqk)
         v = v_ref[0, 0].astype(jnp.float32)                 # (bk, dv)
-        kpos = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = kpos < kv_valid
-        if causal:
-            qpos = iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            mask = mask & (kpos <= qpos)
+        mask = _tile_mask(iq, ik, causal=causal, kv_valid=kv_valid,
+                          block_q=block_q, block_k=block_k)
         for g in range(group):                              # unrolled: the
             # KV tile is fetched ONCE per grid step and reused by every
             # query head in this KV head's GQA group
@@ -198,25 +226,13 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
     def _tile():
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
-        kpos = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = kpos < kv_valid
-        if causal:
-            qpos = iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            mask = mask & (kpos <= qpos)
+        mask = _tile_mask(iq, ik, causal=causal, kv_valid=kv_valid,
+                          block_q=block_q, block_k=block_k)
         for g in range(group):                    # K/V tile shared by the
             q = q_ref[0, g].astype(jnp.float32)   # KV head's query group
-            s = scale * jax.lax.dot_general(
-                q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            s = jnp.where(mask, s, MASK_VALUE)
-            p = jnp.exp(s - lse_ref[0, g])                   # masked -> 0
             do = do_ref[0, g].astype(jnp.float32)
-            dp = jax.lax.dot_general(                        # do · vᵀ
-                do, v, dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            ds = p * (dp - di_ref[0, g])
+            _, ds = _rebuild(q, k, v, do, lse_ref[0, g], di_ref[0, g], mask,
+                             scale)
             acc_ref[g] += jax.lax.dot(ds, k,
                                       preferred_element_type=jnp.float32)
 
@@ -285,28 +301,16 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
     def _tile():
         k = k_ref[0, 0].astype(jnp.float32)                 # (bk, dqk)
         v = v_ref[0, 0].astype(jnp.float32)                 # (bk, dv)
-        kpos = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = kpos < kv_valid
-        if causal:
-            qpos = iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            mask = mask & (kpos <= qpos)
+        mask = _tile_mask(iq, ik, causal=causal, kv_valid=kv_valid,
+                          block_q=block_q, block_k=block_k)
         for g in range(group):                              # unrolled
             q = q_ref[0, g].astype(jnp.float32)             # (bq, dqk)
             do = do_ref[0, g].astype(jnp.float32)
-            s = scale * jax.lax.dot_general(
-                q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            s = jnp.where(mask, s, MASK_VALUE)
-            p = jnp.exp(s - lse_ref[0, g])
+            p, ds = _rebuild(q, k, v, do, lse_ref[0, g], di_ref[0, g], mask,
+                             scale)
             dv_acc[...] += jax.lax.dot_general(              # pᵀ @ do
                 p, do, dimension_numbers=(((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            dp = jax.lax.dot_general(
-                do, v, dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            ds = p * (dp - di_ref[0, g])
             dk_acc[...] += jax.lax.dot_general(              # dsᵀ @ q
                 ds, q, dimension_numbers=(((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -322,6 +326,25 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
         dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
+def _kv_major_specs(group, block_q, block_k, dqk, dv):
+    """Blocks of q, k, v, do, lse and di on the (B, KV, nk, nq) grid of the
+    backward kernels that walk Q tiles inside each KV tile. A q, do or
+    stat block holds `group` query heads: block index h covers KV head
+    h's whole query group."""
+    def q_map(b, h, ik, iq):
+        return b, h, iq, 0
+
+    def kv_map(b, h, ik, iq):
+        return b, h, ik, 0
+
+    stat_spec = pl.BlockSpec((1, group, block_q, 1), q_map)
+    return [pl.BlockSpec((1, group, block_q, dqk), q_map),
+            pl.BlockSpec((1, 1, block_k, dqk), kv_map),
+            pl.BlockSpec((1, 1, block_k, dv), kv_map),
+            pl.BlockSpec((1, group, block_q, dv), q_map),
+            stat_spec, stat_spec]
+
+
 def flash_bwd_dkv(q, k, v, do, lse, di, *, causal: bool, kv_valid: int,
                   scale: float, block_q: int = 128, block_k: int = 128,
                   interpret: bool = False):
@@ -335,28 +358,127 @@ def flash_bwd_dkv(q, k, v, do, lse, di, *, causal: bool, kv_valid: int,
     kernel = functools.partial(
         _bwd_dkv_kernel, scale=scale, causal=causal, kv_valid=kv_valid,
         group=group, n_q=n_q, block_q=block_q, block_k=block_k)
-    # head-block of `group` query heads: block index h covers the KV head
-    # h's whole query group
-    q_spec = pl.BlockSpec((1, group, block_q, dqk),
-                          lambda b, h, ik, iq: (b, h, iq, 0))
-    do_spec = pl.BlockSpec((1, group, block_q, dv),
-                           lambda b, h, ik, iq: (b, h, iq, 0))
-    stat_spec = pl.BlockSpec((1, group, block_q, 1),
-                             lambda b, h, ik, iq: (b, h, iq, 0))
-    k_spec = pl.BlockSpec((1, 1, block_k, dqk),
-                          lambda b, h, ik, iq: (b, h, ik, 0))
-    v_spec = pl.BlockSpec((1, 1, block_k, dv),
-                          lambda b, h, ik, iq: (b, h, ik, 0))
+    in_specs = _kv_major_specs(group, block_q, block_k, dqk, dv)
     return pl.pallas_call(
         kernel,
         grid=(B, KV, n_k, n_q),
-        in_specs=[q_spec, k_spec, v_spec, do_spec, stat_spec, stat_spec],
-        out_specs=[k_spec, v_spec],
+        in_specs=in_specs,
+        out_specs=in_specs[1:3],                            # dk, dv
         out_shape=[jax.ShapeDtypeStruct((B, KV, Sk, dqk), jnp.float32),
                    jax.ShapeDtypeStruct((B, KV, Sk, dv), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((block_k, dqk), jnp.float32),
                         pltpu.VMEM((block_k, dv), jnp.float32)],
         name="flash_bwd_dkv",
+        interpret=interpret,
+    )(q, k, v, do, lse, di)
+
+
+# ---------------------------------------------------------------------------
+# backward, one pass: the dkv kernel's order (Q tiles inner per KV tile),
+# each tile's p and ds built once and feeding all three gradients
+#   dv = pᵀ @ do;  dk = scale · dsᵀ @ q;  dq[Q tile] += ds @ k
+# dq lives in one output block per (batch, KV head) that stays in VMEM
+# across the KV and Q tiles; for a fixed Q tile it sums the KV tiles in
+# the split dq kernel's order, so all three come out bit-identical to it
+# ---------------------------------------------------------------------------
+
+# Scoped VMEM of the one-pass kernel beyond its resident dq: the split
+# kernels' blocks and the (bq, bk) f32 score temporaries fit the default
+# 16 MiB of a v5e; twice that leaves room for holding both kernels' live
+# values at once
+BWD_VMEM_HEADROOM = 32 * 1024 * 1024
+
+
+def resident_dq_bytes(group: int, Sq: int, dqk: int) -> int:
+    """Scoped VMEM of the one-pass kernel's dq block: a KV head's query
+    group over the whole padded query axis in f32, its width padded to the
+    128 lanes, double-buffered by the pipeline."""
+    return 2 * group * Sq * (-(-dqk // 128) * 128) * 4
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
+                dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
+                kv_valid, group, n_q, n_k, block_q, block_k):
+    ik = pl.program_id(2)
+    iq = pl.program_id(3)
+
+    @pl.when((ik == 0) & (iq == 0))
+    def _init_dq():
+        dq_ref[...] = jnp.zeros_like(dq_ref)
+
+    @pl.when(iq == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def _tile():
+        k = k_ref[0, 0].astype(jnp.float32)                 # (bk, dqk)
+        v = v_ref[0, 0].astype(jnp.float32)                 # (bk, dv)
+        mask = _tile_mask(iq, ik, causal=causal, kv_valid=kv_valid,
+                          block_q=block_q, block_k=block_k)
+        rows = pl.ds(pl.multiple_of(iq * block_q, block_q), block_q)
+        for g in range(group):                              # unrolled
+            q = q_ref[0, g].astype(jnp.float32)             # (bq, dqk)
+            do = do_ref[0, g].astype(jnp.float32)
+            p, ds = _rebuild(q, k, v, do, lse_ref[0, g], di_ref[0, g], mask,
+                             scale)
+            dv_acc[...] += jax.lax.dot_general(              # pᵀ @ do
+                p, do, dimension_numbers=(((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dk_acc[...] += jax.lax.dot_general(              # dsᵀ @ q
+                ds, q, dimension_numbers=(((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dq_ref[0, g, rows, :] += jax.lax.dot(            # ds @ k
+                ds, k, preferred_element_type=jnp.float32)
+
+    if causal:
+        pl.when(_causal_tile_live(iq, ik, block_q, block_k))(_tile)
+    else:
+        _tile()
+
+    @pl.when(iq == n_q - 1)
+    def _finalize():
+        dk_ref[0, 0] = (scale * dk_acc[...]).astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when((ik == n_k - 1) & (iq == n_q - 1))
+    def _finalize_dq():
+        dq_ref[...] = scale * dq_ref[...]
+
+
+def flash_bwd(q, k, v, do, lse, di, *, causal: bool, kv_valid: int,
+              scale: float, block_q: int = 128, block_k: int = 128,
+              interpret: bool = False):
+    """Returns (dq (B, H, Sq, dqk), dk (B, KV, Sk, dqk), dv (B, KV, Sk,
+    dv)), all f32, from one pass over the tiles: what ``flash_bwd_dq`` and
+    ``flash_bwd_dkv`` return, bit for bit. Its scoped VMEM grows with
+    :func:`resident_dq_bytes`; the caller checks that it fits."""
+    B, H, Sq, dqk = q.shape
+    KV, Sk, dv = k.shape[1], k.shape[2], v.shape[3]
+    group = H // KV
+    n_q, n_k = Sq // block_q, Sk // block_k
+    kernel = functools.partial(
+        _bwd_kernel, scale=scale, causal=causal, kv_valid=kv_valid,
+        group=group, n_q=n_q, n_k=n_k, block_q=block_q, block_k=block_k)
+    in_specs = _kv_major_specs(group, block_q, block_k, dqk, dv)
+    dq_spec = pl.BlockSpec((1, group, Sq, dqk),
+                           lambda b, h, ik, iq: (b, h, 0, 0))
+    return pl.pallas_call(
+        kernel,
+        grid=(B, KV, n_k, n_q),
+        in_specs=in_specs,
+        out_specs=[dq_spec] + in_specs[1:3],
+        out_shape=[jax.ShapeDtypeStruct((B, H, Sq, dqk), jnp.float32),
+                   jax.ShapeDtypeStruct((B, KV, Sk, dqk), jnp.float32),
+                   jax.ShapeDtypeStruct((B, KV, Sk, dv), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_k, dqk), jnp.float32),
+                        pltpu.VMEM((block_k, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=(resident_dq_bytes(group, Sq, dqk)
+                              + BWD_VMEM_HEADROOM)),
+        name="flash_bwd",
         interpret=interpret,
     )(q, k, v, do, lse, di)
 

@@ -4,8 +4,9 @@ Handles: backend dispatch (pallas TPU / pallas interpret / pure-XLA ref),
 model→kernel layout moves, shape padding to tile multiples (pad keys are
 masked in-kernel via the static ``kv_valid``; pad queries are sliced off),
 the static tile choice from the call's sequence lengths, and the
-``custom_vjp`` that wires the recompute-style backward kernels in
-(DESIGN.md §9).
+``custom_vjp`` that wires the recompute-style backward in: the one-pass
+kernel where its resident dq fits VMEM, else the split pair (DESIGN.md
+§9).
 
 Model-layout contract (what models/attention.py speaks): q (B, Sq, H, dqk),
 k (B, Sk, KV, dqk), v (B, Sk, KV, dv) with H a multiple of KV (GQA); the
@@ -28,13 +29,20 @@ from repro.kernels.flash_attention import ref as _ref
 Backend = Literal["xla", "pallas", "pallas_interpret"]
 BACKENDS: tuple[str, ...] = ("xla", "pallas", "pallas_interpret")
 
-# Largest tiles of the train kernels (fwd, dq, dkv) per sequence axis.
+# Largest tiles of the train kernels (fwd, bwd) per sequence axis.
 # Every grid step costs a fixed ~0.35 µs on a v5e whatever its tile, so
 # tiles are as large as the default scoped VMEM allows; among those the
 # chip sweep of PERF.md §5 found 512 query by 1024 key rows fastest at
-# smollm-360m's widths (a 1024-row Q tile overflows VMEM in flash_bwd_dq).
+# smollm-360m's widths (a 1024-row Q tile overflows VMEM in the split
+# backward's flash_bwd_dq).
 TILE_CAP_Q = 512
 TILE_CAP_K = 1024
+# The one-pass backward (flash_bwd) holds a KV head's dq over the whole
+# query axis in VMEM; it runs where that block, double-buffered, fits this
+# budget (smollm-360m's 3 x 4096 rows of 64: 12 MiB; Moonlight's 8192 rows
+# of 192: 16 MiB; CLIP's: under 0.2 MiB), else the split flash_bwd_dq +
+# flash_bwd_dkv pair runs (a 3-head group of 64 at 32k tokens: 96 MiB).
+FUSED_BWD_DQ_BYTES = 32 * 1024 * 1024
 # A grid step holds its KV head's whole query group: the q, do and o
 # blocks and the f32 accumulators scale with group x block_q x hd (hd
 # padded to the 128 lanes). The Q tile shrinks to keep that product at
@@ -149,6 +157,13 @@ def flash_fwd_lse(q, k, v, *, causal: bool, backend: Backend = "xla",
     return _to_kernel(o[:, :, :Sq]), lse[:, :, :Sq, 0]
 
 
+def fused_bwd(group: int, Sq: int, dqk: int) -> bool:
+    """Whether the backward of a call with a GQA ``group``, ``Sq`` padded
+    query rows and query/key width ``dqk`` runs the one-pass kernel: its
+    resident dq fits FUSED_BWD_DQ_BYTES."""
+    return _k.resident_dq_bytes(group, Sq, dqk) <= FUSED_BWD_DQ_BYTES
+
+
 def _bwd_impl(causal, backend, block_q, block_k, res, do):
     q, k, v, o, lse = res
     scale = _check(q, k, v)
@@ -171,13 +186,15 @@ def _bwd_impl(causal, backend, block_q, block_k, res, do):
         dop = _pad_seq(_to_kernel(do), 2, bq)
         lsep = _pad_seq(lse, 2, bq)[..., None]
         dip = _pad_seq(di, 2, bq)[..., None]
-        dq = _k.flash_bwd_dq(qp, kp, vp, dop, lsep, dip, causal=causal,
-                             kv_valid=Sk, scale=scale, block_q=bq,
-                             block_k=bk, interpret=interp)[:, :, :Sq]
-        dk, dv = _k.flash_bwd_dkv(qp, kp, vp, dop, lsep, dip, causal=causal,
-                                  kv_valid=Sk, scale=scale, block_q=bq,
-                                  block_k=bk, interpret=interp)
-        dk, dv = dk[:, :, :Sk], dv[:, :, :Sk]
+        args = (qp, kp, vp, dop, lsep, dip)
+        kw = dict(causal=causal, kv_valid=Sk, scale=scale, block_q=bq,
+                  block_k=bk, interpret=interp)
+        if fused_bwd(qp.shape[1] // kp.shape[1], qp.shape[2], qp.shape[3]):
+            dq, dk, dv = _k.flash_bwd(*args, **kw)
+        else:
+            dq = _k.flash_bwd_dq(*args, **kw)
+            dk, dv = _k.flash_bwd_dkv(*args, **kw)
+        dq, dk, dv = dq[:, :, :Sq], dk[:, :, :Sk], dv[:, :, :Sk]
     return (_to_kernel(dq).astype(q.dtype), _to_kernel(dk).astype(k.dtype),
             _to_kernel(dv).astype(v.dtype))
 

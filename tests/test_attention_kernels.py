@@ -8,7 +8,10 @@ token-for-token identical between backends.
 Tolerances: f32 kernel-vs-oracle ≤ 1e-4 (only softmax-reassociation
 error); bf16/int8-policy end-to-end ≤ 2e-2 (bf16 rounding dominates).
 """
+import importlib
+
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -166,6 +169,120 @@ def test_flash_grads_respect_input_dtype():
         *a, causal=True, backend="pallas_interpret"), q, k, v)
     dq, dk, dv = vjp(jnp.ones_like(y))
     assert y.dtype == dq.dtype == dk.dtype == dv.dtype == jnp.bfloat16
+
+
+# ---------------------------------------------------------------------------
+# one-pass backward: the same gradients, bit for bit, as the split pair;
+# the call's shape picks the path
+# ---------------------------------------------------------------------------
+
+FK = importlib.import_module("repro.kernels.flash_attention.flash_attention")
+FUSED, SPLIT = ["flash_bwd"], ["flash_bwd_dkv", "flash_bwd_dq"]
+
+
+def _padded_bwd_operands(B, Sq, Sk, H, KV, dqk, dv, causal, bq, bk):
+    """Kernel-layout backward operands padded to the tiles as ops.py pads
+    them, lse from the forward kernel; returns (operands, kernel kwargs)."""
+    q = jax.random.normal(kq, (B, Sq, H, dqk), jnp.float32)
+    k = jax.random.normal(kk, (B, Sk, KV, dqk), jnp.float32)
+    v = jax.random.normal(kv, (B, Sk, KV, dv), jnp.float32)
+    o, lse = FA.flash_fwd_lse(q, k, v, causal=causal,
+                              backend="pallas_interpret", block_q=bq,
+                              block_k=bk)
+    do = jax.random.normal(kg, o.shape, jnp.float32)
+    bq, bk = FA._blocks(q, k, v, bq, bk)
+    di = jnp.transpose(jnp.sum(o * do, axis=-1), (0, 2, 1))
+    pad, lay = FA._pad_seq, FA._to_kernel
+    args = (pad(lay(q), 2, bq), pad(lay(k), 2, bk), pad(lay(v), 2, bk),
+            pad(lay(do), 2, bq), pad(lse, 2, bq)[..., None],
+            pad(di, 2, bq)[..., None])
+    return args, dict(causal=causal, kv_valid=Sk, scale=dqk ** -0.5,
+                      block_q=bq, block_k=bk, interpret=True)
+
+
+@pytest.mark.parametrize("case", [
+    (B, Sq, Sk, H, KV, hd, hd, causal, bq, bk)
+    for B, Sq, Sk, H, KV, hd, causal, bq, bk in BWD_CASES] + [
+    (1, 40, 40, 2, 2, 24, 16, True, 16, 8),    # latent: dqk != dv, 3 x 5
+    (2, 19, 19, 4, 4, 24, 16, False, 0, 0)])   # latent, one padded tile
+def test_flash_bwd_one_pass_bitwise_matches_split(case):
+    args, kw = _padded_bwd_operands(*case)
+    dq, dk, dv = FK.flash_bwd(*args, **kw)
+    want = (FK.flash_bwd_dq(*args, **kw),) + FK.flash_bwd_dkv(*args, **kw)
+    for name, got, ref in zip(("dq", "dk", "dv"), (dq, dk, dv), want):
+        assert got.shape == ref.shape and got.dtype == ref.dtype, name
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref),
+                                      err_msg=f"{case} {name}")
+
+
+def _bwd_kernel_names(B, Sq, Sk, H, KV, dqk, dv, causal):
+    """Names of the backward pallas_calls in the traced gradient of a call
+    of this shape (traced only: nothing runs)."""
+    def loss(q, k, v):
+        return FA.flash_attention(q, k, v, causal=causal,
+                                  backend="pallas_interpret").sum()
+
+    sd = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(
+        sd((B, Sq, H, dqk), jnp.bfloat16), sd((B, Sk, KV, dqk), jnp.bfloat16),
+        sd((B, Sk, KV, dv), jnp.bfloat16))
+    names = []
+
+    def walk(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.append(eqn.params["name"])
+            for p in eqn.params.values():
+                for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                    if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                        walk(sub.jaxpr)
+                    elif isinstance(sub, jax.extend.core.Jaxpr):
+                        walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return sorted(n for n in names if n.startswith("flash_bwd"))
+
+
+# (B, Sq, Sk, H, KV, dqk, dv, causal, backward kernels): the benchmark
+# cells' calls take the one-pass kernel; a smollm-360m call at 32k tokens
+# (a 96 MiB resident dq) and a GQA group of 8 heads of 128 at 4096 (past
+# the budget by its padded 4224 rows) take the split pair
+BWD_PATHS = {
+    "smollm": ((4, 4096, 4096, 15, 5, 64, 64, True), FUSED),
+    "moonlight_mla": ((2, 8192, 8192, 16, 16, 192, 128, True), FUSED),
+    "clip_vision": ((128, 129, 129, 16, 16, 80, 80, False), FUSED),
+    "clip_text": ((128, 77, 77, 16, 16, 64, 64, True), FUSED),
+    "smollm_32k": ((1, 32768, 32768, 15, 5, 64, 64, True), SPLIT),
+    "gqa8_hd128": ((1, 4096, 4096, 32, 4, 128, 128, True), SPLIT),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(BWD_PATHS))
+def test_flash_bwd_path_by_shape(shape):
+    call, want = BWD_PATHS[shape]
+    B, Sq, Sk, H, KV, dqk, dv, causal = call
+    bq, _ = FA.choose_attn_blocks(Sq, Sk, group=H // KV, hd=max(dqk, dv))
+    assert FA.fused_bwd(H // KV, -(-Sq // bq) * bq, dqk) == (want == FUSED)
+    assert _bwd_kernel_names(*call) == want
+
+
+@pytest.mark.parametrize("case", BWD_CASES)
+def test_flash_bwd_split_path_matches_dense_grads(case, monkeypatch):
+    """With no room for a resident dq the split pair runs, and its
+    gradients still match the dense oracle."""
+    monkeypatch.setattr(FA, "FUSED_BWD_DQ_BYTES", 0)
+    B, Sq, Sk, H, KV, hd, causal, bq, bk = case
+    assert _bwd_kernel_names(B, Sq, Sk, H, KV, hd, hd, causal) == SPLIT
+    q, k, v = _qkv(B, Sq, Sk, H, KV, hd)
+    g = jax.random.normal(kg, (B, Sq, H, hd), jnp.float32)
+    ref = jax.grad(lambda *a: jnp.vdot(
+        _dense_oracle(*a, causal).astype(jnp.float32), g),
+        argnums=(0, 1, 2))(q, k, v)
+    got = jax.grad(lambda *a: jnp.vdot(FA.flash_attention(
+        *a, causal=causal, backend="pallas_interpret", block_q=bq,
+        block_k=bk).astype(jnp.float32), g), argnums=(0, 1, 2))(q, k, v)
+    for name, r, p in zip(("dq", "dk", "dv"), ref, got):
+        assert _rel(r, p) <= TOL_F32, (case, name, _rel(r, p))
 
 
 # ---------------------------------------------------------------------------
