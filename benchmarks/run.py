@@ -5,11 +5,9 @@
 
 Benchmarks:
   variance     App. C      quantization variance vs inner dim k
-  ops          Figs 3-4    per-op SwitchBack cost + speedup model
   accuracy     Figs 1-2    precision modes vs training accuracy (CLIP)
   fp8          Fig 5       tensor-wise fp8 + zero-init layer-scale
   stability    Figs 6-10   loss spikes, RMS predictor, StableAdamW
-  roofline     §Roofline   dry-run derived table (needs results/dryrun)
 """
 from __future__ import annotations
 
@@ -21,11 +19,10 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from benchmarks import (bench_accuracy, bench_fp8_layerscale, bench_roofline,
-                        bench_stability, bench_switchback_ops,
+from benchmarks import (bench_accuracy, bench_fp8_layerscale, bench_stability,
                         bench_variance)
 
-ALL = ("variance", "ops", "accuracy", "fp8", "stability", "roofline")
+ALL = ("variance", "accuracy", "fp8", "stability")
 
 
 def main() -> None:
@@ -44,8 +41,6 @@ def main() -> None:
         t1 = time.time()
         if name == "variance":
             bench_variance.run(out_json=f"{args.out}/variance.json")
-        elif name == "ops":
-            bench_switchback_ops.run(out_json=f"{args.out}/ops.json")
         elif name == "accuracy":
             bench_accuracy.run(steps=args.steps or 200,
                                out_json=f"{args.out}/accuracy.json")
@@ -55,8 +50,6 @@ def main() -> None:
         elif name == "stability":
             bench_stability.run(steps=args.steps or 160,
                                 out_json=f"{args.out}/stability.json")
-        elif name == "roofline":
-            bench_roofline.run()
         print(f"[{name} done in {time.time()-t1:.0f}s]")
     print(f"\nall benchmarks done in {time.time()-t0:.0f}s")
 

@@ -12,8 +12,7 @@ import importlib
 
 from repro.configs.base import (CLIPConfig, EncDecConfig, MambaConfig,
                                 MLAConfig, ModelConfig, MoEConfig,
-                                ParallelConfig,
-                                RWKVConfig, ShapeConfig, SHAPES, TrainConfig)
+                                ParallelConfig, RWKVConfig, TrainConfig)
 
 ARCH_IDS = (
     "qwen3-moe-30b-a3b", "arctic-480b", "rwkv6-1.6b", "internvl2-76b",
@@ -57,15 +56,3 @@ def get_reduced_config(name: str):
     mod = importlib.import_module(f"repro.configs.{_MODULES[name]}")
     return mod.REDUCED
 
-
-def shapes_for(name: str):
-    """The shape cells that apply to this arch (assignment rules:
-    long_500k only for ssm/hybrid; every arch here has a decoder)."""
-    cfg = get_config(name)
-    out = ["train_4k", "prefill_32k", "decode_32k"]
-    if getattr(cfg, "supports_long_context", False):
-        out.append("long_500k")
-    if name == PAPER_ARCH or getattr(cfg, "mla", None) is not None:
-        out = ["train_4k"]   # CLIP is a training-only two-tower model;
-        # latent attention trains but has no decode cache yet
-    return [SHAPES[s] if isinstance(s, str) else s for s in out]

@@ -166,20 +166,10 @@ class ParallelConfig:
     mesh_shape: Tuple[int, ...] = (16, 16)
     mesh_axes: Tuple[str, ...] = ("data", "model")
     fsdp: bool = False               # shard weights over data too (ZeRO-3)
-    fsdp_gather_weights: bool = False  # explicit bf16 weight all-gather at
-    # use (ZeRO-3 semantics) instead of GSPMD activation partial-sums
-    gather_wire: str = "bf16"        # bf16|int8 — int8 ships weights over
-    # the wire tensor-wise-quantized; free under SwitchBack (§Perf it. 2)
     pure_dp: bool = False            # fold the model axis into data
-    # parallelism (models too small to need TP, e.g. 1B CLIP on 256 chips)
-    shard_kv_heads: bool = True      # False: replicate K/V projections —
-    # when n_kv_heads < model-axis size, sharding the flat KV dim splits
-    # heads across devices and GSPMD regathers at the head reshape
-    # (§Perf qwen iteration 5); decode keeps True (shards the KV cache)
+    # parallelism (models too small to need tensor parallelism)
     scan_layers: bool = True
     remat: str = "block"             # none|block|full
-    sequence_parallel: bool = False  # shard seq over data when batch too small
-    grad_compression: str = "none"   # none|int8_rowwise
     attn_impl: str = "flash_scan"    # flash_scan | dense — "dense" forces
     # the materialized-scores oracle on EVERY backend (kernels included)
     attn_block_q: int = 0            # flash-attention kernel Q-tile rows;
@@ -326,20 +316,3 @@ class TelemetryConfig:
     path: Optional[str] = None       # JSONL event file (None = off)
     profile_steps: Optional[Tuple[int, int]] = None
     profile_dir: str = "/tmp/repro-profile"
-
-
-@dataclasses.dataclass(frozen=True)
-class ShapeConfig:
-    """One (input-shape) cell of the assignment."""
-    name: str                        # train_4k / prefill_32k / decode_32k / long_500k
-    kind: str                        # train | prefill | decode
-    seq_len: int
-    global_batch: int
-
-
-SHAPES = {
-    "train_4k":    ShapeConfig("train_4k", "train", 4096, 256),
-    "prefill_32k": ShapeConfig("prefill_32k", "prefill", 32768, 32),
-    "decode_32k":  ShapeConfig("decode_32k", "decode", 32768, 128),
-    "long_500k":   ShapeConfig("long_500k", "decode", 524288, 1),
-}

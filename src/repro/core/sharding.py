@@ -54,23 +54,12 @@ class ShardCtx:
 
     Enter it around TRACING (e.g. ``with ShardCtx(...): f.lower(...)``) —
     the constraints are staged into the jaxpr at trace time.
-
-    ``gather_fsdp``: when True, `use_weight` inserts an explicit
-    resharding of FSDP(data)-sharded weights to their no-FSDP sharding in
-    the compute dtype before each use — an all-gather of *weights* (ZeRO-3
-    semantics) instead of letting GSPMD partial-sum *activations*. §Perf
-    iteration 1.
     """
     _current: Optional["ShardCtx"] = None
 
-    def __init__(self, mesh: Optional[Mesh], rules: Optional[dict],
-                 rules_nofsdp: Optional[dict] = None,
-                 gather_fsdp: bool = False, gather_wire: str = "bf16"):
+    def __init__(self, mesh: Optional[Mesh], rules: Optional[dict]):
         self.mesh = mesh
         self.rules = rules
-        self.rules_nofsdp = rules_nofsdp or rules
-        self.gather_fsdp = gather_fsdp
-        self.gather_wire = gather_wire
 
     def constrain(self, x: jax.Array, logical: Tuple[Optional[str], ...]):
         if self.mesh is None or self.rules is None:

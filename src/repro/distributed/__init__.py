@@ -1,9 +1,1 @@
-from repro.distributed.hlo_analysis import (  # noqa: F401
-    collective_summary, parse_collectives, count_dot_flops_by_dtype)
-from repro.distributed.roofline import (  # noqa: F401
-    RooflineCell, model_flops, format_table,
-    PEAK_BF16, PEAK_INT8, HBM_BW, ICI_BW)
-from repro.distributed.compression import (  # noqa: F401
-    compressed_allreduce_mean, compressed_tree_allreduce_mean,
-    wire_bytes_saved)
 from repro.distributed.straggler import StragglerWatchdog  # noqa: F401

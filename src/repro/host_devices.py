@@ -7,7 +7,7 @@ deliberately imports nothing that does).
 
 Sources, in precedence order: explicit ``n``, ``--devices N`` /
 ``--devices=N`` in ``argv`` (an explicit flag beats the ambient env), the
-REPRO_DRYRUN_DEVICES env var (the dryrun/test convention), then
+REPRO_DRYRUN_DEVICES env var (the CI and test convention), then
 ``default``.
 """
 from __future__ import annotations

@@ -1,1 +1,1 @@
-from repro.launch.mesh import make_production_mesh, make_test_mesh  # noqa: F401
+from repro.launch.mesh import make_test_mesh  # noqa: F401

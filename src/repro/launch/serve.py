@@ -132,7 +132,7 @@ def main():
     ap.add_argument("--spec-min-ngram", type=int, default=2,
                     help="spec: shortest n-gram worth a verify pass")
     ap.add_argument("--mesh", default="auto",
-                    choices=("auto", "test", "single", "multi"))
+                    choices=("auto", "test"))
     ap.add_argument("--devices", type=int, default=None,
                     help="force N host CPU devices (read pre-jax-import)")
     ap.add_argument("--seed", type=int, default=0)

@@ -4,14 +4,11 @@
   (the default, ``smollm-360m-reduced``) is its shrunken CPU-sized twin.
   Either trains on synthetic data end-to-end through the engine on a mesh
   over the local devices — the same jitted, donated, sharded step the
-  production path and the dry-run compile.
+  benchmark times.
 * ``--mesh test`` with forced host devices exercises real partitioning:
 
     PYTHONPATH=src REPRO_DRYRUN_DEVICES=8 python -m repro.launch.train \
         --arch smollm-360m-reduced --steps 20 --mesh test
-
-* ``--mesh single|multi`` builds the production runbook meshes (shrunk
-  proportionally when fewer devices exist, as in the dry-run).
 """
 from __future__ import annotations
 
@@ -100,7 +97,7 @@ def main():
                     help="supervisor: rewinds per incident before abort")
     ap.add_argument("--microbatch", type=int, default=1)
     ap.add_argument("--mesh", default="auto",
-                    choices=("auto", "test", "single", "multi"))
+                    choices=("auto", "test"))
     ap.add_argument("--devices", type=int, default=None,
                     help="force N host CPU devices (read pre-jax-import)")
     ap.add_argument("--fsdp", action="store_true",

@@ -70,9 +70,9 @@ def qkv_project(x: Array, p: dict, cfg, policy: QuantPolicy):
     B, S, _ = x.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     cd = policy.compute_dtype
-    wq = PRM.use_weight(p["wq"], ("embed", "heads"), cd)
-    wk = PRM.use_weight(p["wk"], ("embed", "kv_heads"), cd)
-    wv = PRM.use_weight(p["wv"], ("embed", "kv_heads"), cd)
+    wq = p["wq"].astype(cd)
+    wk = p["wk"].astype(cd)
+    wv = p["wv"].astype(cd)
     q = quant_linear(x, wq, policy=policy).reshape(B, S, H, hd)
     k = quant_linear(x, wk, policy=policy).reshape(B, S, KV, hd)
     v = quant_linear(x, wv, policy=policy).reshape(B, S, KV, hd)
@@ -213,14 +213,12 @@ def attention_block(x: Array, p: dict, cfg, policy: QuantPolicy, *,
     q, k, v = qkv_project(x, p, cfg, policy)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
-    # no seq name here: under sequence-parallel the residual stream owns
-    # the model axis on seq; attention internals shard heads instead
     q = PRM.constrain(q, ("batch", None, "heads", None))
     k = PRM.constrain(k, ("batch", None, "kv_heads", None))
     o = _core_attention(q, k, v, causal=causal, policy=policy, impl=impl,
                         block_q=block_q, block_k=block_k)
     o = o.reshape(x.shape[0], x.shape[1], cfg.n_heads * cfg.hd)
-    wo = PRM.use_weight(p["wo"], ("heads", "embed"), policy.compute_dtype)
+    wo = p["wo"].astype(policy.compute_dtype)
     return quant_linear(o, wo, policy=policy)
 
 
@@ -241,13 +239,12 @@ def mla_block(x: Array, p: dict, cfg, policy: QuantPolicy, *,
                         m.kv_lora_rank)
     cd = policy.compute_dtype
     with jax.named_scope("mla"):
-        q = quant_linear(x, PRM.use_weight(p["wq"], ("embed", "heads"), cd),
+        q = quant_linear(x, p["wq"].astype(cd),
                          policy=policy).reshape(B, S, H, dn + dr)
-        kv_a = quant_linear(x, PRM.use_weight(p["wkv_a"], ("embed", None),
-                                              cd), policy=policy)
+        kv_a = quant_linear(x, p["wkv_a"].astype(cd), policy=policy)
         c = rms_norm(kv_a[..., :r], p["kv_norm"]["scale"], cfg.norm_eps)
         k_rope = apply_rope(kv_a[..., None, r:], positions, cfg.rope_theta)
-        kv = quant_linear(c, PRM.use_weight(p["wkv_b"], (None, "heads"), cd),
+        kv = quant_linear(c, p["wkv_b"].astype(cd),
                           policy=policy).reshape(B, S, H, dn + dv)
         q = jnp.concatenate(
             [q[..., :dn], apply_rope(q[..., dn:], positions, cfg.rope_theta)],
@@ -258,7 +255,7 @@ def mla_block(x: Array, p: dict, cfg, policy: QuantPolicy, *,
         k = PRM.constrain(k, ("batch", None, "heads", None))
         o = _core_attention(q, k, kv[..., dn:], causal=True, policy=policy,
                             impl=impl, block_q=block_q, block_k=block_k)
-        wo = PRM.use_weight(p["wo"], ("heads", "embed"), cd)
+        wo = p["wo"].astype(cd)
         return quant_linear(o.reshape(B, S, H * dv), wo, policy=policy)
 
 
@@ -337,7 +334,7 @@ def attention_decode_step(x: Array, cache: KVCache, p: dict, cfg,
         vx = _expand_kv(jnp.swapaxes(v_cache, 1, 2), cfg.n_heads)
         o = dense_attention(q, kx, vx, causal=False, kv_len=kv_len)
     o = o.reshape(B, 1, cfg.n_heads * cfg.hd)
-    wo = PRM.use_weight(p["wo"], ("heads", "embed"), policy.compute_dtype)
+    wo = p["wo"].astype(policy.compute_dtype)
     out = quant_linear(o, wo, policy=policy)
     return out, KVCache(k_cache, v_cache, cache.length + 1)
 
@@ -376,7 +373,7 @@ def attention_prefill(x: Array, cache: KVCache, p: dict, cfg,
     o = _core_attention(q, k, v, causal=True, policy=policy, impl=impl,
                         block_q=block_q, block_k=block_k)
     o = o.reshape(B, S, cfg.n_heads * cfg.hd)
-    wo = PRM.use_weight(p["wo"], ("heads", "embed"), policy.compute_dtype)
+    wo = p["wo"].astype(policy.compute_dtype)
     out = quant_linear(o, wo, policy=policy)
     sel = admit[:, None, None, None]
     k_cache = jnp.where(sel, cache.k.at[:, :, :S].set(
@@ -523,7 +520,7 @@ def attention_paged_prefill(x: Array, cache: PagedKVCache, tables: Array,
         o = jnp.einsum("bhqk,bkhd->bqhd", a,
                        vx.astype(jnp.float32)).astype(q.dtype)
     o = o.reshape(B, S, H * hd)
-    wo = PRM.use_weight(p["wo"], ("heads", "embed"), policy.compute_dtype)
+    wo = p["wo"].astype(policy.compute_dtype)
     out = quant_linear(o, wo, policy=policy)
     return out, PagedKVCache(k_buf, v_buf, cache.length)
 
@@ -567,7 +564,7 @@ def attention_paged_decode_step(x: Array, cache: PagedKVCache,
         q, k_buf, v_buf, tables, valid, batch_dims=(0, None, None, 0, 0),
         backend=backend)
     o = o.reshape(B, 1, H * hd)
-    wo = PRM.use_weight(p["wo"], ("heads", "embed"), policy.compute_dtype)
+    wo = p["wo"].astype(policy.compute_dtype)
     out = quant_linear(o, wo, policy=policy)
     return out, PagedKVCache(k_buf, v_buf, cache.length + 1)
 
@@ -577,23 +574,23 @@ def cross_attention(x: Array, enc_kv: tuple[Array, Array], p: dict, cfg,
                     block_q: int = 0, block_k: int = 0) -> Array:
     """Encoder-decoder cross attention; enc_kv are precomputed (B,Se,KV,hd)."""
     B, S, _ = x.shape
-    wq = PRM.use_weight(p["wq"], ("embed", "heads"), policy.compute_dtype)
+    wq = p["wq"].astype(policy.compute_dtype)
     q = quant_linear(x, wq, policy=policy).reshape(
         B, S, cfg.n_heads, cfg.hd)
     k, v = enc_kv
     o = _core_attention(q, k, v, causal=False, policy=policy, impl=impl,
                         block_q=block_q, block_k=block_k)
     o = o.reshape(B, S, cfg.n_heads * cfg.hd)
-    wo = PRM.use_weight(p["wo"], ("heads", "embed"), policy.compute_dtype)
+    wo = p["wo"].astype(policy.compute_dtype)
     return quant_linear(o, wo, policy=policy)
 
 
 def encode_cross_kv(enc_out: Array, p: dict, cfg, policy: QuantPolicy):
     B, Se, _ = enc_out.shape
-    wk = PRM.use_weight(p["wk"], ("embed", "kv_heads"), policy.compute_dtype)
+    wk = p["wk"].astype(policy.compute_dtype)
     k = quant_linear(enc_out, wk, policy=policy).reshape(
         B, Se, cfg.n_kv_heads, cfg.hd)
-    wv = PRM.use_weight(p["wv"], ("embed", "kv_heads"), policy.compute_dtype)
+    wv = p["wv"].astype(policy.compute_dtype)
     v = quant_linear(enc_out, wv, policy=policy).reshape(
         B, Se, cfg.n_kv_heads, cfg.hd)
     return k, v

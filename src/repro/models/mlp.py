@@ -4,7 +4,6 @@ from __future__ import annotations
 import jax
 
 from repro.core.precision import QuantPolicy, quant_linear
-from repro.models import params as PRM
 from repro.models.common import activation
 
 Array = jax.Array
@@ -13,10 +12,8 @@ Array = jax.Array
 def mlp_block(x: Array, p: dict, cfg, policy: QuantPolicy) -> Array:
     """x: (B, S, D) -> (B, S, D). SwiGLU uses w_gate; GELU does not."""
     cd = policy.compute_dtype
-    h = quant_linear(x, PRM.use_weight(p["w_up"], ("embed", "mlp"), cd),
-                     policy=policy)
-    g = (quant_linear(x, PRM.use_weight(p["w_gate"], ("embed", "mlp"), cd),
-                      policy=policy) if "w_gate" in p else None)
+    h = quant_linear(x, p["w_up"].astype(cd), policy=policy)
+    g = (quant_linear(x, p["w_gate"].astype(cd), policy=policy)
+         if "w_gate" in p else None)
     h = activation(h, g, cfg.act)
-    return quant_linear(h, PRM.use_weight(p["w_down"], ("mlp", "embed"), cd),
-                        policy=policy)
+    return quant_linear(h, p["w_down"].astype(cd), policy=policy)

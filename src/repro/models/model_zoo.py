@@ -23,7 +23,7 @@ from repro.models import params as PRM
 
 @dataclasses.dataclass(frozen=True)
 class ModelBundle:
-    """Everything the trainer / dry-run needs for one architecture."""
+    """Everything the trainer and server need for one architecture."""
     cfg: Any
     param_specs: Dict
     loss_fn: Callable            # (params, batch, policy, parallel) -> (loss, metrics)

@@ -185,12 +185,10 @@ def moe_block(x: Array, p: dict, cfg, policy: QuantPolicy):
         row_token = jnp.where(row_slot >= 0, row_slot // moe.top_k, -1)
         rows = take_rows(x2.astype(cd), row_token, dest.reshape(T, -1))
     with jax.named_scope("moe.experts"):
-        w = {k: PRM.use_weight(p[k], ("experts", None, None))
-             for k in ("w_gate", "w_up", "w_down") if k in p}
-        h = grouped_linear(rows, w["w_up"], tile_expert, n_live, policy)
-        g = (grouped_linear(rows, w["w_gate"], tile_expert, n_live, policy)
-             if "w_gate" in w else None)
-        y = grouped_linear(activation(h, g, cfg.act), w["w_down"],
+        h = grouped_linear(rows, p["w_up"], tile_expert, n_live, policy)
+        g = (grouped_linear(rows, p["w_gate"], tile_expert, n_live, policy)
+             if "w_gate" in p else None)
+        y = grouped_linear(activation(h, g, cfg.act), p["w_down"],
                            tile_expert, n_live, policy)
     with jax.named_scope("moe.combine"):
         ys = take_rows(y, dest, row_slot[:, None]).reshape(T, -1, D)

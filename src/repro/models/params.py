@@ -1,11 +1,11 @@
 """ParamSpec machinery: one declarative tree drives real init, abstract
-(ShapeDtypeStruct) init for the dry-run, and NamedSharding assignment.
+(ShapeDtypeStruct) init, and NamedSharding assignment.
 
 Every model defines ``param_specs(cfg) -> nested dict of ParamSpec``; the
 three consumers derive everything else:
 
     params    = init_params(specs, key)            # smoke tests / examples
-    abstract  = abstract_params(specs)             # dry-run, no allocation
+    abstract  = abstract_params(specs)             # no allocation
     shardings = specs_to_shardings(specs, mesh, rules)
 """
 from __future__ import annotations
@@ -77,10 +77,10 @@ def default_rules(parallel) -> Dict[str, object]:
     carries DP; with fsdp=True the embed axis of weights is sharded over
     data as well (ZeRO-3-style parameter sharding).
 
-    ``pure_dp`` (§Perf iteration 2): models too small to need TP fold the
-    model axis into data parallelism — batch shards over every mesh axis,
-    no tensor dim maps to "model", so blocks have NO activation collectives
-    at all (weight gathers + grad reduce-scatters only).
+    ``pure_dp``: models too small to need TP fold the model axis into
+    data parallelism — batch shards over every mesh axis, no tensor dim
+    maps to "model", so blocks have NO activation collectives at all
+    (weight gathers + grad reduce-scatters only).
     """
     data = parallel.data_axes            # ("data",) or ("pod", "data")
     if parallel.pure_dp:
@@ -99,8 +99,7 @@ def default_rules(parallel) -> Dict[str, object]:
         "embed": None,
         "seq": None,
         "heads": "model",
-        "kv_heads": ("model" if getattr(parallel, "shard_kv_heads", True)
-                     else None),
+        "kv_heads": "model",
         "head_dim": None,
         "mlp": "model",
         "experts": "model",
@@ -114,12 +113,6 @@ def default_rules(parallel) -> Dict[str, object]:
     }
     if parallel.fsdp:
         rules["embed"] = data            # ZeRO-3: shard the big axis over data
-    if parallel.sequence_parallel:
-        # Korthikanti-style SP: the residual stream between blocks shards
-        # the seq dim over `model`; matmul inputs all-gather it back and
-        # block outputs reduce-scatter — replacing 2x-wire all-reduces
-        # with RS+AG pairs (half the bytes) and sharding norms/residuals.
-        rules["seq"] = "model"
     return rules
 
 
@@ -136,48 +129,3 @@ def specs_to_pspecs(specs, mesh: Mesh, rules):
         return _divisible(s.shape, logical_to_pspec(s.logical, rules), mesh)
     return jax.tree.map(one, specs, is_leaf=is_spec)
 
-
-# ---------------------------------------------------------------------------
-# weights under the active ShardCtx
-# ---------------------------------------------------------------------------
-
-def use_weight(w: jax.Array, logical: Tuple[Optional[str], ...],
-               dtype=None) -> jax.Array:
-    """Prepare a weight for use in a matmul.
-
-    With ``gather_fsdp`` on: cast to the compute dtype FIRST (halves the
-    wire bytes) and pin the no-FSDP sharding — XLA emits one all-gather of
-    the (small) weight instead of an all-reduce of the (large) activation
-    partial-sums, and the backward pass symmetrically reduce-scatters the
-    weight gradient (exactly ZeRO-3). No-op outside a ShardCtx.
-
-    ``gather_wire == "int8"`` (§Perf iteration 2, ZeRO++-style): the weight
-    crosses the wire tensor-wise int8-quantized (the paper's Eq. 2 — under
-    the int8_switchback policy this is the SAME quantization the forward
-    matmul applies, so the gather compression is algorithmically free) and
-    is dequantized locally after the gather.
-    """
-    ctx = ShardCtx._current
-    if dtype is not None:
-        w = w.astype(dtype)
-    if ctx is None or not ctx.gather_fsdp or ctx.mesh is None:
-        return w
-    ps = logical_to_pspec(logical, ctx.rules_nofsdp)
-    ps = _divisible(w.shape, ps, ctx.mesh)
-    sh = NamedSharding(ctx.mesh, ps)
-    if ctx.gather_wire == "int8":
-        import jax.numpy as jnp
-        absmax = jnp.maximum(jnp.max(jnp.abs(w.astype(jnp.float32))), 1e-12)
-        q = jnp.round(w.astype(jnp.float32) * (127.0 / absmax)) \
-            .astype(jnp.int8)
-        q = jax.lax.with_sharding_constraint(q, sh)    # int8 on the wire
-        return (q.astype(jnp.float32) * (absmax / 127.0)).astype(w.dtype)
-    return jax.lax.with_sharding_constraint(w, sh)
-
-
-def nofsdp_rules(rules: dict, data_axes) -> dict:
-    """The same rule table with the FSDP (data-over-embed) mapping removed."""
-    out = dict(rules)
-    if out.get("embed") == data_axes or out.get("embed") in ("data",):
-        out["embed"] = None
-    return out

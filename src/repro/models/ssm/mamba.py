@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.precision import QuantPolicy, quant_linear
-from repro.models import params as PRM
 
 Array = jax.Array
 
@@ -79,7 +78,7 @@ def mamba_block(x: Array, p: dict, cfg, policy: QuantPolicy, *,
     dt_rank = mc.dt_rank or -(-D // 16)
 
     cd = policy.compute_dtype
-    xz = quant_linear(x, PRM.use_weight(p["w_in"], ("embed", "mlp"), cd),
+    xz = quant_linear(x, p["w_in"].astype(cd),
                       policy=policy)          # (B,S,2*d_inner)
     xs_raw, z = jnp.split(xz, 2, axis=-1)
     prefix = (state.conv.astype(x.dtype) if state is not None else
@@ -91,7 +90,7 @@ def mamba_block(x: Array, p: dict, cfg, policy: QuantPolicy, *,
     xs_ = jax.nn.silu(xs_.astype(jnp.float32)).astype(x.dtype)
 
     # data-dependent Δ, B, C
-    dbc = quant_linear(xs_, PRM.use_weight(p["w_x_proj"], ("mlp", None), cd),
+    dbc = quant_linear(xs_, p["w_x_proj"].astype(cd),
                        policy=policy)   # (B,S,dt_rank+2N)
     dt, Bm, Cm = jnp.split(dbc, [dt_rank, dt_rank + N], axis=-1)
     delta = jax.nn.softplus(
@@ -102,6 +101,5 @@ def mamba_block(x: Array, p: dict, cfg, policy: QuantPolicy, *,
     h0 = state.h if state is not None else None
     y, h_final = selective_scan(xs_, delta, A, Bm, Cm, p["D"], h0)
     y = y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype)
-    out = quant_linear(y, PRM.use_weight(p["w_out"], ("mlp", "embed"), cd),
-                       policy=policy)
+    out = quant_linear(y, p["w_out"].astype(cd), policy=policy)
     return out, MambaState(new_conv, h_final)

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.precision import QuantPolicy, quant_linear
-from repro.models import params as PRM
 from repro.models.common import group_norm_heads
 
 Array = jax.Array
@@ -154,11 +153,10 @@ def rwkv6_block(x: Array, p: dict, cfg, policy: QuantPolicy, *,
     xg = _mix(x, xs, p["mu_g"], p["mix_lora_a"], p["mix_lora_b_g"])
 
     cd = policy.compute_dtype
-    uw = lambda nm, lg: PRM.use_weight(p[nm], lg, cd)
-    r = quant_linear(xr, uw("wr", ("embed", "heads")), policy=policy).reshape(B, S, H, hd)
-    k = quant_linear(xk, uw("wk", ("embed", "heads")), policy=policy).reshape(B, S, H, hd)
-    v = quant_linear(xv, uw("wv", ("embed", "heads")), policy=policy).reshape(B, S, H, hd)
-    g = quant_linear(xg, uw("wg", ("embed", "heads")), policy=policy)
+    r = quant_linear(xr, p["wr"].astype(cd), policy=policy).reshape(B, S, H, hd)
+    k = quant_linear(xk, p["wk"].astype(cd), policy=policy).reshape(B, S, H, hd)
+    v = quant_linear(xv, p["wv"].astype(cd), policy=policy).reshape(B, S, H, hd)
+    g = quant_linear(xg, p["wg"].astype(cd), policy=policy)
     w = _decay(xw, p).reshape(B, S, H, hd)
     u = p["u"].reshape(H, hd)
 
@@ -189,8 +187,8 @@ def rwkv6_block(x: Array, p: dict, cfg, policy: QuantPolicy, *,
     out = out.reshape(B, S, D).astype(x.dtype)
     out = group_norm_heads(out, p["ln_x"], H)
     out = out * jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype)
-    out = quant_linear(out, PRM.use_weight(p["wo"], ("heads", "embed"),
-                       policy.compute_dtype), policy=policy)
+    out = quant_linear(out, p["wo"].astype(policy.compute_dtype),
+                       policy=policy)
     cm_prev = (state.cm_x_prev if state is not None
                else jnp.zeros((B, D), x.dtype))
     new_state = RWKVState(new_s, x[:, -1, :], cm_prev)
@@ -206,12 +204,9 @@ def rwkv_channel_mix(x: Array, p: dict, cfg, policy: QuantPolicy, *,
     xk = _mix(x, xs, p["mu_ck"], p["mix_lora_a"], p["mix_lora_b_ck"])
     xr = _mix(x, xs, p["mu_cr"], p["mix_lora_a"], p["mix_lora_b_cr"])
     cd = policy.compute_dtype
-    kk = quant_linear(xk, PRM.use_weight(p["w_key"], ("embed", "mlp"), cd),
-                      policy=policy)
+    kk = quant_linear(xk, p["w_key"].astype(cd), policy=policy)
     kk = jnp.square(jax.nn.relu(kk.astype(jnp.float32))).astype(x.dtype)
-    vv = quant_linear(kk, PRM.use_weight(p["w_value"], ("mlp", "embed"), cd),
-                      policy=policy)
+    vv = quant_linear(kk, p["w_value"].astype(cd), policy=policy)
     rr = jax.nn.sigmoid(quant_linear(
-        xr, PRM.use_weight(p["w_receptance"], ("embed", "heads"), cd),
-        policy=policy).astype(jnp.float32))
+        xr, p["w_receptance"].astype(cd), policy=policy).astype(jnp.float32))
     return (rr.astype(x.dtype) * vv), x[:, -1, :]

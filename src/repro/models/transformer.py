@@ -6,10 +6,6 @@ pattern repeats with period P = lcm(attention period, MoE period); the model
 scans over L/P groups, unrolling the P heterogeneous layers inside the group
 body. This keeps HLO size O(P) instead of O(L) (probe: 186s unrolled vs 2.5s
 scanned compile at 20B scale) while supporting mixed layer kinds.
-
-The same `group_apply` body is reused by the dry-run cost probes
-(launch/dryrun.py) so per-layer FLOPs/bytes/collectives are measured from
-exactly the compiled computation and multiplied by the group count.
 """
 from __future__ import annotations
 
@@ -370,10 +366,9 @@ def embed_input(params, tokens: Array, cfg: ModelConfig,
     with jax.named_scope("embed"):
         x = jnp.asarray(params["embed"], policy.compute_dtype)[tokens]
         if extra_embeds is not None:
-            fe = quant_linear(extra_embeds.astype(policy.compute_dtype),
-                              PRM.use_weight(params["frontend_proj"],
-                                             ("embed", "embed"),
-                                             policy.compute_dtype),
+            cd = policy.compute_dtype
+            fe = quant_linear(extra_embeds.astype(cd),
+                              params["frontend_proj"].astype(cd),
                               policy=policy)
             x = jnp.concatenate([fe, x], axis=1)
         return PRM.constrain(x, ("batch", "seq", "embed"))
@@ -392,9 +387,8 @@ def lm_head(params, x: Array, cfg: ModelConfig, policy: QuantPolicy):
             # sensitive.
             logits = jnp.einsum(
                 "btd,dv->btv", x.astype(policy.compute_dtype),
-                PRM.use_weight(params["head"], ("embed", "vocab"),
-                               policy.compute_dtype))
-        # vocab gets the model axis (takes precedence over seq under SP)
+                params["head"].astype(policy.compute_dtype))
+        # vocab gets the model axis
         return PRM.constrain(logits, ("batch", None, "vocab"))
 
 
@@ -500,25 +494,6 @@ def init_decode_state(cfg: ModelConfig, batch: int, max_len: int,
                       jnp.float32),
             jnp.zeros((G, batch, cfg.d_model), dtype),
             jnp.zeros((G, batch, cfg.d_model), dtype))
-
-    return {f"pos{i}": one(i) for i in range(P)}
-
-
-def decode_state_logical_axes(cfg: ModelConfig):
-    """Logical axes for the decode state (for sharding assignment)."""
-    P = period(cfg)
-
-    def one(i):
-        kind = cfg.layer_kind(i)
-        if kind == "attn":
-            ax = ("layers", "batch", "kv_heads", "cache_seq", None)
-            return ATT.KVCache(ax, ax, ("layers",))
-        if kind == "mamba":
-            return MambaState(("layers", "batch", None, "mlp"),
-                              ("layers", "batch", "mlp", None))
-        return RWKVState(("layers", "batch", "heads", None, None),
-                         ("layers", "batch", "embed"),
-                         ("layers", "batch", "embed"))
 
     return {f"pos{i}": one(i) for i in range(P)}
 

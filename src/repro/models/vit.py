@@ -87,29 +87,26 @@ def _attn(x, p, heads, policy, causal, impl="flash_scan", block_q=0,
     B, S, W = x.shape
     hd = W // heads
     cd = policy.compute_dtype
-    uw = lambda nm, lg: PRM.use_weight(p[nm], lg, cd)
-    q = quant_linear(x, uw("wq", ("embed", "heads")), p["bq"],
+    q = quant_linear(x, p["wq"].astype(cd), p["bq"],
                      policy=policy).reshape(B, S, heads, hd)
-    k = quant_linear(x, uw("wk", ("embed", "heads")), p["bk"],
+    k = quant_linear(x, p["wk"].astype(cd), p["bk"],
                      policy=policy).reshape(B, S, heads, hd)
-    v = quant_linear(x, uw("wv", ("embed", "heads")), p["bv"],
+    v = quant_linear(x, p["wv"].astype(cd), p["bv"],
                      policy=policy).reshape(B, S, heads, hd)
     # same backend rule as the LM towers: the policy's kernel backend
     # flips both towers of the paper's CLIP onto the fused flash kernels
     from repro.models.attention import _core_attention
     o = _core_attention(q, k, v, causal=causal, policy=policy, impl=impl,
                         block_q=block_q, block_k=block_k).reshape(B, S, W)
-    return quant_linear(o, uw("wo", ("heads", "embed")), p["bo"],
-                        policy=policy)
+    return quant_linear(o, p["wo"].astype(cd), p["bo"], policy=policy)
 
 
 def _mlp(x, p, policy):
     cd = policy.compute_dtype
-    h = quant_linear(x, PRM.use_weight(p["w_up"], ("embed", "mlp"), cd),
-                     p["b_up"], policy=policy)
+    h = quant_linear(x, p["w_up"].astype(cd), p["b_up"], policy=policy)
     h = jax.nn.gelu(h.astype(jnp.float32)).astype(x.dtype)
-    return quant_linear(h, PRM.use_weight(p["w_down"], ("mlp", "embed"), cd),
-                        p["b_down"], policy=policy)
+    return quant_linear(h, p["w_down"].astype(cd), p["b_down"],
+                        policy=policy)
 
 
 def vit_block(x, lp, heads: int, policy: QuantPolicy, causal: bool = False,
@@ -149,10 +146,9 @@ def vision_forward(params, images_or_patches: Array, cfg: CLIPConfig,
         else:
             patches = images_or_patches
         B, N, _ = patches.shape
-        x = quant_linear(patches.astype(policy.compute_dtype),
-                         PRM.use_weight(params["patch_embed"],
-                                        ("embed", "heads"),
-                                        policy.compute_dtype), policy=policy)
+        cd = policy.compute_dtype
+        x = quant_linear(patches.astype(cd), params["patch_embed"].astype(cd),
+                         policy=policy)
         x = x + params["pos_embed"][:, 1:N + 1].astype(x.dtype)
 
         # patch dropout (paper §2.2.2: 0.5) — keep a random half at train

@@ -378,8 +378,7 @@ class ServeEngine:
         list for ``prompts[i]`` and stats carries tokens/s, per-request
         TTFT, decode-only inter-token latency percentiles (plus the raw
         wall-clock ``itl_wall_*`` and the isolated ``prefill_stall_*``),
-        and the scheduler's admission/eviction/preemption counters (the
-        JSON row source for ``benchmarks/bench_serve.py``).
+        and the scheduler's admission/eviction/preemption counters.
 
         Under ``cache_mode="paged"`` the loop additionally drives a
         :class:`~repro.serve.paged.PagedCacheManager`: admission runs the

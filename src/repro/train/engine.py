@@ -3,7 +3,7 @@
 Given ``(model, TrainConfig, ParallelConfig, mesh)`` the engine assembles
 the full sharded TrainState story once:
 
-  * abstract state (ShapeDtypeStructs — zero allocation, what the dry-run
+  * abstract state (ShapeDtypeStructs — zero allocation, what ``lower``
     lowers against) and concrete sharded init (``init_state``),
   * per-leaf NamedShardings for params (via the ``models/params.py``
     logical-axis rules), optimizer state (via the Optimizer protocol's
@@ -12,11 +12,10 @@ the full sharded TrainState story once:
     surviving axes), scaler state and the input batch,
   * a jitted train step with ``donate_argnums=(0,)`` whose in_shardings
     pin the state/batch layout, wrapped so every call (and trace) runs
-    under the mesh + ShardCtx (activation constraints, ZeRO-3 gathers).
+    under the mesh + ShardCtx (activation constraints).
 
-Consumers: ``launch/train.py`` trains through it, ``launch/dryrun.py``
-compiles through it (cost/probe assembly unchanged), tests assert parity
-between meshes, and the Trainer resumes checkpoints onto
+Consumers: ``launch/train.py`` and the benchmark train through it, tests
+assert parity between meshes, and the Trainer resumes checkpoints onto
 ``engine.state_shardings``. No consumer constructs optimizer-state
 shardings by hand.
 """
@@ -39,14 +38,8 @@ from repro.train.train_step import (TrainState, make_train_setup,
                                     make_train_step)
 
 def make_shard_ctx(mesh, parallel: ParallelConfig) -> PRM.ShardCtx:
-    """Trace-time sharding context: activates activation constraints and
-    (when parallel.fsdp_gather_weights) the explicit ZeRO-3 weight gathers."""
-    rules = default_rules(parallel)
-    nofsdp = PRM.nofsdp_rules(rules, rules.get("batch"))
-    return PRM.ShardCtx(mesh, rules, nofsdp,
-                        gather_fsdp=parallel.fsdp and
-                        parallel.fsdp_gather_weights,
-                        gather_wire=parallel.gather_wire)
+    """Trace-time sharding context: activates activation constraints."""
+    return PRM.ShardCtx(mesh, default_rules(parallel))
 
 
 def batch_shardings(inputs, mesh: Mesh, rules):
@@ -138,7 +131,7 @@ class TrainEngine:
             **kw)
 
     def lower(self, batch_abs=None):
-        """Lower the train step against abstract inputs (dry-run path)."""
+        """Lower the train step against abstract inputs."""
         batch_abs = self.batch_spec if batch_abs is None else batch_abs
         with jax.set_mesh(self.mesh), self.shard_ctx():
             return self.jit_step.lower(self.state_abs, batch_abs)

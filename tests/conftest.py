@@ -3,10 +3,10 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-# Tests see exactly ONE device by default (the dry-run sets its own
-# 512-device flag in a subprocess). The sharded smoke lane opts into fake
-# host devices via REPRO_DRYRUN_DEVICES=N (must happen before the first
-# jax backend init); tests needing multiple devices skip when absent.
+# Tests see exactly ONE device by default. The sharded smoke lane opts
+# into fake host devices via REPRO_DRYRUN_DEVICES=N (must happen before
+# the first jax backend init); tests needing multiple devices skip when
+# absent.
 from repro.host_devices import force_host_device_count  # noqa: E402
 
 force_host_device_count(argv=())

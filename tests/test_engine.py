@@ -104,8 +104,7 @@ def test_fsdp_shards_embed_over_data(single_device_trajectory):
 @needs8
 def test_adafactor_factored_state_gets_1d_pspecs():
     """Adafactor's vr/vc are means over one param axis — their shardings
-    must keep the surviving axis's mesh mapping (previously silently
-    replicated by dryrun's hasattr(opt_abs, 'exp_avg') fallback)."""
+    must keep the surviving axis's mesh mapping, not replicate."""
     eng = _engine(make_test_mesh((2, 4)), optimizer="adafactor", fsdp=True)
     state = eng.init_state()
     specs = jax.tree.leaves(eng.specs, is_leaf=lambda x: hasattr(x, "logical"))

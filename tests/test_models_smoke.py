@@ -197,14 +197,27 @@ def test_encdec_decode_matches_forward():
                                rtol=2e-3, atol=2e-3)
 
 
-def test_use_weight_noop_outside_context():
-    """PRM.use_weight must be a pure cast outside a ShardCtx (so smoke
-    tests and single-device training never pay for it)."""
-    from repro.models import params as PRM
-    w = jnp.ones((8, 4), jnp.float32)
-    out = PRM.use_weight(w, ("embed", "mlp"), jnp.bfloat16)
-    assert out.dtype == jnp.bfloat16
-    np.testing.assert_allclose(np.asarray(out, np.float32), 1.0)
+def test_linear_weights_are_cast_to_compute_dtype_before_use():
+    """A linear casts its f32 master weight to the compute dtype before
+    the matmul, and the int8 policy quantizes that bf16-rounded weight:
+    a weight that already holds bf16 values gives bit-identical outputs."""
+    from repro.models.mlp import mlp_block
+    cfg = get_reduced_config("smollm-360m")
+    D, F = 64, 128
+    ks = jax.random.split(KEY, 4)
+    p = {"w_up": jax.random.normal(ks[0], (D, F), jnp.float32),
+         "w_gate": jax.random.normal(ks[1], (D, F), jnp.float32),
+         "w_down": jax.random.normal(ks[2], (F, D), jnp.float32)}
+    rounded = jax.tree.map(
+        lambda w: w.astype(jnp.bfloat16).astype(jnp.float32), p)
+    x = jax.random.normal(ks[3], (2, 8, D), jnp.bfloat16)
+    for mode in ("bf16", "int8_switchback"):
+        pol = QuantPolicy(mode)
+        out = mlp_block(x, p, cfg, pol)
+        assert out.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(
+            np.asarray(out, np.float32),
+            np.asarray(mlp_block(x, rounded, cfg, pol), np.float32))
 
 
 @pytest.mark.slow

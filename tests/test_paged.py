@@ -342,15 +342,16 @@ def test_paged_prefix_adoption_matches_full_prefill(reduced):
 # engine level
 # ---------------------------------------------------------------------------
 
-def _engines(max_batch, max_len=32, bs=4, **kw):
+def _engines(max_batch, max_len=32, bs=4, policy=None, **kw):
     cfg = get_reduced_config(ARCH)
     mesh = make_test_mesh((1, 1))
     common = dict(max_batch=max_batch, max_len=max_len,
                   quant_mode="int8_switchback", **kw)
-    ring = make_serve_engine(build(cfg), ServeConfig(**common), mesh)
+    ring = make_serve_engine(build(cfg), ServeConfig(**common), mesh,
+                             policy=policy)
     paged = make_serve_engine(
         build(cfg), ServeConfig(cache_mode="paged", block_size=bs,
-                                **common), mesh)
+                                **common), mesh, policy=policy)
     return ring, paged, cfg
 
 
@@ -374,26 +375,39 @@ def test_engine_paged_matches_ring_int8_churn(reduced):
         assert s1[k] >= 0 and s2[k] >= 0
 
 
-def test_engine_shared_prefix_reuse_across_churn(reduced):
+@pytest.mark.parametrize(
+    "sys_len,tail,bs,new,min_saved,policy",
+    [(16, 3, 4, 4, 0.0, None),
+     # 6 requests behind a 32-token system prompt: adopting it must skip
+     # at least half of the ring engine's prefill tokens. Unquantized f32,
+     # as the other parity tests here: under int8 the ring and paged
+     # prefills round apart at this length (test_paged_prefill_matches_
+     # ring_bitwise) and greedy decode flips at near-ties
+     (32, 4, 8, 8, 0.5, F32)],
+    ids=["churn", "half_saved"])
+def test_engine_shared_prefix_reuse_across_churn(reduced, sys_len, tail, bs,
+                                                 new, min_saved, policy):
     """Requests sharing a system prompt, churned through 2 slots: later
     waves must adopt parked prefix blocks (hit rate > 0, prefill tokens
     saved) while still matching the ring oracle token-for-token."""
-    ring, paged, cfg = _engines(2, max_len=48, bs=4)
+    ring, paged, cfg = _engines(2, max_len=sys_len + tail + new + bs, bs=bs,
+                                policy=policy)
     params_host = jax.device_get(ring.init_params(0))
     rng = np.random.default_rng(1)
-    sysp = rng.integers(0, cfg.vocab_size, size=16).tolist()
-    prompts = [sysp + rng.integers(0, cfg.vocab_size, size=3).tolist()
+    sysp = rng.integers(0, cfg.vocab_size, size=sys_len).tolist()
+    prompts = [sysp + rng.integers(0, cfg.vocab_size, size=tail).tolist()
                for _ in range(6)]
     g1, s1 = ring.generate(ring.shard_params(params_host), prompts,
-                           max_new_tokens=4)
+                           max_new_tokens=new)
     g2, s2 = paged.generate(paged.shard_params(params_host), prompts,
-                            max_new_tokens=4)
+                            max_new_tokens=new)
     assert g1 == g2
     assert s2["prefix_hits"] > 0
     assert s2["prefill_tokens_saved"] > 0
     assert s2["prefill_tokens"] < s1["prefill_tokens"]
+    assert 1 - s2["prefill_tokens"] / s1["prefill_tokens"] >= min_saved
     # shared blocks mean fewer peak blocks than 6 lone prompts would need
-    assert s2["peak_blocks_in_use"] < 6 * math.ceil(19 / 4)
+    assert s2["peak_blocks_in_use"] < 6 * math.ceil((sys_len + tail) / bs)
 
 
 def test_engine_paged_no_prefix_cache_still_matches(reduced):
